@@ -22,7 +22,12 @@ time; see SURVEY.md header for provenance).
 
 __version__ = "0.1.0"
 
-from . import core  # noqa: F401
+from .programs.cache import enable_persistent_cache as _enable_cache
+
+# before anything can compile: eager ops and non-cached programs are kept too
+_enable_cache()
+
+from . import core  # noqa: F401,E402
 from . import linalg  # noqa: F401
 from . import metrics  # noqa: F401
 from . import preprocessing  # noqa: F401

@@ -5,7 +5,7 @@ Every performance lever in the runtime is a documented env knob
 ``SERVE_WINDOW_MS``, ``SERVE_MAX_BATCH``, ``SEARCH_INFLIGHT`` — docs/api.md
 §env) — but until this module they were constants frozen at construction:
 every recorded win (the 1.45x 4-vs-1 readers under remote-store emulation,
-the 1.27-1.55x relay-emulated concurrent search) required a human to read
+the 1.27-1.55x concurrent search under emulated staging latency) required a human to read
 the graftpath verdict and re-run.  This registry makes each of those
 parameters a :class:`Knob`: bounded, strictly parsed, with a runtime
 setter (:func:`set_knob`) and a change counter, so the controller loop

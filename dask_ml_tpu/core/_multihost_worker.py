@@ -21,20 +21,7 @@ import sys
 
 def main(pid: int, nproc: int, port: str, local_devices: int = 4) -> None:
     os.environ["JAX_PLATFORMS"] = "cpu"
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={local_devices}"
-    ).strip()
     import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", local_devices)
-    except AttributeError:
-        # older jax (< 0.4.38) has no jax_num_cpu_devices option; the
-        # XLA_FLAGS host-platform count set above covers it (backends
-        # haven't been created yet at this point in the worker)
-        pass
 
     from dask_ml_tpu.core import distributed as dist
 

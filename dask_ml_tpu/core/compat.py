@@ -1,25 +1,16 @@
-"""Version shims (twin of ``dask_ml/_compat.py``, reduced to what we need)."""
+"""The jax surface the package pins in one place (twin of
+``dask_ml/_compat.py``, reduced to what we need)."""
 
 from __future__ import annotations
 
 import jax
 
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
-JAX_VERSION = jax.__version__
+shard_map = jax.shard_map
 
 
 def shard_map_unchecked(fn, mesh, in_specs, out_specs):
-    """shard_map with the replication check disabled, handling the kwarg
-    rename (check_rep → check_vma) across jax versions.  Needed when an
+    """shard_map with the replication check disabled.  Needed when an
     out_spec is P() for a value that is replicated by construction (e.g. the
     R factor of a TSQR) but not provably so to the checker."""
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_vma=False)
-    except TypeError:  # older jax spells it check_rep
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_rep=False)
+    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                     check_vma=False)

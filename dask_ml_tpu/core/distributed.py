@@ -80,50 +80,21 @@ def initialize(coordinator_address: str | None = None,
     """
     if is_initialized():
         return
-    verify_cpu_count = 0
     backend_is_cpu = os.environ.get("JAX_PLATFORMS", "").startswith("cpu")
     if backend_is_cpu:
         jax.config.update("jax_platforms", "cpu")
         if local_device_count:
-            try:
-                jax.config.update(
-                    "jax_num_cpu_devices", int(local_device_count)
-                )
-            except AttributeError:
-                # older jax (< 0.4.38) has no jax_num_cpu_devices: fall
-                # back to the XLA flag, which still applies here because
-                # the CPU backend hasn't been created yet (initialize()
-                # runs before any device use).  Joining the group with a
-                # silently-wrong device count would desync the fleet's
-                # mesh and hang its first collective.
-                import re
-
-                flags = re.sub(
-                    r"--xla_force_host_platform_device_count=\d+", "",
-                    os.environ.get("XLA_FLAGS", ""),
-                )
-                os.environ["XLA_FLAGS"] = (
-                    flags + " --xla_force_host_platform_device_count="
-                    f"{int(local_device_count)}"
-                ).strip()
-                # env mutation is a silent no-op once the backend exists
-                # (jax >= 0.4.38 raises from config.update in that case);
-                # remember to verify the count took effect below
-                verify_cpu_count = int(local_device_count)
+            # raises once the CPU backend exists (initialize() must run
+            # before any device use): joining the group with a
+            # silently-wrong device count would desync the fleet's mesh
+            # and hang its first collective
+            jax.config.update("jax_num_cpu_devices", int(local_device_count))
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,
         process_id=process_id,
     )
-    if verify_cpu_count and jax.local_device_count() != verify_cpu_count:
-        raise RuntimeError(
-            f"CPU backend already existed before initialize(): "
-            f"local_device_count={jax.local_device_count()} != requested "
-            f"{verify_cpu_count}.  On jax < 0.4.38 set "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count="
-            f"{verify_cpu_count} before the first jax device use."
-        )
 
 
 def is_initialized() -> bool:

@@ -1,9 +1,9 @@
 """Global per-epoch shuffle as key-derived permutations (SURVEY §3.2).
 
 A billion-row epoch cannot shuffle through a host-RAM buffer — the
-whole point of the windowed ingest story (ROUND5_NOTES: 18.79 GB
-streamed with child VmHWM < 1.5 GB) is that no O(n) structure ever
-exists on the host.  The reference's answer (SURVEY §3.2: "PRNG per
+whole point of the windowed ingest story (a stream far larger than
+RAM with the child's VmHWM bounded, ``tests/test_streaming_rss.py``) is
+that no O(n) structure ever exists on the host.  The reference's answer (SURVEY §3.2: "PRNG per
 shard, ``jax.random.fold_in(key, shard_id)``") is to make the shuffle a
 pure FUNCTION of (key, epoch): every epoch is a deterministic
 permutation derived by key folding —

@@ -10,7 +10,10 @@ plus generators that stream row blocks straight into ``shard_rows`` /
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -30,25 +33,45 @@ __all__ = [
     "to_columnar",
 ]
 
-_NATIVE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "native")
-_SRC = os.path.join(_NATIVE_DIR, "loader.cpp")
-_SO = os.path.join(_NATIVE_DIR, "_loader.so")
+_SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "native",
+                    "loader.cpp")
 
 _lock = make_lock("io.registry")
 _lib = None
 
 
-def _build() -> None:
+def _so_path() -> str:
+    """The shared object for the CURRENT ``loader.cpp``: its name carries
+    a digest of the source's content, so a stale binary (an mtime says
+    nothing after a copy or a checkout) can never be the one loaded."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(os.path.dirname(_SRC), f"_loader-{digest}.so")
+
+
+def _build(so: str) -> None:
+    # build beside the target and rename: a concurrent process (multihost
+    # workers share the checkout) must never dlopen a half-written file
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = [
         "g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-        _SRC, "-o", _SO,
+        _SRC, "-o", tmp,
     ]
     try:
         subprocess.run(cmd, check=True, capture_output=True, text=True)
+        os.replace(tmp, so)
     except FileNotFoundError as e:  # pragma: no cover
         raise RuntimeError("native loader needs g++ on PATH") from e
     except subprocess.CalledProcessError as e:  # pragma: no cover
         raise RuntimeError(f"native loader build failed:\n{e.stderr}") from e
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    # binaries built from any other source text are dead weight
+    for stale in glob.glob(os.path.join(os.path.dirname(so), "_loader*.so")):
+        if stale != so:
+            with contextlib.suppress(OSError):
+                os.unlink(stale)
 
 
 def _load():
@@ -56,11 +79,10 @@ def _load():
     with _lock:
         if _lib is not None:
             return _lib
-        if (not os.path.exists(_SO)) or (
-            os.path.getmtime(_SO) < os.path.getmtime(_SRC)
-        ):
-            _build()
-        lib = ctypes.CDLL(_SO)
+        so = _so_path()
+        if not os.path.exists(so):
+            _build(so)
+        lib = ctypes.CDLL(so)
         lib.dmlt_csv_dims.argtypes = [
             ctypes.c_char_p, ctypes.c_int,
             ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),

@@ -57,7 +57,8 @@ def tsqr_strategy() -> str:
 
     ``auto`` is ``cholqr2`` on every platform — measured, not assumed
     (``bench.py :: tsqr_strategy_ab``): two agreeing CPU runs at 3.96×
-    (IQR-disjoint) and the round-5 chip run (BENCH_LOCAL.md) both decide
+    (IQR-disjoint) and the round-5 chip run (``bench_chip_evidence.jsonl``
+    ``tsqr_strategy_ab``) both decide
     cholqr2; the guarded Householder fallback inside the same program
     covers the ill-conditioned regime, so the fast default costs no
     correctness.

@@ -50,8 +50,10 @@ def _column_categories(col: np.ndarray) -> np.ndarray:
 def _encode_column(cats: np.ndarray, values: np.ndarray):
     """(codes, known): indices of ``values`` into ``cats`` preserving the
     given category order (user-supplied inventories need not be sorted).
-    Missing values encode as unknown (-1), like pandas categoricals."""
-    codes = np.asarray(pd.Categorical(values, categories=np.asarray(cats)).codes)
+    Missing and unknown values encode as -1, like pandas categoricals
+    (``pd.Categorical(values, categories=cats)`` itself is deprecated for
+    values outside the categories since pandas 3)."""
+    codes = pd.Index(np.asarray(cats)).get_indexer(np.asarray(values))
     return codes, codes >= 0
 
 

@@ -12,8 +12,9 @@ programs live:
 * :mod:`.cache` — :class:`CachedProgram`, the cache every step-program
   dispatch goes through instead of a bare ``jax.jit`` (the
   ``jit-outside-cache`` lint rule holds new code to that), with
-  hit/miss/ahead-hit books and the ``DASK_ML_TPU_COMPILE_CACHE``
-  persistent XLA cache knob;
+  hit/miss/ahead-hit books, and the persistent compilation cache
+  armed at package import (``JAX_COMPILATION_CACHE_DIR``, else
+  ``<checkout>/.jax_cache``);
 * :mod:`.ahead` — the blessed ``dask-ml-tpu-compile-ahead`` worker
   thread that pre-compiles the next bucket's program while the current
   block computes (``DASK_ML_TPU_COMPILE_AHEAD``).
@@ -39,7 +40,7 @@ from .bucket import (  # noqa: F401
     resolve_policy,
 )
 from .cache import (  # noqa: F401
-    CACHE_DIR_ENV,
+    DEFAULT_CACHE_DIR,
     CachedProgram,
     cached_program,
     enable_persistent_cache,
@@ -52,8 +53,8 @@ __all__ = [
     "AHEAD_THREAD_NAME",
     "ahead_worker_alive",
     "BUCKET_ENV",
-    "CACHE_DIR_ENV",
     "DEFAULT_BUCKETS",
+    "DEFAULT_CACHE_DIR",
     "BucketPolicy",
     "CachedProgram",
     "bucket_rows",

@@ -31,10 +31,9 @@ the obs metrics registry (``program.*``, tagged per program name) and
 in :func:`report` — surfaced as ``diagnostics.program_report()`` and
 ratcheted by the ``recompile_tax`` bench workload.
 
-The persistent XLA compilation cache (cold-start killer across bench
-rounds and multihost workers) arms behind ``DASK_ML_TPU_COMPILE_CACHE``
-the first time any program compiles; see
-:func:`enable_persistent_cache`.
+jax's persistent compilation cache (cold starts across processes: chip
+runs, multihost workers) is armed when the package is imported; its
+directory is placed from outside, see :func:`enable_persistent_cache`.
 """
 
 from __future__ import annotations
@@ -55,7 +54,7 @@ from ..obs import scope as _scope
 from ..obs.metrics import registry as _registry
 
 __all__ = [
-    "CACHE_DIR_ENV",
+    "DEFAULT_CACHE_DIR",
     "CachedProgram",
     "cached_program",
     "enable_persistent_cache",
@@ -65,11 +64,13 @@ __all__ = [
 
 logger = logging.getLogger(__name__)
 
-#: policy knob: directory for jax's persistent XLA compilation cache
-#: ('' = off, the default).  Shared across processes: bench rounds and
-#: multihost workers stop paying cold compiles for programs any prior
-#: process already built.
-CACHE_DIR_ENV = "DASK_ML_TPU_COMPILE_CACHE"
+#: where the compile cache lives when ``JAX_COMPILATION_CACHE_DIR`` does
+#: not say: a FIXED path in the checkout (ignored by git).  The path is
+#: part of how a later process finds the cache again, so it must not
+#: come from a temporary directory, a pid or the time.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 #: how long a consumer waits on an in-flight compile-ahead build before
 #: giving up and compiling on its own thread (a safety valve, not a
@@ -79,43 +80,25 @@ _AHEAD_WAIT_S = 120.0
 _REG_LOCK = make_lock("programs.registry")
 _BY_NAME: dict[str, "CachedProgram"] = {}
 
-_PERSISTENT = {"armed": False, "dir": None, "error": None}
-_PERSISTENT_LOCK = make_lock("programs.persistent")
 
+def enable_persistent_cache() -> str:
+    """Arm jax's persistent compilation cache and return its directory.
 
-def enable_persistent_cache(path: str | None = None) -> str | None:
-    """Arm jax's persistent XLA compilation cache at ``path`` (default:
-    the ``DASK_ML_TPU_COMPILE_CACHE`` knob; ``''`` leaves it off).
-
-    Returns the armed directory or None.  Called lazily before the
-    first compile in this module, and idempotent — the thresholds are
-    opened up (min size/time → 0) so even the small step programs this
-    repo streams get cached.  Fail-soft: an unwritable directory or an
-    unsupported backend logs one warning and leaves the in-process
-    behavior untouched (the persistent cache is an accelerator, never
-    a correctness dependency)."""
-    with _PERSISTENT_LOCK:
-        if _PERSISTENT["armed"]:
-            return _PERSISTENT["dir"]
-        if path is None:
-            path = os.environ.get(CACHE_DIR_ENV, "").strip()
-        if not path:
-            return None
-        try:
-            os.makedirs(path, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", path)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.0)
-        except Exception as e:  # pragma: no cover - backend-dependent
-            _PERSISTENT["armed"], _PERSISTENT["error"] = True, str(e)
-            logger.warning(
-                "persistent compilation cache at %r could not be armed "
-                "(%s); continuing without it", path, e)
-            return None
-        _PERSISTENT["armed"], _PERSISTENT["dir"] = True, path
-        return path
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set jax has already taken it
+    and no directory is set here; otherwise the cache goes to
+    :data:`DEFAULT_CACHE_DIR`.  Called when the package is imported,
+    before anything compiles, so eager operations and programs outside
+    :class:`CachedProgram` are kept as well.  The thresholds are opened
+    (min size/time -> 0) so that the small step programs this repo
+    streams are cached too.  An unwritable directory is jax's to report:
+    it warns once and compiles as if there were no cache."""
+    path = jax.config.jax_compilation_cache_dir
+    if not path:
+        path = DEFAULT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 
 # -- signatures ----------------------------------------------------------
@@ -399,19 +382,15 @@ class CachedProgram:
 
     # -- compilation (consumer thread on miss; blessed thread on warm) ---
     def _compile_entry(self, sig, args, static, source: str):
-        enable_persistent_cache()
         t0 = time.perf_counter()
         entry = None
         try:
             compiled = self._jitted.lower(*args, **static).compile()
             entry = _Entry(compiled, source, time.perf_counter() - t0)
-            try:
-                # tell the roofline layer what platform cost estimates
-                # belong to (roofline itself never imports jax, so the
-                # host-only sampler/scrape threads can read it freely)
-                _roofline.note_platform(jax.default_backend())
-            except Exception:  # pragma: no cover - backend query failure
-                pass
+            # tell the roofline layer what device the cost estimates
+            # belong to (roofline itself never imports jax, so the
+            # host-only sampler/scrape threads can read it freely)
+            _roofline.note_device_kind(jax.devices()[0].device_kind)
         except Exception as e:
             if source == "ahead":
                 # the consumer's own demand path still works; record and
@@ -542,7 +521,7 @@ def report() -> dict:
         "programs": per,
         "totals": totals,
         "bucket": counters_snapshot(),
-        "persistent_cache": _PERSISTENT["dir"],
+        "persistent_cache": jax.config.jax_compilation_cache_dir,
     }
 
 

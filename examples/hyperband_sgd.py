@@ -17,14 +17,6 @@ if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=8"
     ).strip()
 
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:  # older jax: XLA_FLAGS (set by the harness) covers it
-    pass
-
 import numpy as np  # noqa: E402
 from scipy.stats import loguniform  # noqa: E402
 

@@ -1,8 +1,7 @@
 """North-star #2: KMeans with k-means|| init and the fused Lloyd loop.
 
 Each Lloyd round is one program: distance gemm on the MXU, masked
-one-hot-gemm center reduce, psum across shards. Measured 0.73 ms per
-2M x 50 round on a single v5e chip (BENCH_LOCAL.md).
+one-hot-gemm center reduce, psum across shards.
 """
 import pathlib
 import sys
@@ -16,14 +15,6 @@ if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
         os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=8"
     ).strip()
-
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:  # older jax: XLA_FLAGS (set by the harness) covers it
-    pass
 
 import numpy as np  # noqa: E402
 from sklearn.datasets import make_blobs  # noqa: E402

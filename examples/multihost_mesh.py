@@ -26,14 +26,7 @@ if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=8"
     ).strip()
 
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:  # older jax: XLA_FLAGS (set by the harness) covers it
-    pass
-
+import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
 from dask_ml_tpu.core import use_mesh  # noqa: E402
@@ -55,10 +48,11 @@ with use_mesh(flat):
     acc_flat = float(lr.score(Xs, ys))
 print(f"flat mesh {dict(flat.shape)}: ADMM accuracy {acc_flat:.3f}")
 
-# -- hierarchical mesh: explicit 'dcn' axis (2 slices x 4 devices here;
-# on a real fleet global_mesh(hierarchical=True) derives it from the
-# process group)
-devs = np.array(jax.devices()).reshape(2, 4, 1)
+# -- hierarchical mesh: explicit 'dcn' axis (2 slices x 4 devices on the
+# CPU mesh, 2 x 2 on a four-chip host, 1 x 1 on one chip; on a real
+# fleet global_mesh(hierarchical=True) derives it from the process group)
+n_dev = len(jax.devices())
+devs = np.array(jax.devices()).reshape(2 if n_dev % 2 == 0 else 1, -1, 1)
 hmesh = Mesh(devs, ("dcn", "data", "model"))
 with use_mesh(hmesh):
     Xh = dist.shard_rows_global(X, hmesh)

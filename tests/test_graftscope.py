@@ -716,14 +716,18 @@ class _FakeCompiled:
 class TestRoofline:
     def test_default_peaks_have_provenance(self):
         cpu = roofline.peaks_for("cpu")
-        tpu = roofline.peaks_for("tpu")
+        v5e = roofline.peaks_for("TPU v5 lite")  # as the chip reports it
         assert cpu["source"].startswith("measured")
-        assert tpu["source"].startswith("assumed")
+        assert v5e["source"].startswith("published")
+        assert v5e["flops_per_s"] == 1.97e14 and v5e["bytes_per_s"] == 8.19e11
         assert cpu["flops_per_s"] > 0 and cpu["bytes_per_s"] > 0
 
-    def test_unknown_platform_has_no_peaks(self):
-        assert roofline.peaks_for("quantum") is None
-        assert roofline.peaks_for(None) is None
+    def test_unknown_device_kind_has_no_peaks(self):
+        # peaks are keyed by device_kind, not platform: a TPU that is not
+        # in the table gets nothing — never the v5e row
+        for kind in ("TPU v4", "TPU v6 lite", "TPU7x", "tpu", "quantum",
+                     None):
+            assert roofline.peaks_for(kind) is None, kind
 
     def test_env_override_and_reset(self, monkeypatch):
         monkeypatch.setenv(roofline.PEAKS_ENV,
@@ -782,7 +786,7 @@ class TestRoofline:
         assert roofline.capture_cost(_FakeCompiled(
             {"flops": 1.0, "bytes accessed": 1.0}))["flops"] == 1.0
         assert roofline.capture_cost(
-            _FakeCompiled(RuntimeError("relayed"))) is None
+            _FakeCompiled(RuntimeError("unsupported"))) is None
         assert roofline.capture_cost(_FakeCompiled([])) is None
         assert roofline.capture_cost(_FakeCompiled(
             [{"flops": -1.0, "bytes accessed": 4.0}])) is None

@@ -41,6 +41,49 @@ def csv_file(tmp_path_factory):
     return str(p), X
 
 
+class TestLoaderBuild:
+    """The shared object is keyed on ``loader.cpp``'s CONTENT: an mtime
+    means nothing after a copy or a checkout, so neither a missing nor a
+    stale binary may stand in for the committed source."""
+
+    @pytest.fixture
+    def private_native_dir(self, tmp_path, monkeypatch):
+        import shutil
+
+        src = tmp_path / "loader.cpp"
+        shutil.copy(dio._SRC, src)
+        monkeypatch.setattr(dio, "_SRC", str(src))
+        monkeypatch.setattr(dio, "_lib", None)
+        return tmp_path
+
+    def test_missing_and_stale_so_both_build_from_source(
+            self, private_native_dir, csv_file):
+        import os
+
+        path, X = csv_file
+        stale = private_native_dir / "_loader.so"
+        stale.write_bytes(b"not a shared object")
+        # newer than the source: the old mtime rule would have loaded it
+        os.utime(stale, (2**31, 2**31))
+        dio._load()
+        built = dio._so_path()
+        assert os.path.exists(built) and not stale.exists()
+        np.testing.assert_allclose(dio.read_csv(path), X, rtol=1e-6)
+
+    def test_content_change_rebuilds(self, private_native_dir, monkeypatch):
+        import os
+
+        dio._load()
+        first = dio._so_path()
+        with open(dio._SRC, "a") as f:
+            f.write("\n// one more line of source\n")
+        monkeypatch.setattr(dio, "_lib", None)
+        dio._load()
+        second = dio._so_path()
+        assert second != first
+        assert os.path.exists(second) and not os.path.exists(first)
+
+
 class TestCSV:
     def test_dims(self, csv_file):
         p, X = csv_file

@@ -299,10 +299,8 @@ class TestRingPairwise:
             Xs.data, Ys.data, mesh_holder=MeshHolder(get_mesh()),
             fn=_sq_euclidean,
         )
-        from conftest import spec_axis
-
         # never replicated
-        assert spec_axis(out.sharding.spec[0]) == DATA_AXIS
+        assert out.sharding.spec[0] == DATA_AXIS
 
     def test_uneven_rows(self, rng, mesh):
         # both operands need pad+mask handling (neither divisible by 8)

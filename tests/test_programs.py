@@ -414,29 +414,74 @@ class TestCachedProgram:
 
 
 class TestPersistentCache:
-    def test_knob_arms_and_reports(self, tmp_path, monkeypatch):
-        d = str(tmp_path / "xla-cache")
-        monkeypatch.setattr(cache, "_PERSISTENT",
-                            {"armed": False, "dir": None, "error": None})
-        monkeypatch.setenv(cache.CACHE_DIR_ENV, d)
-        try:
-            armed = programs.enable_persistent_cache()
-            assert armed == d and os.path.isdir(d)
-            assert programs.report()["persistent_cache"] == d
-            # idempotent: second call returns the armed dir
-            assert programs.enable_persistent_cache("/elsewhere") == d
-        finally:
-            jax.config.update("jax_compilation_cache_dir", None)
-            monkeypatch.setattr(cache, "_PERSISTENT",
-                                {"armed": False, "dir": None,
-                                 "error": None})
+    """The cache directory is placed from OUTSIDE: where
+    ``JAX_COMPILATION_CACHE_DIR`` is set jax has taken it and the code
+    sets none; where it is not, the fixed ``<checkout>/.jax_cache``."""
 
-    def test_off_by_default(self, monkeypatch):
-        monkeypatch.setattr(cache, "_PERSISTENT",
-                            {"armed": False, "dir": None, "error": None})
-        monkeypatch.delenv(cache.CACHE_DIR_ENV, raising=False)
-        assert programs.enable_persistent_cache() is None
-        assert programs.report()["persistent_cache"] is None
+    _KEY = "jax_compilation_cache_dir"
+
+    @pytest.fixture
+    def updates(self, monkeypatch):
+        """Every ``jax.config.update`` key the code under test sets,
+        with the process's real cache directory restored afterwards."""
+        before = jax.config.jax_compilation_cache_dir
+        seen = []
+        real = jax.config.update
+
+        def spy(name, value):
+            seen.append(name)
+            return real(name, value)
+
+        monkeypatch.setattr(jax.config, "update", spy)
+        yield seen
+        real(self._KEY, before)
+
+    def test_armed_at_import(self):
+        # conftest imported the package: the cache is already armed, at
+        # the one directory program_report() names
+        armed = jax.config.jax_compilation_cache_dir
+        assert armed and programs.report()["persistent_cache"] == armed
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+
+    def test_variable_set_directory_untouched_by_code(self, tmp_path,
+                                                      updates):
+        d = str(tmp_path / "from-outside")
+        jax.config.update(self._KEY, d)  # what jax does with the variable
+        del updates[:]
+        assert programs.enable_persistent_cache() == d
+        assert self._KEY not in updates
+        assert jax.config.jax_compilation_cache_dir == d
+        assert programs.report()["persistent_cache"] == d
+
+    def test_variable_unset_uses_checkout_dir(self, updates):
+        jax.config.update(self._KEY, None)
+        checkout = os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))
+        want = os.path.join(checkout, ".jax_cache")
+        assert programs.DEFAULT_CACHE_DIR == want
+        assert programs.enable_persistent_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+
+    def test_cache_files_land_where_the_variable_says(self, tmp_path):
+        import subprocess
+        import sys
+
+        d = tmp_path / "xla-cache"
+        checkout = os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))
+        code = (
+            "import jax, jax.numpy as jnp, dask_ml_tpu\n"
+            "from dask_ml_tpu import diagnostics\n"
+            "jax.jit(lambda x: x * 2 + 1)(jnp.ones(7)).block_until_ready()\n"
+            "print(diagnostics.program_report()['persistent_cache'])\n")
+        out = subprocess.run(
+            [sys.executable, "-c", code], cwd=checkout, text=True,
+            capture_output=True, timeout=300,
+            env={**os.environ, "JAX_PLATFORMS": "cpu",
+                 "JAX_COMPILATION_CACHE_DIR": str(d)})
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert out.stdout.split()[-1] == str(d)
+        assert any(d.iterdir()), "no cache entry written where asked"
 
 
 # -- estimator integration ------------------------------------------------
