@@ -45,7 +45,6 @@ from .pipeline import (  # noqa: F401
 from . import obs  # noqa: F401
 from .obs import (  # noqa: F401
     event,
-    export_perfetto,
     flight_dump,
     metrics_snapshot,
     span,
@@ -56,7 +55,7 @@ __all__ = [
     "FaultStats", "fault_stats", "reset_fault_stats", "fault_report",
     "pipeline_report", "reset_pipeline_stats",
     "lint_report", "sanitize_report", "program_report", "serve_report",
-    "obs", "span", "event", "metrics_snapshot", "export_perfetto",
+    "obs", "span", "event", "metrics_snapshot",
     "flight_dump", "run_report", "reset",
 ]
 
@@ -171,9 +170,9 @@ def run_report() -> dict:
       per-plane reporters, unchanged shapes (views over the same
       registry).
 
-    Call :func:`reset` first to scope the report to one fit; export the
-    same fit with :func:`export_perfetto` to render its host lanes AND
-    its measured device lane in one trace.
+    Call :func:`reset` first to scope the report to one fit; to see its
+    host spans against the real device lanes, run it under
+    :func:`trace`.
     """
     resilience = fault_report()
     # graftpath AFTER the settled device read below would re-settle;
@@ -327,6 +326,16 @@ def trace(log_dir: str):
     The TPU analogue of watching the distributed dashboard's task stream:
     ``with diagnostics.trace('/tmp/prof'): est.fit(X)`` then point
     TensorBoard (or xprof) at the directory.
+
+    The one file holds the program's own spans too: while the session
+    runs every ``obs.span`` is live and also a ``TraceAnnotation`` on the
+    host plane (``glm.fit`` > ``glm.classes`` / ``glm.prepare`` /
+    ``glm.solve``, each carrying the fit's id as ``fit``), on the clock
+    of the device lanes.  Inside the solver programs the device
+    operations are named by ``jax.named_scope``: ``admm.local_solve``,
+    ``admm.consensus``, ``lbfgs.direction``, ``lbfgs.line_search``,
+    ``lbfgs.update`` — read them in XProf's op names (the trace viewer,
+    or the op profile's tree).
 
     Exception-safe: ``start_trace`` itself can raise (unwritable
     directory, a trace already active) — the stop only runs if the

@@ -14,6 +14,7 @@ import numpy as np
 from ..base import ClassifierMixin, RegressorMixin, TPUEstimator
 from ..core.sharded import ShardedRows
 from ..preprocessing.data import _ingest_float
+from .. import obs as _obs
 from .. import sanitize as _san
 from ..solvers import (
     Logistic,
@@ -26,6 +27,7 @@ from ..solvers import (
     newton,
     proximal_grad,
 )
+from ..solvers.algorithms import COUNTED_SOLVERS, SOLVE_COUNTS
 from .utils import add_intercept, binary_indicator
 
 _SOLVERS = {
@@ -35,6 +37,28 @@ _SOLVERS = {
     "gradient_descent": gradient_descent,
     "proximal_grad": proximal_grad,
 }
+
+
+def _fetch_counts(runs):
+    """The fit's one device-to-host transfer of its iteration counts:
+    ``(n_iter_, counts)``.  ``runs`` holds one entry per solver run, a
+    scalar iteration count or, from a counted solver, the
+    ``SOLVE_COUNTS`` vector; ``counts`` is what goes on the ``glm.solve``
+    span: all three counts of a single counted run, else the rounds."""
+    got = np.asarray(runs, dtype=np.int32)
+    if got.ndim == 2:
+        return got[:, 0], dict(zip(SOLVE_COUNTS, got[0].tolist()))
+    return got, {"rounds": int(got.max())}
+
+
+def _publish_counts(span, counts):
+    """A finished solve's counts: onto its span, and into the always-on
+    registry (``solve.count`` solves, and their summed counts)."""
+    span.set(**counts)
+    reg = _obs.registry()
+    reg.counter("solve.count").inc()
+    for name, value in counts.items():
+        reg.counter(f"solve.{name}").inc(value)
 
 
 class _GLM(TPUEstimator):
@@ -92,10 +116,24 @@ class _GLM(TPUEstimator):
                     X, y, family or self.family, beta0, kwargs,
                     self.fit_checkpoint,
                 )
-            return _SOLVERS[self.solver](
-                X, y, return_n_iter=True, family=family or self.family,
-                beta0=beta0, **kwargs
-            )
+            return self._run_solver(
+                X, y, family or self.family, beta0, kwargs)
+
+    def _solve_span(self):
+        """``glm.solve``: from the solver call to ``n_iter_`` on the host,
+        so the wait for the device is inside it."""
+        return _obs.span("glm.solve", line_search=(
+            self.solver_kwargs or {}).get("line_search", "default"))
+
+    def _run_solver(self, X, y, family, beta0, kwargs):
+        """One whole-solve dispatch: ``(beta, n_it)``, both still on the
+        device, where ``n_it`` is the ``SOLVE_COUNTS`` vector from a
+        counted solver and the scalar iteration count from the others
+        (``_fetch_counts`` reads either)."""
+        flag = ("return_counts" if self.solver in COUNTED_SOLVERS
+                else "return_n_iter")
+        return _SOLVERS[self.solver](
+            X, y, family=family, beta0=beta0, **{flag: True}, **kwargs)
 
     def _solve_chunked(self, X, y, family, beta0, kwargs, ckpt):
         """Preemption-safe solve: the fused device solver runs in SEGMENTS
@@ -178,24 +216,46 @@ class _GLM(TPUEstimator):
         return betas
 
     def fit(self, X, y=None, sample_weight=None):
+        # the fit's spans (live under ``obs.enable()`` or a profiler
+        # session): ``glm.fit`` is the root, its id the fit's identifier
+        # on every child; what lies outside ``glm.classes``,
+        # ``glm.prepare`` and ``glm.solve`` is its self time
+        with _obs.span("glm.fit", estimator=type(self).__name__,
+                       solver=self.solver) as root:
+            return self._fit(X, y, sample_weight, root)
+
+    def _prepare(self, X, root, span, **root_attrs):
+        """Ingest X and append the intercept column; the fit's sizes go
+        on the root span."""
         X = _ingest_float(self, X)
         self.n_features_in_ = X.data.shape[1]
         Xi = add_intercept(X) if self.fit_intercept else X
-        if sample_weight is not None:
-            from ..utils import reweight_rows
+        root.set(rows=X.n_samples, features=self.n_features_in_,
+                 chips=len(X.data.sharding.device_set), **root_attrs)
+        span.set(padded_rows=Xi.data.shape[0])
+        return Xi
 
-            Xi = reweight_rows(Xi, sample_weight=sample_weight)
-        warm = None
-        if self.warm_start:
-            warm = self._warm_ok(
-                getattr(self, "betas_", None), (1, Xi.data.shape[1]),
-                was_multinomial=getattr(self, "_multinomial", False),
-            )
-        beta, n_it = self._solve(
-            Xi, y, beta0=None if warm is None else warm[0])
-        # sklearn contract: iteration count(s) of the solver run(s);
-        # converted only now, after the solve is dispatched
-        self.n_iter_ = np.asarray([n_it], dtype=np.int32)
+    def _fit(self, X, y, sample_weight, root):
+        with _obs.span("glm.prepare") as span:
+            Xi = self._prepare(X, root, span)
+            if sample_weight is not None:
+                from ..utils import reweight_rows
+
+                Xi = reweight_rows(Xi, sample_weight=sample_weight)
+            warm = None
+            if self.warm_start:
+                warm = self._warm_ok(
+                    getattr(self, "betas_", None), (1, Xi.data.shape[1]),
+                    was_multinomial=getattr(self, "_multinomial", False),
+                )
+        with self._solve_span() as span:
+            beta, n_it = self._solve(
+                Xi, y, beta0=None if warm is None else warm[0])
+            # sklearn contract: iteration count(s) of the solver run(s);
+            # converted only now, after the solve is dispatched (the wait
+            # for the device is here, inside the span)
+            self.n_iter_, counts = _fetch_counts([n_it])
+            _publish_counts(span, counts)
         if self.fit_intercept:
             self.coef_ = beta[:-1]
             self.intercept_ = float(beta[-1])
@@ -272,7 +332,7 @@ class LogisticRegression(ClassifierMixin, _GLM):
         )
         return betas, classes
 
-    def fit(self, X, y=None, sample_weight=None):
+    def _fit(self, X, y, sample_weight, root):
         # warm start (an improvement over the reference: dask_glm ignores
         # it): capture the PREVIOUS fit's parameters before this fit
         # overwrites them; they seed the solver when the problem geometry
@@ -294,63 +354,30 @@ class LogisticRegression(ClassifierMixin, _GLM):
         from ..core.sharded import ShardedRows as _SR
         from ..core.sharded import as_sharded
 
-        # raw device label vectors ride the ShardedRows no-fetch paths
-        y = as_sharded(y)
-        if isinstance(y, _SR):
-            # device-side class discovery: only the unique label VALUES
-            # cross to host (a handful of scalars), never the n-row label
-            # vector — a full unshard of device-resident labels is an
-            # O(n) device->host transfer, and illegal for multi-host
-            # global arrays.  Pad rows are remapped to the first (real)
-            # label so padding cannot mint a phantom class.
-            yd = jnp.where(y.mask > 0, y.data, y.data[0])
-            self.classes_ = np.asarray(jnp.unique(yd))
-            yv = None
-        else:
-            yv = np.asarray(y)
-            self.classes_ = np.unique(yv)
-        if len(self.classes_) < 2:
+        with _obs.span("glm.classes") as span:
+            # raw device label vectors ride the ShardedRows no-fetch paths
+            y = as_sharded(y)
+            if isinstance(y, _SR):
+                # device-side class discovery: only the unique label
+                # VALUES cross to host (a handful of scalars), never the
+                # n-row label vector — a full unshard of device-resident
+                # labels is an O(n) device->host transfer, and illegal for
+                # multi-host global arrays.  Pad rows are remapped to the
+                # first (real) label so padding cannot mint a phantom
+                # class.
+                yd = jnp.where(y.mask > 0, y.data, y.data[0])
+                self.classes_ = np.asarray(jnp.unique(yd))
+                yv = None
+            else:
+                yv = np.asarray(y)
+                self.classes_ = np.unique(yv)
+            K = len(self.classes_)
+            span.set(classes=K)
+        if K < 2:
             raise ValueError(
                 "LogisticRegression needs samples of at least 2 classes; "
                 f"got {self.classes_.tolist()}"
             )
-        X = _ingest_float(self, X)
-        self.n_features_in_ = X.data.shape[1]
-        Xi = add_intercept(X) if self.fit_intercept else X
-
-        if sample_weight is not None or self.class_weight is not None:
-            # weights scale the mask: every masked reduction in the
-            # solvers becomes the sklearn weighted loss (VERDICT r2
-            # missing #6 — the mask machinery IS the per-row weight)
-            from ..utils import host_class_weight_rows, reweight_rows
-
-            if self.class_weight is not None and yv is not None:
-                # host labels can be strings or big ints that a device
-                # cast would corrupt: resolve the per-row class weight on
-                # host and fold it into sample_weight
-                row_w = host_class_weight_rows(
-                    self.class_weight, self.classes_, yv
-                )
-                if sample_weight is not None:
-                    row_w = row_w * np.asarray(sample_weight, np.float32)
-                Xi = reweight_rows(Xi, sample_weight=row_w)
-            elif self.class_weight is not None:
-                # device labels are numeric by construction: count and
-                # weight classes on device, no label round-trip
-                Xi = reweight_rows(
-                    Xi, sample_weight=sample_weight,
-                    class_weight=self.class_weight, classes=self.classes_,
-                    y_padded=y.data,
-                )
-            else:
-                Xi = reweight_rows(Xi, sample_weight=sample_weight)
-
-        def _indicator(cls):
-            """One-vs-rest target via the SHARED encoding helper
-            (linear_model.utils.binary_indicator)."""
-            return binary_indicator(yv if yv is not None else y, cls)
-
-        K = len(self.classes_)
 
         def _warm(shape, want_multinomial=False):
             """Previous betas when classes and parameter shape match
@@ -367,98 +394,137 @@ class LogisticRegression(ClassifierMixin, _GLM):
                 ),
             )
 
+        # binary: one sigmoid solve.  'multinomial' with 2 classes is the
+        # SAME loss reparameterized (w = w1 - w0); for L2 the softmax
+        # penalty ||w0||² + ||w1||² equals ||w||²/2 at the symmetric
+        # optimum — i.e. the sigmoid fit at HALF the penalty.  That
+        # scalar equivalence is L2-ONLY (L1 of the split pair is |w|,
+        # elasticnet has no single scale), so non-L2 multinomial takes
+        # the true 2-class softmax solve.
+        binary = K == 2 and not (
+            self.multi_class == "multinomial" and self.penalty != "l2")
+        softmax = not binary and self.multi_class == "multinomial"
+
+        with _obs.span("glm.prepare") as span:
+            Xi = self._prepare(X, root, span, classes=K)
+            if sample_weight is not None or self.class_weight is not None:
+                # weights scale the mask: every masked reduction in the
+                # solvers becomes the sklearn weighted loss (VERDICT r2
+                # missing #6 — the mask machinery IS the per-row weight)
+                from ..utils import host_class_weight_rows, reweight_rows
+
+                if self.class_weight is not None and yv is not None:
+                    # host labels can be strings or big ints that a
+                    # device cast would corrupt: resolve the per-row class
+                    # weight on host and fold it into sample_weight
+                    row_w = host_class_weight_rows(
+                        self.class_weight, self.classes_, yv
+                    )
+                    if sample_weight is not None:
+                        row_w = row_w * np.asarray(
+                            sample_weight, np.float32)
+                    Xi = reweight_rows(Xi, sample_weight=row_w)
+                elif self.class_weight is not None:
+                    # device labels are numeric by construction: count
+                    # and weight classes on device, no label round-trip
+                    Xi = reweight_rows(
+                        Xi, sample_weight=sample_weight,
+                        class_weight=self.class_weight,
+                        classes=self.classes_, y_padded=y.data,
+                    )
+                else:
+                    Xi = reweight_rows(Xi, sample_weight=sample_weight)
+            # the solve's target and its warm start (previous betas_)
+            p = Xi.data.shape[1]
+            if binary:
+                # one-vs-rest target via the SHARED encoding helper
+                target = binary_indicator(
+                    yv if yv is not None else y, self.classes_[1])
+                warm = _warm((1, p))
+            elif softmax:
+                if yv is None:
+                    yd2 = jnp.where(y.mask > 0, y.data, y.data[0])
+                    target = _SR(
+                        data=jnp.searchsorted(
+                            jnp.asarray(self.classes_, yd2.dtype), yd2
+                        ).astype(jnp.float32),
+                        mask=y.mask, n_samples=y.n_samples,
+                    )
+                else:
+                    target = np.searchsorted(
+                        self.classes_, yv).astype(np.float32)
+                warm = _warm((K, p), want_multinomial=True)
+            else:
+                n_pad = Xi.data.shape[0]
+                if yv is None:
+                    target = (
+                        y.data[None, :]
+                        == jnp.asarray(self.classes_, y.data.dtype)[:, None]
+                    ).astype(jnp.float32)
+                else:
+                    Yh = (yv[None, :] == self.classes_[:, None]).astype(
+                        np.float32
+                    )
+                    target = jnp.asarray(
+                        np.pad(Yh, ((0, 0), (0, n_pad - Yh.shape[1])))
+                    )
+                warm = _warm((K, p))
+
         self._multinomial = False
-        if K == 2 and not (
-            self.multi_class == "multinomial" and self.penalty != "l2"
-        ):
-            # binary: one sigmoid solve.  'multinomial' with 2 classes is
-            # the SAME loss reparameterized (w = w1 - w0); for L2 the
-            # softmax penalty ||w0||² + ||w1||² equals ||w||²/2 at the
-            # symmetric optimum — i.e. the sigmoid fit at HALF the
-            # penalty.  That scalar equivalence is L2-ONLY (L1 of the
-            # split pair is |w|, elasticnet has no single scale), so
-            # non-L2 multinomial falls through to the true 2-class
-            # softmax solve below.
-            y01 = _indicator(self.classes_[1])
-            wb = _warm((1, Xi.data.shape[1]))
-            w0 = None if wb is None else wb[0]
-            if self.multi_class == "multinomial":
-                kwargs = self._solver_call_kwargs()
-                kwargs["lamduh"] = kwargs["lamduh"] / 2.0
-                beta, n_it = _SOLVERS[self.solver](
-                    Xi, y01, return_n_iter=True, family=self.family,
-                    beta0=w0, **kwargs,
-                )
-            else:
-                beta, n_it = self._solve(Xi, y01, beta0=w0)
-            self.betas_ = beta[None, :]
-            n_iter_runs = [n_it]
-        elif self.multi_class == "multinomial":
-            # true softmax: ONE solve over a flat (features*K) parameter
-            # vector (solvers/families.py :: multinomial); closes the
-            # reference's binary-only dask_glm gap
-            from ..solvers import multinomial as _mn
+        with self._solve_span() as span:
+            if binary:
+                w0 = None if warm is None else warm[0]
+                if self.multi_class == "multinomial":
+                    kwargs = self._solver_call_kwargs()
+                    kwargs["lamduh"] = kwargs["lamduh"] / 2.0
+                    beta, n_it = self._run_solver(
+                        Xi, target, self.family, w0, kwargs)
+                else:
+                    beta, n_it = self._solve(Xi, target, beta0=w0)
+                self.betas_ = beta[None, :]
+                n_iter_runs = [n_it]
+            elif softmax:
+                # true softmax: ONE solve over a flat (features*K)
+                # parameter vector (solvers/families.py :: multinomial);
+                # closes the reference's binary-only dask_glm gap
+                from ..solvers import multinomial as _mn
 
-            fam = _mn(K)
-            if yv is None:
-                yd2 = jnp.where(y.mask > 0, y.data, y.data[0])
-                y_idx = _SR(
-                    data=jnp.searchsorted(
-                        jnp.asarray(self.classes_, yd2.dtype), yd2
-                    ).astype(jnp.float32),
-                    mask=y.mask, n_samples=y.n_samples,
-                )
+                # warm start: betas_ stores W (K, p); the flat vector the
+                # softmax family consumes is its (p, K) transpose raveled
+                beta_flat, n_it = self._solve(
+                    Xi, target, family=_mn(K),
+                    beta0=None if warm is None else warm.T.ravel())
+                W = beta_flat.reshape(p, K).T  # (K, p)
+                if K == 2:
+                    # non-L2 binary softmax (the L2 case took the sigmoid
+                    # shortcut above): collapse to the sigmoid form — the
+                    # decision function w = w1 - w0 gives the EXACT
+                    # softmax posterior, and the binary coef_/predict
+                    # contract holds
+                    self.betas_ = (W[1] - W[0])[None, :]
+                else:
+                    self.betas_ = W
+                    self._multinomial = True
+                # sklearn multinomial reports ONE solver run replicated
+                # per class in n_iter_; keep a single honest count instead
+                n_iter_runs = [n_it]
             else:
-                y_idx = np.searchsorted(self.classes_, yv).astype(np.float32)
-            # warm start: betas_ stores W (K, p); the flat vector the
-            # softmax family consumes is its (p, K) transpose raveled
-            wm = _warm((K, Xi.data.shape[1]), want_multinomial=True)
-            beta_flat, n_it = self._solve(
-                Xi, y_idx, family=fam,
-                beta0=None if wm is None else wm.T.ravel())
-            W = beta_flat.reshape(Xi.data.shape[1], K).T  # (K, p)
-            if K == 2:
-                # non-L2 binary softmax (the L2 case took the sigmoid
-                # shortcut above): collapse to the sigmoid form — the
-                # decision function w = w1 - w0 gives the EXACT softmax
-                # posterior, and the binary coef_/predict contract holds
-                self.betas_ = (W[1] - W[0])[None, :]
-            else:
-                self.betas_ = W
-                self._multinomial = True
-            # sklearn multinomial reports ONE solver run replicated per
-            # class in n_iter_; keep a single honest count instead
-            n_iter_runs = [n_it]
-        else:
-            # packed one-vs-rest: the K independent solves run as ONE
-            # vmapped XLA program (solvers.packed_solve) — the reference
-            # dispatches a task graph per class; a K-long Python loop of
-            # device solves was the round-2 shape (VERDICT r2 missing #4)
-            from ..solvers import packed_solve
+                # packed one-vs-rest: the K independent solves run as ONE
+                # vmapped XLA program (solvers.packed_solve) — the
+                # reference dispatches a task graph per class; a K-long
+                # Python loop of device solves was the round-2 shape
+                # (VERDICT r2 missing #4)
+                from ..solvers import packed_solve
 
-            n_pad = Xi.data.shape[0]
-            if yv is None:
-                Y = (
-                    y.data[None, :]
-                    == jnp.asarray(self.classes_, y.data.dtype)[:, None]
-                ).astype(jnp.float32)
-            else:
-                Yh = (yv[None, :] == self.classes_[:, None]).astype(
-                    np.float32
+                self.betas_, n_iter_runs = packed_solve(  # betas_ (K, p)
+                    self.solver, Xi, target, family=self.family,
+                    Beta0=warm, **self._solver_call_kwargs(),
                 )
-                Y = jnp.asarray(
-                    np.pad(Yh, ((0, 0), (0, n_pad - Yh.shape[1])))
-                )
-            betas, n_its = packed_solve(
-                self.solver, Xi, Y, family=self.family,
-                Beta0=_warm((K, Xi.data.shape[1])),
-                **self._solver_call_kwargs(),
-            )
-            self.betas_ = betas  # (K, p)
-            n_iter_runs = n_its
-        # sklearn contract: one count per OvR solve — device scalars are
-        # converted only here, after every class's solve has dispatched
-        self.n_iter_ = np.asarray(n_iter_runs, dtype=np.int32)
+            # sklearn contract: one count per OvR solve — device scalars
+            # are converted only here, after every class's solve has
+            # dispatched (the wait for the device is here, in the span)
+            self.n_iter_, counts = _fetch_counts(n_iter_runs)
+            _publish_counts(span, counts)
         if self.fit_intercept:
             self.coef_ = (
                 self.betas_[0, :-1] if len(self.classes_) == 2

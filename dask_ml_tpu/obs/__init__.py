@@ -11,23 +11,23 @@ The one event spine every runtime layer reports through (docs/design.md
   ``PipelineStats``, ``FaultStats``, and graftsan publish into — the
   old reporters keep their shapes as views;
 * :mod:`.export` — schema-versioned JSONL streaming
-  (``DASK_ML_TPU_TRACE=path``) and Chrome/Perfetto ``trace_event``
-  export, so a streamed fit's host-side overlap renders next to an
-  XProf device trace;
+  (``DASK_ML_TPU_TRACE=path``); host spans against the device are read
+  in the profiler's own trace, where :mod:`.spans` writes every span as
+  a ``TraceAnnotation`` while a session runs (``diagnostics.trace``);
 * :mod:`.flight` — the always-on last-N-events post-mortem ring dumped
   by the conftest watchdog and the preemption/fault paths;
 * :mod:`.scope` — graftscope device-time accounting: per-program
   in-flight intervals from the dispatch choke points, the
-  utilization/idle-gap report (``run_report()["device"]``), and the
-  Perfetto device lane;
+  utilization/idle-gap report (``run_report()["device"]``);
 * :mod:`.serve` — the live Prometheus ``/metrics`` + ``/healthz``
   endpoint (``DASK_ML_TPU_METRICS_PORT``), supervised like the
   compile-ahead thread.
 
 Everything importable from here is pure-stdlib host code (no jax) —
 safe in any thread including the prefetch worker; the jax compile
-listener lives in :mod:`.jaxhooks` and is installed lazily by
-:func:`enable` / :func:`install_jax_hooks`.
+listener and the profiler-session check live in :mod:`.jaxhooks`, which
+is imported lazily (by :func:`enable` / :func:`install_jax_hooks`, and
+by the first ``span()`` once jax is in the process).
 """
 
 from __future__ import annotations
@@ -62,11 +62,7 @@ from .spans import (  # noqa: F401
     span_records,
     span_tree,
 )
-from .export import (  # noqa: F401
-    export_perfetto,
-    perfetto_trace,
-    read_jsonl,
-)
+from .export import read_jsonl  # noqa: F401
 from . import flight  # noqa: F401
 from .flight import (  # noqa: F401
     dump as flight_dump,
@@ -93,7 +89,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "registry", "metrics_snapshot", "reset_metrics",
     # export
-    "export_perfetto", "perfetto_trace", "read_jsonl",
+    "read_jsonl",
     # flight
     "flight", "flight_dump", "flight_post_mortem", "flight_tail",
     # graftscope: device-time accounting + roofline + scrape endpoint

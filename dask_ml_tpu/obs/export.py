@@ -1,4 +1,4 @@
-"""Exporters: schema-versioned JSONL event log + Chrome/Perfetto JSON.
+"""The exporter: a schema-versioned JSONL event log.
 
 **JSONL** (``DASK_ML_TPU_TRACE=path`` or ``obs.enable(jsonl_path=...)``)
 streams every completed span/event as one JSON line the moment it
@@ -7,16 +7,10 @@ first line is a header ``{"schema": "grafttrace", "version": 1, ...}``;
 :func:`read_jsonl` validates it on read-back and refuses a NEWER major
 version (an older one is fine — the schema only grows).
 
-**Perfetto** (:func:`perfetto_trace` / :func:`export_perfetto`) emits
-the Chrome ``trace_event`` format (``{"traceEvents": [...]}``, complete
-``"X"`` slices in microseconds, one ``tid`` lane per recorded thread
-with ``"M"`` thread-name metadata) plus a dedicated **device lane**
-(tid 0) built from graftscope's per-program in-flight intervals
-(:mod:`.scope`).  Load it in ui.perfetto.dev or ``chrome://tracing``:
-the host-side parse/stage/compute overlap renders directly against
-measured device occupancy — idle gaps are the white space in the
-device lane — and the whole thing still sits happily next to an XProf
-device trace of the same fit.
+To see host spans against the device there is one way, the profiler's
+own trace: ``with diagnostics.trace(dir): est.fit(X, y)`` — inside a
+profiler session every span is also a ``TraceAnnotation`` in the
+``.xplane.pb`` (:mod:`.spans`), on the device lanes' clock.
 """
 
 from __future__ import annotations
@@ -34,8 +28,6 @@ from . import spans as _spans
 __all__ = [
     "JsonlSink",
     "read_jsonl",
-    "perfetto_trace",
-    "export_perfetto",
 ]
 
 
@@ -148,126 +140,3 @@ def read_jsonl(path: str) -> tuple[dict, list[dict]]:
             continue
         records.append(obj)
     return first, records
-
-
-#: host span names that are device DISPATCH SITES — the source ends of
-#: the graftpath flow arrows into the device lane
-_FLOW_DISPATCH_NAMES = frozenset({"pipeline.compute"})
-
-
-def _json_attrs(attrs: dict) -> dict:
-    return {k: (v if isinstance(v, (str, int, float, bool, type(None)))
-                else repr(v))
-            for k, v in attrs.items()}
-
-
-def perfetto_trace(records=None, device=None) -> dict:
-    """Build a Chrome ``trace_event`` dict from grafttrace records
-    (default: everything retained in the span rings) plus a dedicated
-    **device lane** (``tid 0``, thread-name ``"device"``): one ``X``
-    slice per graftscope in-flight interval (default: the retained
-    :func:`~.scope.timeline`; pass ``device=[]`` to omit), so host
-    parse/stage overlap and device occupancy read in ONE trace — idle
-    gaps are literally the white space in that lane.
-
-    Accepts either :class:`~.spans.SpanRecord` objects or the dict form
-    (a JSONL read-back), so a trace can be re-rendered offline from the
-    event log alone (the device lane is in-process state: an offline
-    re-render passes its own interval dicts or ``[]``).
-    """
-    if records is None:
-        records = _spans.span_records()
-    if device is None:
-        from . import scope as _scope
-
-        device = _scope.timeline()
-    dicts = [r if isinstance(r, dict) else r.as_dict() for r in records]
-    if not dicts and not device:
-        return {"traceEvents": [], "displayTimeUnit": "ms"}
-    epoch = min([d["t0"] for d in dicts] + [iv["t0"] for iv in device])
-    pid = os.getpid()
-    tids: dict[str, int] = {}
-    events = []
-    for iv in device:
-        args = {"open": True} if iv.get("open") else {}
-        if "flops" in iv:
-            # per-dispatch roofline attribution rides the slice: flops,
-            # bytes, and — when the interval has real duration — the
-            # achieved GFLOP/s a trace reader can eyeball against peaks
-            args["flops"] = iv["flops"]
-            args["bytes"] = iv["bytes"]
-            dur_s = iv["t1"] - iv["t0"]
-            if dur_s > 0:
-                args["gflops_per_s"] = round(iv["flops"] / dur_s / 1e9, 3)
-        events.append({
-            "name": iv["program"], "pid": pid, "tid": 0,
-            "ts": round((iv["t0"] - epoch) * 1e6, 3),
-            "dur": round((iv["t1"] - iv["t0"]) * 1e6, 3),
-            "ph": "X",
-            "args": args,
-        })
-    for d in dicts:
-        tid = tids.setdefault(d["thread"], len(tids) + 1)
-        args = _json_attrs(d.get("attrs", {}))
-        if d.get("error"):
-            args["error"] = d["error"]
-        common = {
-            "name": d["name"], "pid": pid, "tid": tid,
-            "ts": round((d["t0"] - epoch) * 1e6, 3), "args": args,
-        }
-        if d["kind"] == "event":
-            events.append({**common, "ph": "i", "s": "t"})
-        else:
-            events.append({
-                **common, "ph": "X",
-                "dur": round((d["t1"] - d["t0"]) * 1e6, 3),
-            })
-    # graftpath flow events (design.md §19): bind each device-lane slice
-    # to the host span that was driving the device when it was enqueued
-    # — the dispatch-site spans (``pipeline.compute``) whose window
-    # contains the interval's enqueue moment.  Perfetto renders the
-    # pair as an arrow from the host lane into the device lane, so the
-    # causal chain host-step → device-program is visible in the trace,
-    # not just inferable from vertical alignment.  Ambiguity resolves
-    # to the SMALLEST containing span (the innermost dispatch scope);
-    # an interval no dispatch span contains (serve-plane dispatches, a
-    # sanitizer-hook track from an unspanned thread) gets no arrow.
-    dispatch_spans = sorted(
-        ((d["t0"], d["t1"], tids[d["thread"]]) for d in dicts
-         if d["kind"] != "event" and d["name"] in _FLOW_DISPATCH_NAMES),
-        key=lambda s: s[1] - s[0])
-    flow_id = 0
-    flows = []
-    for iv in device:
-        host = next(((t0, t1, tid) for t0, t1, tid in dispatch_spans
-                     if t0 <= iv["t0"] <= t1), None)
-        if host is None:
-            continue
-        flow_id += 1
-        ts = round((iv["t0"] - epoch) * 1e6, 3)
-        common = {"name": "graftpath", "cat": "graftpath",
-                  "pid": pid, "id": flow_id}
-        flows.append({**common, "ph": "s", "tid": host[2], "ts": ts})
-        flows.append({**common, "ph": "f", "bp": "e", "tid": 0,
-                      "ts": ts})
-    meta = [
-        {"ph": "M", "pid": pid, "tid": tid, "name": "thread_name",
-         "args": {"name": thread}}
-        for thread, tid in tids.items()
-    ]
-    if device:
-        meta.insert(0, {"ph": "M", "pid": pid, "tid": 0,
-                        "name": "thread_name", "args": {"name": "device"}})
-    return {"traceEvents": meta + events + flows,
-            "displayTimeUnit": "ms"}
-
-
-def export_perfetto(path: str | None = None, records=None,
-                    device=None) -> dict:
-    """:func:`perfetto_trace`, optionally written to ``path`` as JSON.
-    Returns the trace dict either way."""
-    trace = perfetto_trace(records, device=device)
-    if path:
-        with open(path, "w", encoding="utf-8") as f:
-            json.dump(trace, f, separators=(",", ":"))
-    return trace

@@ -1,4 +1,5 @@
-"""The one obs module that touches jax: compile-event publication.
+"""The one obs module that touches jax: compile-event publication, and
+the profiler session that arms :mod:`.spans`.
 
 ``jax.monitoring`` emits ``/jax/core/compile/backend_compile_duration``
 once per XLA backend compile (never on a cache hit) — the same signal
@@ -14,7 +15,14 @@ thread-dispatch/stage-purity reachability), and this module is imported
 lazily by :func:`~.spans.enable` and by graftsan's hook installer.
 ``install()`` is idempotent and is the SINGLE registry publisher for
 compile events — graftsan's own listener only does per-region
-attribution, so double-installation can never double-count.
+attribution, so double-installation can never double-count.  The
+listener also records a ``compile`` event onto the span open on the
+compiling thread, so ``run_report()["span_tree"]`` says which step
+recompiled.
+
+``session_check()`` / ``annotation()`` are what :mod:`.spans` needs of
+``jax.profiler.TraceAnnotation``: whether a profiler session is running
+(its static ``is_enabled()``), and a host span in that session's trace.
 """
 
 from __future__ import annotations
@@ -24,19 +32,47 @@ import threading
 from .._locks import make_lock
 
 from . import metrics as _metrics
+from . import spans as _spans
 
-__all__ = ["install", "COMPILE_EVENT"]
+__all__ = ["install", "COMPILE_EVENT", "session_check", "annotation"]
 
 #: jax.monitoring event key: one firing per XLA backend compile
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 _LOCK = make_lock("obs.jaxhooks")
 _INSTALLED = False
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, once asked for
+
+
+def _annotation_type():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
+
+def session_check():
+    """The check for "a jax profiler session is running in this
+    process": ``TraceAnnotation``'s own static ``is_enabled``, handed
+    out so that the caller pays one builtin call a time."""
+    return _annotation_type().is_enabled
+
+
+def annotation(name: str, **attrs):
+    """A ``TraceAnnotation`` for the running session (not yet entered).
+    A traced run is also when compiles should land on spans, so this
+    arms the compile listener."""
+    install()
+    return _annotation_type()(name, **attrs)
 
 
 def install() -> None:
     """Register the compile-event listener exactly once per process."""
     global _INSTALLED
+    if _INSTALLED:
+        return
     with _LOCK:
         if _INSTALLED:
             return
@@ -47,6 +83,7 @@ def install() -> None:
                 reg = _metrics.registry()
                 reg.counter("compile.count").inc()
                 reg.histogram("compile.duration_s").record(float(duration))
+                _spans.event("compile", duration_s=float(duration))
 
         _mon.register_event_duration_secs_listener(_on_event_duration)
         _INSTALLED = True

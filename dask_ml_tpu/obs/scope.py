@@ -33,7 +33,7 @@ seconds land in the metrics registry (``device.busy_s{program}``
 histograms, ``device.dispatches{program}`` counters — scraped by
 :mod:`.serve`), closed intervals in a bounded ring consumed by
 :func:`device_report` (``diagnostics.run_report()["device"]``) and
-:func:`~.export.perfetto_trace`'s dedicated device lane.
+the critical-path engine (:mod:`.critical`).
 
 Honesty contract: ``t1`` carries a detection slack of at most one
 sampler period (~2 ms) — fine for the ms-scale block programs this
@@ -92,8 +92,8 @@ SCOPE_THREAD_NAME = "dask-ml-tpu-scope"
 _SAMPLE_S = 0.002
 
 #: how many closed intervals the timeline ring retains (registry totals
-#: survive eviction; the ring bounds what device_report / the perfetto
-#: device lane can SEE, same posture as the span rings).
+#: survive eviction; the ring bounds what device_report / the critical
+#: path can SEE, same posture as the span rings).
 _RING_CAP = 8192
 
 #: sampler deaths tolerated before degrading to sweep-on-dispatch only

@@ -24,10 +24,19 @@ Parentage rules (docs/design.md §11):
 
 A span that completes with no parent by any rule is a **root**; the most
 recent root is what ``diagnostics.run_report()`` assembles into the
-per-fit tree.  Tracing is off by default: ``span()`` costs one global
-flag read and returns a shared no-op.  ``enable()`` (or a set
-``DASK_ML_TPU_TRACE``) arms recording; the conftest arms it for every
-test run so a hung test's watchdog dump can show the open span path.
+per-fit tree.  Recording is live when ``enable()`` (or a set
+``DASK_ML_TPU_TRACE``) armed it, **or while a jax profiler session is
+running** (``jax.profiler.start_trace``, ``diagnostics.trace``): the
+session is the switch, so a traced run needs no other.  Under a session
+a span that nests on its thread also opens a
+``jax.profiler.TraceAnnotation`` of the same name and attributes plus
+``fit=<root span id>``, so the program's spans lie in the ``.xplane.pb``
+on the device trace's own clock; the ring record is written either way.
+Detached spans and ``record_span`` (which do not nest on a thread) stay
+ring-only.  With neither armed ``span()`` costs one flag read and the
+session check and returns a shared no-op.  The conftest arms recording
+for every test run so a hung test's watchdog dump can show the open
+span path.
 Events additionally feed the always-on flight recorder (:mod:`.flight`)
 even while tracing is disabled — faults and checkpoints must leave a
 post-mortem regardless.
@@ -38,6 +47,7 @@ from __future__ import annotations
 import collections
 import itertools
 import os
+import sys
 import threading
 
 from .._locks import make_lock
@@ -85,18 +95,43 @@ _TLS = threading.local()  # .stack: open spans; .ring: completed records
 _REG_LOCK = make_lock("obs.spans")
 _RINGS: dict[int, tuple[str, collections.deque, list]] = {}
 _LAST_ROOT: "SpanRecord | None" = None
+#: open span id -> its root's id, so that a span parented across threads
+#: (``adopt``, ``parent=``) still names the fit it belongs to
+_OPEN_ROOTS: dict[int, int] = {}
 
 
 class _State:
-    __slots__ = ("enabled", "ring_size", "sink")
+    __slots__ = ("enabled", "ring_size", "sink", "hooks", "session")
 
     def __init__(self):
         self.enabled = False
         self.ring_size = _DEFAULT_RING
         self.sink = None  # JsonlSink | None
+        # obs.jaxhooks and its session check, once jax is in the process
+        self.hooks = self.session = None
 
 
 _STATE = _State()
+
+
+def _session() -> bool:
+    """Whether a jax profiler session is running.  Resolved through
+    :mod:`.jaxhooks` (the one obs module that touches jax) the first
+    time jax is in the process: before that no session can exist, and
+    ``obs`` itself never imports jax."""
+    running = _STATE.session
+    if running is None:
+        if "jax" not in sys.modules:
+            return False
+        from . import jaxhooks
+
+        _STATE.hooks = jaxhooks
+        running = _STATE.session = jaxhooks.session_check()
+    return running()
+
+
+def _live() -> bool:
+    return _STATE.enabled or _session()
 
 
 class SpanRecord:
@@ -172,6 +207,9 @@ class _Noop:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **attrs) -> None:
+        pass
+
 
 _NOOP = _Noop()
 
@@ -180,10 +218,10 @@ class Span:
     """An OPEN span; completes (and records) on ``__exit__``."""
 
     __slots__ = ("name", "attrs", "span_id", "parent_id", "_detached",
-                 "_t0", "_pushed")
+                 "_t0", "_pushed", "_annotate", "_annotation")
 
     def __init__(self, name: str, parent_id: int | None,
-                 detached: bool, attrs: dict):
+                 detached: bool, attrs: dict, annotate: bool = False):
         self.name = name
         self.attrs = attrs
         self.span_id = next(_ids)
@@ -191,6 +229,8 @@ class Span:
         self._detached = detached
         self._pushed = False
         self._t0 = 0.0
+        self._annotate = annotate and not detached
+        self._annotation = None
 
     def __enter__(self):
         st = None
@@ -203,11 +243,29 @@ class Span:
                     self.parent_id = getattr(_TLS, "adopt", None)
             st.append(self)
             self._pushed = True
+        root = (self.span_id if self.parent_id is None
+                else _OPEN_ROOTS.get(self.parent_id, self.parent_id))
+        _OPEN_ROOTS[self.span_id] = root
+        if self._annotate:
+            self._annotation = _STATE.hooks.annotation(
+                self.name, **self.attrs, fit=root)
+            self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
+    def set(self, **attrs) -> None:
+        """Attributes known only once the work is done (a solve's
+        counts): onto the record, and onto the open annotation."""
+        self.attrs.update(attrs)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**attrs)
+
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
+        _OPEN_ROOTS.pop(self.span_id, None)
         if self._pushed:
             self._pushed = False
             st = _stack()
@@ -239,9 +297,12 @@ def span(name: str, *, parent: int | None = None, detached: bool = False,
 
     ``detached=True`` skips the thread stack: the span is parented ONLY
     by the explicit ``parent`` and never becomes an implicit parent —
-    the form async scopes must use.  Returns a no-op when tracing is
-    disabled.
+    the form async scopes must use.  Returns a no-op when recording is
+    neither enabled nor inside a profiler session; inside one, a span
+    that is not detached also opens a ``TraceAnnotation``.
     """
+    if _session():
+        return Span(name, parent, detached, attrs, annotate=True)
     if not _STATE.enabled:
         return _NOOP
     return Span(name, parent, detached, attrs)
@@ -260,8 +321,9 @@ def record_span(name: str, t0: float, t1: float, *,
     is given — pass an explicit parent from rootless threads (dataset
     readers), or skip the call entirely when no parent exists, so a
     retroactive record can never steal ``last_root`` from a real fit.
-    No-op while tracing is disabled."""
-    if not _STATE.enabled:
+    Ring-only (it opens no annotation: the interval is already over).
+    No-op while recording is not live."""
+    if not _live():
         return
     if parent is None:
         st = getattr(_TLS, "stack", None)
@@ -280,11 +342,11 @@ def record_span(name: str, t0: float, t1: float, *,
 
 
 def event(name: str, *, parent: int | None = None, **attrs) -> None:
-    """Record a point event: onto the span tree when tracing is enabled,
-    and ALWAYS into the flight recorder (faults/checkpoints must leave a
+    """Record a point event: onto the span tree when recording is live
+    (enabled, or inside a profiler session), and ALWAYS into the flight recorder (faults/checkpoints must leave a
     post-mortem even in an untraced process)."""
     _flight.record("event", name, attrs)
-    if not _STATE.enabled:
+    if not _live():
         return
     if parent is None:
         st = getattr(_TLS, "stack", None)

@@ -115,6 +115,32 @@ def reset_dispatch_counts():
     DISPATCH_COUNTS["solves"] = 0
 
 
+#: what the counted runners (``_admm_run``, ``_lbfgs_run``) return in the
+#: place of a scalar iteration count, as one small int32 vector so that
+#: the host fetches it in the one transfer ``n_iter_`` already costs: the
+#: solver's own iterations (ADMM rounds; ``n_iter_``), the L-BFGS
+#: iterations inside them, and ``LBFGSState.n_evals`` (a lower bound on
+#: reads of the design matrix)
+SOLVE_COUNTS = ("rounds", "inner_iters", "passes")
+#: the solvers that take ``return_counts=True``
+COUNTED_SOLVERS = ("admm", "lbfgs")
+
+
+def _iterations(n_it):
+    """A runner's scalar iteration count: the counted runners return the
+    :data:`SOLVE_COUNTS` vector in its place."""
+    return n_it[0] if n_it.ndim else n_it
+
+
+def _with_counts(beta, counts, return_n_iter, return_counts):
+    """A counted solver's return value.  Counts stay on the device:
+    converting here would block the async dispatch pipeline (callers
+    convert after ALL solves)."""
+    if return_counts:
+        return beta, counts
+    return (beta, counts[0]) if return_n_iter else beta
+
+
 def _make_objective(family, reg, x, y, mask, lamduh):
     """Total objective as a traceable closure over THIS trace's arrays.
 
@@ -146,16 +172,19 @@ def _lbfgs_run(x, yv, mask, beta0, lamduh, max_iter, tol, *, family, reg,
     beta, st = lbfgs_minimize(
         obj, beta0, max_iter=max_iter, tol=tol, line_search=line_search
     )
-    return beta, st.k
+    return beta, jnp.stack([st.k, st.k, st.n_evals]).astype(jnp.int32)
 
 
 def lbfgs(X, y, *, family: type[Family] = Logistic, regularizer=L2,
           lamduh: float = 0.0, max_iter: int = 100, tol: float = 1e-5,
-          beta0=None, return_n_iter: bool = False, line_search: str = "auto"):
+          beta0=None, return_n_iter: bool = False, line_search: str = "auto",
+          return_counts: bool = False):
     """Full-gradient L-BFGS on the total (smooth) objective.
 
     Reference: ``dask_glm/algorithms.py :: lbfgs`` (scipy driver with
     distributed gradient); here the whole optimizer is one XLA program.
+    ``return_counts=True`` returns ``(beta, counts)``, the device vector
+    :data:`SOLVE_COUNTS` lays out.
 
     ``line_search="auto"`` resolves to the measured per-platform winner
     (probe_grid on TPU, backtrack on CPU — :func:`line_search_strategy`).
@@ -170,14 +199,12 @@ def lbfgs(X, y, *, family: type[Family] = Logistic, regularizer=L2,
     x, yv, mask = _prep(X, y)
     DISPATCH_COUNTS["solves"] += 1
     beta0 = _init_beta(beta0, x, family)
-    beta, n_it = _lbfgs_run(
+    beta, counts = _lbfgs_run(
         x, yv, mask, beta0, jnp.asarray(lamduh, _param_dtype(x)),
         jnp.int32(max_iter), jnp.asarray(tol, _param_dtype(x)),
         family=family, reg=reg, line_search=line_search,
     )
-    # n_it stays a device scalar: converting here would block the
-    # async dispatch pipeline (callers convert after ALL solves)
-    return (beta, n_it) if return_n_iter else beta
+    return _with_counts(beta, counts, return_n_iter, return_counts)
 
 
 # ---------------------------------------------------- gradient descent --
@@ -198,7 +225,7 @@ def _gd_run(x, yv, mask, beta0, lamduh, max_it, tol, *, family, reg,
         f, g = vg(beta)
         # c2=None: pure Armijo — the reference gradient_descent's
         # backtracking semantics, no curvature/expansion phase
-        t, f_new, _gn, failed = run_line_search(
+        t, f_new, _gn, failed, _ = run_line_search(
             line_search, vg, beta, f, g, -stepsize * g, 1e-4, 30, c2=None)
         beta_new = beta - t * stepsize * g
         stepsize_new = jnp.where(t > 0, stepsize * t * 2.0, stepsize * 0.5)
@@ -323,7 +350,7 @@ def _newton_run(x, yv, mask, beta0, lamduh, max_it, tol, *, family, reg,
         H = H + 1e-8 * jnp.eye(d, dtype=_param_dtype(x))
         p = -jnp.linalg.solve(H, g)
         # c2=None: pure Armijo (damped-Newton semantics)
-        t, f_new, _gn, failed = run_line_search(
+        t, f_new, _gn, failed, _ = run_line_search(
             line_search, vg, beta, f, g, p, 1e-4, 30, c2=None)
         return beta + t * p, f, f_new
 
@@ -403,19 +430,24 @@ def _admm_run(x, yv, mask, lamduh, rho, abstol, reltol, inner_tol, max_it,
                 (b - z_rep + u0) ** 2
             )
 
-        b_new, _ = lbfgs_minimize(
-            local_obj, b0, max_iter=inner_iter, tol=inner_tol,
-            line_search=line_search,
-        )
-        b_bar = lax.psum(b_new, row_ax) / n_shards
-        u_bar = lax.psum(u0, row_ax) / n_shards
-        z_new = reg.prox(b_bar + u_bar, lamduh / (rho_c * n_shards))
-        u_new = u0 + b_new - z_new
-        # residual pieces
-        primal_sq = lax.psum(jnp.sum((b_new - z_new) ** 2), row_ax)
-        beta_norm_sq = lax.psum(jnp.sum(b_new ** 2), row_ax)
-        u_norm_sq = lax.psum(jnp.sum(u_new ** 2), row_ax)
-        return b_new[None], u_new[None], z_new, primal_sq, beta_norm_sq, u_norm_sq
+        with jax.named_scope("admm.local_solve"):
+            b_new, st = lbfgs_minimize(
+                local_obj, b0, max_iter=inner_iter, tol=inner_tol,
+                line_search=line_search,
+            )
+        with jax.named_scope("admm.consensus"):
+            b_bar = lax.psum(b_new, row_ax) / n_shards
+            u_bar = lax.psum(u0, row_ax) / n_shards
+            z_new = reg.prox(b_bar + u_bar, lamduh / (rho_c * n_shards))
+            u_new = u0 + b_new - z_new
+            # residual pieces
+            primal_sq = lax.psum(jnp.sum((b_new - z_new) ** 2), row_ax)
+            beta_norm_sq = lax.psum(jnp.sum(b_new ** 2), row_ax)
+            u_norm_sq = lax.psum(jnp.sum(u_new ** 2), row_ax)
+            # the round lasts as long as its slowest shard's solve
+            work = lax.pmax(jnp.stack([st.k, st.n_evals]), row_ax)
+        return (b_new[None], u_new[None], z_new, primal_sq, beta_norm_sq,
+                u_norm_sq, work)
 
     step = shard_map_unchecked(
         one_shard,
@@ -436,6 +468,7 @@ def _admm_run(x, yv, mask, lamduh, rho, abstol, reltol, inner_tol, max_it,
             P(),
             P(),
             P(),
+            P(),
         ),
     )
 
@@ -445,15 +478,15 @@ def _admm_run(x, yv, mask, lamduh, rho, abstol, reltol, inner_tol, max_it,
 
     def cond(state):
         (i, _, _, _, _, primal, dual, eps_pri, eps_dual,
-         rho_moved) = state
+         rho_moved, _) = state
         return (i < max_it) & (
             (primal >= eps_pri) | (dual >= eps_dual) | rho_moved
         )
 
     def body(state):
-        i, beta_l, u_l, z, rho_c, *_ = state
+        i, beta_l, u_l, z, rho_c, *_, work = state
         z_old = z
-        beta_l, u_l, z, primal_sq, beta_sq, u_sq = step(
+        beta_l, u_l, z, primal_sq, beta_sq, u_sq, round_work = step(
             x, yv, mask, z, beta_l, u_l, rho_c
         )
         primal = jnp.sqrt(primal_sq)
@@ -502,7 +535,7 @@ def _admm_run(x, yv, mask, lamduh, rho, abstol, reltol, inner_tol, max_it,
             u_l = u_l * (rho_c / rho_new)
             rho_c = rho_new
         return (i + 1, beta_l, u_l, z, rho_c, primal, dual, eps_pri,
-                eps_dual, rho_moved)
+                eps_dual, rho_moved, work + round_work)
 
     inf = jnp.asarray(jnp.inf, _param_dtype(x))
     zero = jnp.asarray(0.0, _param_dtype(x))
@@ -515,9 +548,9 @@ def _admm_run(x, yv, mask, lamduh, rho, abstol, reltol, inner_tol, max_it,
     z0 = z_init.astype(_param_dtype(x))
     init = (jnp.int32(0), beta_l0, u_l0, z0,
             jnp.asarray(rho, _param_dtype(x)), inf, inf, zero, zero,
-            jnp.asarray(False))
+            jnp.asarray(False), jnp.zeros(2, jnp.int32))
     final = lax.while_loop(cond, body, init)
-    return final[3], final[0]
+    return final[3], jnp.concatenate([final[0][None], final[-1]])
 
 
 def admm(X, y, *, family: type[Family] = Logistic, regularizer=L2,
@@ -525,7 +558,8 @@ def admm(X, y, *, family: type[Family] = Logistic, regularizer=L2,
          abstol: float = 1e-4, reltol: float = 1e-2,
          inner_iter: int = 50, inner_tol: float = 1e-6, mesh=None,
          return_n_iter: bool = False, line_search: str = "backtrack",
-         adaptive_rho: bool = True, beta0=None):
+         adaptive_rho: bool = True, beta0=None,
+         return_counts: bool = False):
     """Consensus ADMM (Boyd et al. §8): per-shard local subproblems solved by
     the jit-safe L-BFGS inside ``shard_map``, consensus z through the
     regularizer's prox, scaled dual updates.
@@ -553,6 +587,10 @@ def admm(X, y, *, family: type[Family] = Logistic, regularizer=L2,
     Production configs with ``inner_tol > 0`` get the same early exit
     from the tolerance itself, so the default stays the conservative
     backtrack; pass ``auto``/``probe_grid`` explicitly to opt in.
+
+    ``return_counts=True`` returns ``(beta, counts)``, the device vector
+    :data:`SOLVE_COUNTS` lays out (per round the slowest shard's inner
+    iterations and evaluations, summed over the rounds).
     """
     line_search = line_search_strategy(line_search)
     reg = get_regularizer(regularizer)
@@ -560,7 +598,7 @@ def admm(X, y, *, family: type[Family] = Logistic, regularizer=L2,
     x, yv, mask = _prep(X, y)
     DISPATCH_COUNTS["solves"] += 1
     dt = _param_dtype(x)
-    beta, n_it = _admm_run(
+    beta, counts = _admm_run(
         x, yv, mask,
         jnp.asarray(lamduh, dt), jnp.asarray(rho, dt),
         jnp.asarray(abstol, dt), jnp.asarray(reltol, dt),
@@ -570,9 +608,7 @@ def admm(X, y, *, family: type[Family] = Logistic, regularizer=L2,
         inner_iter=inner_iter, line_search=line_search,
         adaptive_rho=adaptive_rho,
     )
-    # n_it stays a device scalar: converting here would block the
-    # async dispatch pipeline (callers convert after ALL solves)
-    return (beta, n_it) if return_n_iter else beta
+    return _with_counts(beta, counts, return_n_iter, return_counts)
 
 
 # ------------------------------------------------------- packed (vmap) --
@@ -754,13 +790,14 @@ def packed_solve(solver: str, X, Y, *, family: type[Family] = Logistic,
         mh = MeshHolder(mesh)
 
         def one(yv, b0):
-            return _admm_run(
+            beta, counts = _admm_run(
                 x, yv, mask, lam, jnp.asarray(rho, dt),
                 jnp.asarray(abstol, dt), jnp.asarray(reltol, dt),
                 jnp.asarray(inner_tol, dt), jnp.int32(max_iter), b0,
                 family=family, reg=reg, mesh_holder=mh,
                 inner_iter=inner_iter, line_search=line_search,
             )
+            return beta, counts[0]
 
         if strategy == "sequential":
             return _sequential(one, B0)
@@ -788,10 +825,11 @@ def packed_solve(solver: str, X, Y, *, family: type[Family] = Logistic,
     )
 
     def one(yv, b0):
-        return run(
+        beta, n_it = run(
             x, yv, mask, b0, lam, jnp.int32(max_iter),
             jnp.asarray(tol, dt), family=family, reg=reg, **extra_kw,
         )
+        return beta, _iterations(n_it)
 
     if strategy == "sequential":
         return _sequential(one, B0)
@@ -832,7 +870,7 @@ def lambda_sweep(solver: str, X, y, lams, *, family: type[Family] = Logistic,
         mh = MeshHolder(mesh)
 
         def one_a(lam):
-            return _admm_run(
+            beta, counts = _admm_run(
                 x, yd, mask, lam, jnp.asarray(rho, dt),
                 jnp.asarray(abstol, dt), jnp.asarray(reltol, dt),
                 jnp.asarray(inner_tol, dt), jnp.int32(max_iter),
@@ -840,6 +878,7 @@ def lambda_sweep(solver: str, X, y, lams, *, family: type[Family] = Logistic,
                 family=family, reg=reg, mesh_holder=mh,
                 inner_iter=inner_iter, line_search=line_search,
             )
+            return beta, counts[0]
 
         return jax.vmap(one_a)(lam_v)
     runners = {
@@ -865,9 +904,10 @@ def lambda_sweep(solver: str, X, y, lams, *, family: type[Family] = Logistic,
     )
 
     def one(lam, b0):
-        return run(
+        beta, n_it = run(
             x, yd, mask, b0, lam, jnp.int32(max_iter),
             jnp.asarray(tol, dt), family=family, reg=reg, **extra_kw,
         )
+        return beta, _iterations(n_it)
 
     return jax.vmap(one)(lam_v, B0)

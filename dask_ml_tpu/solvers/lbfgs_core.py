@@ -50,6 +50,10 @@ class LBFGSState(NamedTuple):
     k: jax.Array  # iterations taken
     n_updates: jax.Array  # history entries written
     converged: jax.Array
+    # calls of the objective or of value_and_grad at one point, a batched
+    # grid of candidate steps counting once: each reads the data at least
+    # once, so this is a lower bound on passes over the design matrix
+    n_evals: jax.Array
 
 
 def _two_loop(g, S, Y, rho, n_updates, m):
@@ -110,6 +114,7 @@ def _backtrack_wolfe(value_and_grad, x, f0, g, p, c1, c2, max_backtracks):
 
     t0 = jnp.asarray(1.0, dtype=f0.dtype)
     t, f_new, j = lax.while_loop(bt_cond, bt_body, (t0, fun(x + p), 0))
+    n_evals = 1 + j  # the unit step, then one objective call a backtrack
     failed = (j >= max_backtracks) & (f_new > f0 + c1 * t * dg)
     t = jnp.where(failed, 0.0, t)
     f_new = jnp.where(failed, f0, f_new)
@@ -129,18 +134,24 @@ def _backtrack_wolfe(value_and_grad, x, f0, g, p, c1, c2, max_backtracks):
             t = 2.0 * t
             return t, fun(x + t * p), j + 1
 
-        t, f_new, _ = lax.while_loop(ex_cond, ex_body, (t, f_new, 0))
-    return t, f_new, None, failed
+        t, f_new, j_ex = lax.while_loop(ex_cond, ex_body, (t, f_new, 0))
+        # every test of the condition (one more than the expansions
+        # taken) evaluates the gradient at t and the objective at 2t;
+        # every expansion evaluates the objective once more
+        n_evals = n_evals + 2 * (j_ex + 1) + j_ex
+    return t, f_new, None, failed, n_evals
 
 
 def run_line_search(strategy, value_and_grad, x, f0, g, p, c1,
                     max_backtracks, c2=0.9):
     """Dispatch on the STATIC strategy string.
 
-    Returns ``(t, f_new, g_new_or_None, failed)`` — ``probe_grid``
-    already evaluated the gradient at the accepted step and returns it
-    (saving the caller's recompute pass); ``backtrack`` returns None and
-    the caller evaluates once at ``x + t p``.
+    Returns ``(t, f_new, g_new_or_None, failed, n_evals)`` —
+    ``probe_grid`` already evaluated the gradient at the accepted step
+    and returns it (saving the caller's recompute pass); ``backtrack``
+    returns None and the caller evaluates once at ``x + t p``.
+    ``n_evals`` counts the search's own calls of the objective or of
+    ``value_and_grad`` (see :class:`LBFGSState`).
 
     With the weak-Wolfe conditions (Armijo + curvature
     gᵀ(x+tp)·p ≥ c2·gᵀp); ``c2=None`` (STATIC) disables the curvature
@@ -191,7 +202,7 @@ def _grid_line_search(value_and_grad, x, f0, g, p, c1, c2, max_backtracks,
 
     def accept_unit(_):
         one = jnp.asarray(1.0, f0.dtype)
-        return one, f1, g1, jnp.asarray(False)
+        return one, f1, g1, jnp.asarray(False), jnp.asarray(1)
 
     def grid(_):
         n_steps = expansions + 1 + max_backtracks
@@ -210,7 +221,8 @@ def _grid_line_search(value_and_grad, x, f0, g, p, c1, c2, max_backtracks,
         f_new = jnp.where(any_a, fs[idx], f0)
         # failed: x_new == x, so the caller's current gradient is exact
         g_new = jnp.where(any_a, gs[idx], g)
-        return t, f_new, g_new, jnp.logical_not(any_a)
+        # the unit probe, and all candidates in one batched call
+        return t, f_new, g_new, jnp.logical_not(any_a), jnp.asarray(2)
 
     return lax.cond(unit_ok, accept_unit, grid, None)
 
@@ -261,47 +273,57 @@ def lbfgs_minimize(
         k=jnp.asarray(0),
         n_updates=jnp.asarray(0),
         converged=jnp.max(jnp.abs(g0)) <= tol,
+        n_evals=jnp.asarray(1),
     )
 
     def cond(st: LBFGSState):
         return (st.k < max_iter) & jnp.logical_not(st.converged)
 
+    # the named scopes are the boundaries the device trace names after
+    # any refactor (XProf's op names under ``diagnostics.trace()``)
     def body(st: LBFGSState):
-        p = -_two_loop(st.g, st.S, st.Y, st.rho, st.n_updates, m)
-        # safeguard: if p is not a descent direction, use -g
-        descent = jnp.dot(p, st.g) < 0
-        p = jnp.where(descent, p, -st.g)
-        t, f_ls, g_ls, failed = run_line_search(
-            line_search, value_and_grad, st.x, st.f, st.g, p, c1,
-            max_backtracks,
-        )
-        x_new = st.x + t * p
-        if g_ls is None:  # static per strategy: backtrack re-evaluates
-            f_new, g_new = value_and_grad(x_new)
-        else:  # probe_grid already evaluated (f, g) at the accepted step
-            f_new, g_new = f_ls, g_ls
-        s = x_new - st.x
-        y = g_new - st.g
-        sy = jnp.dot(s, y)
-        # relative curvature condition: an absolute threshold rejects the
-        # small-but-informative steps taken in narrow valleys
-        good = sy > 1e-10 * jnp.linalg.norm(s) * jnp.linalg.norm(y)
-        pos = st.n_updates % m
-        S = jnp.where(good, st.S.at[pos].set(s), st.S)
-        Y = jnp.where(good, st.Y.at[pos].set(y), st.Y)
-        rho = jnp.where(good, st.rho.at[pos].set(1.0 / jnp.maximum(sy, 1e-12)), st.rho)
-        n_updates = st.n_updates + jnp.where(good, 1, 0)
-        rel_dec = (st.f - f_new) / jnp.maximum(
-            jnp.maximum(jnp.abs(st.f), jnp.abs(f_new)), 1.0
-        )
-        stalled = (tol > 0) & (
-            rel_dec <= 10.0 * jnp.finfo(dtype).eps
-        )
-        converged = (jnp.max(jnp.abs(g_new)) <= tol) | failed | stalled
-        return LBFGSState(
-            x=x_new, f=f_new, g=g_new, S=S, Y=Y, rho=rho,
-            k=st.k + 1, n_updates=n_updates, converged=converged,
-        )
+        with jax.named_scope("lbfgs.direction"):
+            p = -_two_loop(st.g, st.S, st.Y, st.rho, st.n_updates, m)
+            # safeguard: if p is not a descent direction, use -g
+            descent = jnp.dot(p, st.g) < 0
+            p = jnp.where(descent, p, -st.g)
+        with jax.named_scope("lbfgs.line_search"):
+            t, f_ls, g_ls, failed, n_evals = run_line_search(
+                line_search, value_and_grad, st.x, st.f, st.g, p, c1,
+                max_backtracks,
+            )
+            x_new = st.x + t * p
+            if g_ls is None:  # static per strategy: backtrack re-evaluates
+                f_new, g_new = value_and_grad(x_new)
+                n_evals = n_evals + 1
+            else:  # probe_grid already evaluated (f, g) at the accepted step
+                f_new, g_new = f_ls, g_ls
+        with jax.named_scope("lbfgs.update"):
+            s = x_new - st.x
+            y = g_new - st.g
+            sy = jnp.dot(s, y)
+            # relative curvature condition: an absolute threshold rejects the
+            # small-but-informative steps taken in narrow valleys
+            good = sy > 1e-10 * jnp.linalg.norm(s) * jnp.linalg.norm(y)
+            pos = st.n_updates % m
+            S = jnp.where(good, st.S.at[pos].set(s), st.S)
+            Y = jnp.where(good, st.Y.at[pos].set(y), st.Y)
+            rho = jnp.where(
+                good, st.rho.at[pos].set(1.0 / jnp.maximum(sy, 1e-12)),
+                st.rho)
+            n_updates = st.n_updates + jnp.where(good, 1, 0)
+            rel_dec = (st.f - f_new) / jnp.maximum(
+                jnp.maximum(jnp.abs(st.f), jnp.abs(f_new)), 1.0
+            )
+            stalled = (tol > 0) & (
+                rel_dec <= 10.0 * jnp.finfo(dtype).eps
+            )
+            converged = (jnp.max(jnp.abs(g_new)) <= tol) | failed | stalled
+            return LBFGSState(
+                x=x_new, f=f_new, g=g_new, S=S, Y=Y, rho=rho,
+                k=st.k + 1, n_updates=n_updates, converged=converged,
+                n_evals=st.n_evals + n_evals,
+            )
 
     final = lax.while_loop(cond, body, init)
     return final.x, final

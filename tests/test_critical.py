@@ -10,8 +10,7 @@ serve closed-loop run; the per-request serve split pinned
 zero steady compiles; the data plane's reorder-queue wait counting as
 FED (not idle) under graftscope; the ``data.*`` / ``search.round_s``
 families scraping through ``/metrics`` as valid Prometheus text; the
-flight-recorder dump showing OPEN device intervals; Perfetto flow
-events linking host dispatch spans to device-lane slices; and the perf
+flight-recorder dump showing OPEN device intervals; and the perf
 ratchet's v3 overlap-efficiency floor + bottleneck pin semantics.
 """
 
@@ -437,7 +436,7 @@ class TestDataPlaneHonesty:
         assert "search_round_s_count 1" in text
 
 
-# -- flight recorder + perfetto (satellite 3 + tentpole joins) -----------
+# -- flight recorder (satellite 3) ---------------------------------------
 
 class TestForensicJoins:
     def test_flight_dump_shows_open_device_interval(self):
@@ -454,32 +453,6 @@ class TestForensicJoins:
         assert "open device intervals: (none)" in \
             flight.post_mortem("after")
 
-    def test_perfetto_flow_events_link_compute_to_device_lane(self):
-        from dask_ml_tpu.linear_model import SGDClassifier
-
-        model = SGDClassifier(random_state=0)
-        stream_partial_fit(model, _sgd_blocks(4), depth=2,
-                           fit_kwargs={"classes": np.array([0, 1])})
-        scope.settle(5.0)
-        trace = obs.perfetto_trace()
-        flows = [e for e in trace["traceEvents"]
-                 if e.get("cat") == "graftpath"]
-        starts = [e for e in flows if e["ph"] == "s"]
-        ends = [e for e in flows if e["ph"] == "f"]
-        assert starts and ends
-        assert {e["id"] for e in starts} == {e["id"] for e in ends}
-        # the finish end sits on the device lane, the start on a host
-        # thread's lane
-        assert all(e["tid"] == 0 for e in ends)
-        assert all(e["tid"] != 0 for e in starts)
-        # every start lies inside a pipeline.compute slice
-        computes = [(e["ts"], e["ts"] + e["dur"], e["tid"])
-                    for e in trace["traceEvents"]
-                    if e.get("name") == "pipeline.compute"
-                    and e.get("ph") == "X"]
-        for s in starts:
-            assert any(t0 <= s["ts"] <= t1 and tid == s["tid"]
-                       for t0, t1, tid in computes)
 
 
 # -- perf ratchet v3 (satellite 6 semantics) -----------------------------

@@ -2,8 +2,7 @@
 
 Covers the acceptance criteria: a depth-2 streamed SGD fit yields ONE
 span tree with pipeline stage children + a retry event from an injected
-``FaultPlan`` fault + registry histograms with p50/p99; the Perfetto
-export of the same fit is valid ``trace_event`` JSON; tracing enabled
+``FaultPlan`` fault + registry histograms with p50/p99; tracing enabled
 stays within 3% wall of disabled; the JSONL log round-trips through its
 schema; the flight recorder leaves a post-mortem for step faults; and
 the legacy reporters keep their shapes as registry views.
@@ -12,6 +11,7 @@ the legacy reporters keep their shapes as registry views.
 import io as _io
 import json
 import math
+import os
 import threading
 import time
 
@@ -260,8 +260,7 @@ class TestRunReportAcceptance:
         """Acceptance criterion: run_report() on a depth-2 streamed SGD
         fit = ONE span tree with pipeline stage children, >=1 retry
         event from an injected FaultPlan ingest fault, and registry
-        histograms with p50/p99; the Perfetto export of the same fit
-        loads as valid trace_event JSON."""
+        histograms with p50/p99."""
         from dask_ml_tpu import io as dio
         from dask_ml_tpu.linear_model import SGDClassifier
         from dask_ml_tpu.resilience.testing import FaultPlan, fault_plan
@@ -298,22 +297,6 @@ class TestRunReportAcceptance:
         # legacy reporters unchanged shape, same store
         assert rep["pipeline"]["streams"] == 1
         assert rep["faults"]["retries"]["ingest"] == 1
-
-        # Perfetto export of the same fit: valid trace_event JSON
-        out = tmp_path / "trace.json"
-        obs.export_perfetto(str(out))
-        with open(out) as f:
-            trace = json.load(f)
-        events = trace["traceEvents"]
-        assert events, "empty perfetto export"
-        for e in events:
-            assert {"name", "ph", "pid", "tid"} <= set(e)
-            if e["ph"] == "X":
-                assert e["dur"] >= 0 and e["ts"] >= 0
-        assert any(e.get("name") == "pipeline.stream" for e in events)
-        # one tid lane per recorded thread, with thread-name metadata
-        assert any(e["ph"] == "M" and e["args"]["name"]
-                   == PREFETCH_THREAD_NAME for e in events)
 
 
 class TestStitching:
@@ -463,8 +446,7 @@ class TestJsonlExport:
     def test_multi_session_append_round_trips(self, tmp_path):
         """The sink appends: two sessions on one path (the documented
         multi-process DASK_ML_TPU_TRACE usage) leave two header lines —
-        both validated, neither returned as a record, and the combined
-        records still render as Perfetto."""
+        both validated, neither returned as a record."""
         path = str(tmp_path / "two.jsonl")
         for session in range(2):
             obs.disable()
@@ -480,25 +462,6 @@ class TestJsonlExport:
         names = [r["name"] for r in records]
         assert names == ["session0", "session1"]
         assert all("schema" not in r for r in records)
-        trace = obs.perfetto_trace(records)  # must not KeyError
-        assert len([e for e in trace["traceEvents"]
-                    if e["ph"] == "X"]) == 2
-
-    def test_perfetto_from_jsonl_records(self, tmp_path):
-        """A trace re-renders offline from the JSONL alone (dict-form
-        records accepted)."""
-        path = str(tmp_path / "t.jsonl")
-        obs.disable()
-        obs.enable(jsonl_path=path)
-        try:
-            with obs.span("offline"):
-                pass
-        finally:
-            obs.disable()
-            obs.enable()
-        _, records = obs.read_jsonl(path)
-        trace = obs.perfetto_trace(records)
-        assert any(e["name"] == "offline" for e in trace["traceEvents"])
 
 
 class TestFlightRecorder:
@@ -723,3 +686,199 @@ class TestTraceExceptionSafety:
             with diagnostics.trace("/tmp/x"):
                 raise ValueError("body failed")
         assert calls == ["start", "stop"]
+
+
+# -- ISSUE 26: a profiler session arms the spans -------------------------
+
+def _small_logistic(rows=600, features=5, seed=0):
+    r = np.random.default_rng(seed)
+    X = r.normal(size=(rows, features)).astype(np.float32)
+    y = (X @ r.normal(size=features) + r.normal(size=rows) > 0)
+    return X, y.astype(np.float32)
+
+
+def _host_events(trace_dir):
+    """``{name: [(start_ns, end_ns, {stat: value}), ...]}`` of the host
+    plane of the newest ``.xplane.pb`` under ``trace_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    out: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                out.setdefault(e.name, []).append(
+                    (e.start_ns, e.start_ns + e.duration_ns, dict(e.stats)))
+    return out
+
+
+class TestProfilerSessionArmsSpans:
+    @pytest.fixture(scope="class")
+    def traced(self, tmp_path_factory):
+        """One ADMM fit inside a profiler session with recording NOT
+        enabled: what the rings and the session's file hold."""
+        import jax
+
+        from dask_ml_tpu.linear_model import LogisticRegression
+
+        X, y = _small_logistic()
+        trace_dir = str(tmp_path_factory.mktemp("session"))
+        diagnostics.reset()
+        obs.disable()
+        try:
+            before = obs.span("ghost")
+            jax.profiler.start_trace(trace_dir)
+            try:
+                est = LogisticRegression(solver="admm").fit(X, y)
+                with obs.span("t.parent") as parent:
+                    with obs.span("t.detached", detached=True,
+                                  parent=parent.span_id):
+                        pass
+                    t = time.perf_counter()
+                    obs.record_span("t.retro", t - 1e-3, t)
+            finally:
+                jax.profiler.stop_trace()
+            after = obs.span("ghost")
+            records = [r.as_dict() for r in obs.span_records()]
+            counters = obs.metrics_snapshot()["counters"]
+        finally:
+            obs.enable()
+        return {"est": est, "records": records, "noops": (before, after),
+                "host": _host_events(trace_dir), "counters": counters}
+
+    def test_span_is_the_noop_outside_the_session(self, traced):
+        before, after = traced["noops"]
+        assert before is after and before.span_id is None
+        assert before.set(anything=1) is None  # set() is a no-op on it
+        with before as entered:
+            assert entered is before
+
+    def test_fit_records_one_root_with_its_three_children(self, traced):
+        spans = [r for r in traced["records"] if r["kind"] == "span"]
+        roots = [r for r in spans if r["name"] == "glm.fit"]
+        assert len(roots) == 1 and roots[0]["parent_id"] is None
+        root = roots[0]
+        kids = [r for r in spans if r["parent_id"] == root["span_id"]]
+        assert [k["name"] for k in kids] == [
+            "glm.classes", "glm.prepare", "glm.solve"]
+        at = root["t0"]
+        for k in kids:  # in that order, not overlapping, inside the root
+            assert at <= k["t0"] <= k["t1"] <= root["t1"]
+            at = k["t1"]
+        X, _ = _small_logistic()
+        assert root["attrs"] == {
+            "estimator": "LogisticRegression", "solver": "admm",
+            "rows": X.shape[0], "features": X.shape[1], "chips": 8,
+            "classes": 2}
+        assert kids[0]["attrs"] == {"classes": 2}
+        assert kids[1]["attrs"]["padded_rows"] >= X.shape[0]
+
+    def test_xplane_holds_the_spans_nested_under_one_fit_id(self, traced):
+        host = traced["host"]
+        names = ("glm.fit", "glm.classes", "glm.prepare", "glm.solve")
+        for name in names:
+            assert len(host[name]) == 1, name
+        (f0, f1, fstats), = host["glm.fit"]
+        root = next(r for r in traced["records"] if r["name"] == "glm.fit")
+        at = f0
+        for name in names[1:]:
+            (s, e, stats), = host[name]
+            assert at <= s <= e <= f1
+            assert stats["fit"] == root["span_id"]
+            at = e
+        assert fstats["fit"] == root["span_id"]
+        assert fstats["estimator"] == "LogisticRegression"
+        # the annotation spans the same interval as the ring record, on
+        # another clock: their durations agree
+        assert (f1 - f0) / 1e9 == pytest.approx(root["dur_s"], abs=2e-3)
+
+    def test_set_reaches_the_open_annotation(self, traced):
+        (_, _, stats), = traced["host"]["glm.solve"]
+        solve = next(r for r in traced["records"] if r["name"] == "glm.solve")
+        for count in ("rounds", "inner_iters", "passes"):
+            assert stats[count] == solve["attrs"][count] > 0
+        (_, _, fstats), = traced["host"]["glm.fit"]
+        assert fstats["rows"] == 600  # set() after entering, on the root
+
+    def test_detached_and_retroactive_spans_stay_ring_only(self, traced):
+        ring = {r["name"] for r in traced["records"]}
+        assert {"t.parent", "t.detached", "t.retro"} <= ring
+        assert "t.parent" in traced["host"]
+        assert "t.detached" not in traced["host"]
+        assert "t.retro" not in traced["host"]
+
+    def test_counts_on_the_span_and_in_the_registry(self, traced):
+        est = traced["est"]
+        solve = next(r for r in traced["records"] if r["name"] == "glm.solve")
+        a = solve["attrs"]
+        assert a["rounds"] == int(est.n_iter_[0])
+        assert a["passes"] >= a["rounds"] + a["inner_iters"]
+        c = traced["counters"]
+        assert c["solve.count"] == 1
+        for count in ("rounds", "inner_iters", "passes"):
+            assert c[f"solve.{count}"] / c["solve.count"] == a[count]
+
+
+class TestFitSpansAndCounts:
+    def _solve_span(self):
+        return next(c for c in obs.span_tree()["children"]
+                    if c["name"] == "glm.solve")
+
+    def test_identical_fits_count_the_same(self):
+        from dask_ml_tpu.linear_model import LogisticRegression
+
+        X, y = _small_logistic()
+        seen = []
+        for _ in range(2):
+            LogisticRegression(solver="admm").fit(X, y)
+            seen.append(self._solve_span()["attrs"])
+        assert seen[0] == seen[1] and seen[0]["passes"] > 0
+
+    @pytest.mark.parametrize("estimator,solver", [
+        ("LogisticRegression", "lbfgs"), ("LinearRegression", "lbfgs"),
+        ("LinearRegression", "admm")])
+    def test_counted_solvers_report_all_three(self, estimator, solver):
+        import dask_ml_tpu.linear_model as lm
+
+        X, y = _small_logistic()
+        est = getattr(lm, estimator)(solver=solver).fit(X, y)
+        a = self._solve_span()["attrs"]
+        assert a["rounds"] == int(est.n_iter_[0])
+        if solver == "lbfgs":
+            assert a["inner_iters"] == a["rounds"]
+        assert a["passes"] >= a["rounds"] + a["inner_iters"] - (
+            a["rounds"] if solver == "lbfgs" else 0)
+        tree = obs.span_tree()
+        assert tree["name"] == "glm.fit"
+        want = (["glm.classes"] if estimator == "LogisticRegression"
+                else []) + ["glm.prepare", "glm.solve"]
+        assert [c["name"] for c in tree["children"]] == want
+
+    def test_uncounted_solver_reports_its_rounds_only(self):
+        from dask_ml_tpu.linear_model import LogisticRegression
+
+        X, y = _small_logistic()
+        est = LogisticRegression(solver="newton").fit(X, y)
+        a = self._solve_span()["attrs"]
+        assert a["rounds"] == int(est.n_iter_[0])
+        assert "passes" not in a and "inner_iters" not in a
+
+    def test_compile_lands_on_the_span_that_compiled(self):
+        """A first fit at a new program shows ``compile`` events under
+        ``glm.solve``; the same fit again shows none anywhere."""
+        from dask_ml_tpu.linear_model import LogisticRegression
+
+        X, y = _small_logistic(rows=333, features=7)
+        # a static argument no other test uses: a fresh _admm_run
+        kw = dict(solver="admm", solver_kwargs={"inner_iter": 13})
+        LogisticRegression(**kw).fit(X, y)
+        events = self._solve_span()["events"]
+        assert [e["name"] for e in events].count("compile") >= 1
+        assert all(e["attrs"]["duration_s"] > 0 for e in events)
+        LogisticRegression(**kw).fit(X, y)
+        assert "compile" not in [n for n, _ in _tree_names(obs.span_tree())]

@@ -309,3 +309,105 @@ class TestLambdaSweep:
         X, y = self._data(rng)
         with pytest.raises(ValueError, match="1-D"):
             lambda_sweep("lbfgs", X, y, [[0.1, 1.0]], family=Logistic)
+
+
+class TestSolveCounts:
+    """ISSUE 26 part C: ``LBFGSState.n_evals`` and the ``SOLVE_COUNTS``
+    vector the counted runners carry out of the solve."""
+
+    @pytest.mark.parametrize("diag", [[0.6, 0.8, 1.0], [0.5, 0.75, 1.0, 0.9]])
+    @pytest.mark.parametrize("line_search,per_iter", [
+        # the unit step's objective, the curvature test's gradient at t
+        # and objective at 2t, then value_and_grad at the accepted point
+        ("backtrack", 4),
+        # the unit probe evaluates value and gradient at once
+        ("probe_grid", 1),
+    ])
+    def test_n_evals_by_hand_when_every_unit_step_is_accepted(
+            self, diag, line_search, per_iter):
+        A = jnp.diag(jnp.asarray(diag, jnp.float32))
+        x, st = lbfgs_minimize(lambda x: 0.5 * x @ A @ x,
+                               jnp.ones(len(diag)), tol=1e-6,
+                               line_search=line_search)
+        k = int(st.k)
+        assert k >= 4 and bool(st.converged)
+        # one evaluation at the start, then per_iter an iteration
+        assert int(st.n_evals) == 1 + per_iter * k
+
+    def test_a_rejected_unit_step_costs_more_evaluations(self):
+        # a steep valley: the first (gradient) step overshoots, so the
+        # search backtracks (backtrack) or pays its one batched grid
+        A = jnp.diag(jnp.asarray([1.0, 100.0], jnp.float32))
+        f = lambda x: 0.5 * x @ A @ x  # noqa: E731
+        _, bt = lbfgs_minimize(f, jnp.ones(2), tol=1e-6)
+        assert int(bt.n_evals) > 1 + 4 * int(bt.k)
+        _, grid = lbfgs_minimize(f, jnp.ones(2), tol=1e-6,
+                                 line_search="probe_grid")
+        k = int(grid.k)
+        assert 1 + k < int(grid.n_evals) <= 1 + 2 * k
+
+    @pytest.mark.parametrize("line_search", ["backtrack", "probe_grid"])
+    def test_admm_counts_vector(self, logistic_data, line_search):
+        X, y, _ = logistic_data
+        kw = dict(family=Logistic, lamduh=1.0, line_search=line_search)
+        beta, counts = solvers.admm(X, y, return_counts=True, **kw)
+        rounds, inner, passes = (int(c) for c in counts)
+        assert solvers.algorithms.SOLVE_COUNTS == (
+            "rounds", "inner_iters", "passes")
+        assert counts.dtype == jnp.int32 and counts.shape == (3,)
+        # every round evaluates once at its start and at least once an
+        # inner iteration
+        assert rounds >= 1 and passes >= rounds + inner
+        beta2, n_it = solvers.admm(X, y, return_n_iter=True, **kw)
+        assert int(n_it) == rounds  # the scalar contract is unchanged
+        np.testing.assert_array_equal(np.asarray(beta), np.asarray(beta2))
+        # the same problem counts the same
+        _, again = solvers.admm(X, y, return_counts=True, **kw)
+        np.testing.assert_array_equal(np.asarray(counts), np.asarray(again))
+
+    def test_lbfgs_counts_vector(self, logistic_data):
+        X, y, _ = logistic_data
+        _, counts = solvers.lbfgs(X, y, lamduh=1.0, return_counts=True,
+                                  line_search="backtrack")
+        rounds, inner, passes = (int(c) for c in counts)
+        _, n_it = solvers.lbfgs(X, y, lamduh=1.0, return_n_iter=True,
+                                line_search="backtrack")
+        assert rounds == inner == int(n_it) and passes >= 1 + inner
+
+    @pytest.mark.parametrize("solver", ["admm", "lbfgs"])
+    @pytest.mark.parametrize("strategy", ["packed", "sequential"])
+    def test_packed_solve_keeps_scalar_iterations(
+            self, logistic_data, monkeypatch, solver, strategy):
+        from dask_ml_tpu.solvers import packed_solve
+
+        monkeypatch.setenv("DASK_ML_TPU_PACK", strategy)
+        X, y, _ = logistic_data
+        sX = shard_rows(X)
+        Y = np.zeros((2, sX.data.shape[0]), np.float32)
+        Y[0, :len(y)], Y[1, :len(y)] = y, 1 - y
+        betas, n_its = packed_solve(solver, sX, jnp.asarray(Y),
+                                    family=Logistic, lamduh=1.0,
+                                    line_search="backtrack")
+        assert betas.shape == (2, X.shape[1]) and n_its.shape == (2,)
+        for lane in range(2):
+            single, n_it = getattr(solvers, solver)(
+                sX, Y[lane], family=Logistic, lamduh=1.0,
+                return_n_iter=True, line_search="backtrack")
+            assert int(n_its[lane]) == int(n_it)
+            np.testing.assert_allclose(np.asarray(betas[lane]),
+                                       np.asarray(single), atol=2e-4)
+
+    @pytest.mark.parametrize("solver", ["admm", "lbfgs"])
+    def test_lambda_sweep_keeps_scalar_iterations(
+            self, logistic_data, solver):
+        X, y, _ = logistic_data
+        lams = [0.1, 1.0, 10.0]
+        betas, n_its = lambda_sweep(solver, X, y, lams, family=Logistic)
+        assert betas.shape == (3, X.shape[1]) and n_its.shape == (3,)
+        for lane, lam in enumerate(lams):
+            single, n_it = getattr(solvers, solver)(
+                X, y, family=Logistic, lamduh=lam, return_n_iter=True,
+                line_search="backtrack")
+            assert int(n_its[lane]) == int(n_it)
+            np.testing.assert_allclose(np.asarray(betas[lane]),
+                                       np.asarray(single), atol=2e-4)
