@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from ..base import ClassifierMixin, RegressorMixin, TPUEstimator, clone
-from ..core.sharded import ShardedRows, unshard
+from ..core.sharded import ShardedRows, masked_unique, unshard
 from ..utils import check_max_iter
 from .. import sanitize as _san
 
@@ -42,14 +42,6 @@ def _to_host_pair(X, y):
     Xh = unshard(X) if isinstance(X, ShardedRows) else np.asarray(X)
     yh = unshard(y) if isinstance(y, ShardedRows) else (np.asarray(y) if y is not None else None)
     return Xh, yh
-
-
-def _device_classes(y: ShardedRows) -> np.ndarray:
-    """Class inventory of device-resident labels without an O(n) fetch —
-    pad rows are remapped to the first real label so padding cannot mint
-    a phantom class (same pattern as linear_model.glm)."""
-    yd = jnp.where(y.mask > 0, y.data, y.data[0])
-    return np.asarray(jnp.unique(yd))
 
 
 # One compiled program per (loss, penalty, schedule, fit_intercept, shapes)
@@ -192,7 +184,7 @@ class _BlockwiseBase(TPUEstimator):
             if "classes" in kwargs:
                 classes = np.sort(np.asarray(kwargs["classes"]))
             elif isinstance(y, ShardedRows):
-                classes = _device_classes(y)
+                classes = masked_unique(y.data, y.mask)
             else:
                 classes = np.unique(np.asarray(ydata))
             for m in members:
@@ -256,7 +248,7 @@ class BlockwiseVotingClassifier(ClassifierMixin, _BlockwiseBase):
         if self.classes is not None:
             self.classes_ = np.unique(np.asarray(self.classes))
         elif isinstance(y, ShardedRows):
-            self.classes_ = _device_classes(y)
+            self.classes_ = masked_unique(y.data, y.mask)
         else:
             self.classes_ = np.unique(np.asarray(y))
         return self

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..base import ClassifierMixin, RegressorMixin, TPUEstimator
-from ..core.sharded import ShardedRows
+from ..core.sharded import ShardedRows, masked_unique
 from ..preprocessing.data import _ingest_float
 from .. import obs as _obs
 from .. import sanitize as _san
@@ -312,8 +312,7 @@ class LogisticRegression(ClassifierMixin, _GLM):
 
         y = as_sharded(y)
         if isinstance(y, _SR):
-            yd = jnp.where(y.mask > 0, y.data, y.data[0])
-            classes = np.asarray(jnp.unique(yd))
+            classes = masked_unique(y.data, y.mask)
         else:
             classes = np.unique(np.asarray(y))
         if len(classes) != 2:
@@ -358,15 +357,16 @@ class LogisticRegression(ClassifierMixin, _GLM):
             # raw device label vectors ride the ShardedRows no-fetch paths
             y = as_sharded(y)
             if isinstance(y, _SR):
-                # device-side class discovery: only the unique label
-                # VALUES cross to host (a handful of scalars), never the
-                # n-row label vector — a full unshard of device-resident
-                # labels is an O(n) device->host transfer, and illegal for
-                # multi-host global arrays.  Pad rows are remapped to the
-                # first (real) label so padding cannot mint a phantom
-                # class.
-                yd = jnp.where(y.mask > 0, y.data, y.data[0])
-                self.classes_ = np.asarray(jnp.unique(yd))
+                # device-side class discovery: one program scans the
+                # sharded labels for their distinct values in ascending
+                # order (a masked min per value, so pad rows are no
+                # class) and only those VALUES cross to host in one
+                # fetch, never the n-row label vector — a full unshard of
+                # device-resident labels is an O(n) device->host
+                # transfer, and illegal for multi-host global arrays.
+                # Only a vector of more values than the scan holds, or
+                # with a NaN, is sorted (core.sharded.masked_unique).
+                self.classes_ = masked_unique(y.data, y.mask, span)
                 yv = None
             else:
                 yv = np.asarray(y)

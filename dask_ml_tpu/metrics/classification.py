@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.sharded import ShardedRows
+from ..core.sharded import ShardedRows, masked_unique
 
 
 def _lengths(a):
@@ -127,10 +127,10 @@ def _class_inventory(t, p, mask, labels):
         # CALLER's order is the output order for average=None (sklearn
         # contract) — do not sort
         return np.asarray(labels)
-    fill = t[0]
-    tv = jnp.where(mask > 0, t, fill)
-    pv = jnp.where(mask > 0, p, fill)
-    return np.union1d(np.asarray(jnp.unique(tv)), np.asarray(jnp.unique(pv)))
+    found = masked_unique(t, mask)
+    if p is t:  # one vector (roc_auc_score's y_true): one scan
+        return found
+    return np.union1d(found, masked_unique(p, mask))
 
 
 def _indicator_matrices(y_true, y_pred, sample_weight, labels):

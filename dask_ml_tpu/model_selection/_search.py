@@ -28,7 +28,7 @@ from concurrent.futures import ThreadPoolExecutor, as_completed
 import numpy as np
 
 from ..base import TPUEstimator, clone
-from ..core.sharded import ShardedRows, unshard
+from ..core.sharded import ShardedRows, masked_unique, unshard
 from ..metrics.scorer import check_scoring
 from ..utils import check_random_state
 from ._split import _take as _rows  # pandas/array/ShardedRows row subset
@@ -144,14 +144,13 @@ def _fold_classes_ok(ytr, yte) -> bool:
     import jax.numpy as jnp
 
     if isinstance(ytr, ShardedRows):
-        ytr_d = jnp.where(ytr.mask > 0, ytr.data, ytr.data[0])
-        classes = jnp.unique(ytr_d)
+        classes = masked_unique(ytr.data, ytr.mask)
         if classes.shape[0] != 2:
             return False
         if isinstance(yte, ShardedRows):
             ok = jnp.all((yte.mask <= 0) | jnp.isin(yte.data, classes))
             return bool(ok)
-        return bool(np.isin(np.asarray(yte), np.asarray(classes)).all())
+        return bool(np.isin(np.asarray(yte), classes).all())
     classes = np.unique(np.asarray(ytr))
     if classes.shape[0] != 2:
         return False
