@@ -1,0 +1,32 @@
+"""k-means|| layer: from the first candidate's draw to the starting
+centres on the host (``cluster/k_means.py :: init_scalable``: two
+programs, two fetches, sklearn's weighted k-means++ on the candidates).
+
+Read from the program's own spans (``dask_ml_tpu/obs/spans.py``, live
+while the profiler session of a ``--trace 1`` run is on): the duration of
+``kmeans.init`` in each traced fit's ``kmeans.fit`` tree, mean over those
+fits, in ms.  Nothing to read without a trace or where the program opens
+no such span (a parent commit that has none)."""
+
+
+def fit_trees(ctx):
+    """The span trees of the traced fits: the last ``kmeans.fit`` roots
+    the program recorded, as many as the trace holds ``bench.fit`` spans."""
+    if not ctx["trace"]:
+        return []
+    from dask_ml_tpu import obs
+
+    roots = [r for r in obs.span_records()
+             if r.name == "kmeans.fit" and r.parent_id is None]
+    return [obs.span_tree(r) for r in roots[-len(ctx["trace"]["fits"]):]]
+
+
+def child(tree, name):
+    return next((c for c in tree["children"] if c["name"] == name), None)
+
+
+def read(ctx):
+    spans = [child(t, "kmeans.init") for t in fit_trees(ctx)]
+    if not spans or None in spans:
+        return None
+    return 1e3 * sum(s["dur_s"] for s in spans) / len(spans)

@@ -7,23 +7,27 @@ tree-reduced center updates (``_kmeans_single_lloyd``); SURVEY.md §3.2.
 TPU design: one jitted SPMD step per Lloyd round — the pairwise-distance
 gemm rides the MXU, per-cluster sums are a one-hot matmul (another gemm),
 and the k×d/k reductions are psums over ICI inserted by XLA.  The k-means‖
-rounds reuse the same distance kernel with a per-shard PRNG for candidate
-sampling; only the (tiny) candidate set ever reaches the host, where the
-final weighted k-means++ runs exactly as the reference does it.
+rounds are one per-shard program that carries every row's least distance
+and nearest candidate, with a per-shard PRNG for candidate sampling; only
+the (tiny) candidate set ever reaches the host, where the final weighted
+k-means++ runs exactly as the reference does it.
 """
 
 from __future__ import annotations
 
 import logging
-from functools import partial as _fpartial
-
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
+from jax.sharding import PartitionSpec as P
+
+from .. import obs as _obs
 from ..base import TPUEstimator, TransformerMixin
-from ..core.prng import as_key
+from ..core.compat import shard_map_unchecked as _shard_map
+from ..core.mesh import MeshHolder, data_axes, data_axes_size, get_mesh
+from ..core.prng import as_key, fold_in_shard
 from ..core.sharded import ShardedRows, unshard
 from ..preprocessing.data import _ingest_float as _ingest_float_any
 from ..utils import _timer, safe_denominator
@@ -69,10 +73,10 @@ def _kmeans_mode() -> str:
       bf16-split gemm (both operands split: the one-hot side carries the
       sample-weight mask).  6 MXU passes per round instead of 12; on
       MXU-bound shapes (k ≥ ~32) this can halve round time at
-      k-means-irrelevant precision cost.  Chip-adjudicated: 1.36–1.44×
-      faster at 1M×64 k=64 in 3 of 4 sessions (docs/design.md, round-5
-      chip table); the default stays ``highest`` as a deliberate
-      precision-contract exception.
+      k-means-irrelevant precision cost.  No reading of it stands in
+      ``PERF.md`` (the benchmark's k-means cell runs ``highest``); the
+      default stays ``highest`` as a deliberate precision-contract
+      exception.
     """
     import os
 
@@ -85,6 +89,23 @@ def _kmeans_mode() -> str:
     return v
 
 
+#: up to this many clusters the Lloyd reduce sums offsets from per-cluster
+#: anchors, picked for every row by a chain of selects that fuses into the
+#: reduce's operand; a gather of more (or its gemm form) would be made in
+#: memory beside the table, and their sums are shorter by 1/k anyway
+_ANCHOR_MAX_CLUSTERS = 16
+
+
+def _row_anchors(anchors, labels):
+    """``anchors[labels]``, (n, d), as a chain of ``k - 1`` selects:
+    elementwise, so it fuses into whatever consumes it and is never made
+    in memory."""
+    own = jnp.broadcast_to(anchors[0], (labels.shape[0], anchors.shape[1]))
+    for j in range(1, anchors.shape[0]):
+        own = jnp.where((labels == j)[:, None], anchors[j], own)
+    return own
+
+
 def _lloyd_step_fn(x, mask, centers, *, mode="highest", scatter="segsum"):
     """One Lloyd round: assign, reduce per-cluster sums/counts, update.
 
@@ -92,17 +113,18 @@ def _lloyd_step_fn(x, mask, centers, *, mode="highest", scatter="segsum"):
     sharded x the per-cluster reductions become ICI psums.  ``mode`` is
     static (see ``_kmeans_mode``).
     """
-    if mode == "fast":
-        d2 = _sq_euclidean(x, centers, precision=jax.lax.Precision.HIGH)
-    else:
-        d2 = _sq_dists(x, centers)
-    labels = jnp.argmin(d2, axis=1)
-    # jnp.min selects the SAME element as d2[argmin] but lowers to a fused
-    # reduce; a take_along_axis gather here costs ~14 ms/round on a v5e
-    # (11x the whole rest of the step) because XLA:TPU lowers dynamic
-    # row-gathers serially
-    min_d2 = jnp.min(d2, axis=1)
-    inertia = jnp.sum(min_d2 * mask)
+    with jax.named_scope("lloyd.assign"):
+        if mode == "fast":
+            d2 = _sq_euclidean(x, centers, precision=jax.lax.Precision.HIGH)
+        else:
+            d2 = _sq_dists(x, centers)
+        labels = jnp.argmin(d2, axis=1)
+        # jnp.min selects the SAME element as d2[argmin] but lowers to a
+        # fused reduce; a take_along_axis gather here costs ~14 ms/round on
+        # a v5e (11x the whole rest of the step) because XLA:TPU lowers
+        # dynamic row-gathers serially
+        min_d2 = jnp.min(d2, axis=1)
+        inertia = jnp.sum(min_d2 * mask)
     # per-cluster reduce through the shared scatter policy (ops.scatter):
     # one-hot gemm on the MXU or segment_sum, whichever the platform
     # measurement favors.  Precision on the gemm path: HIGH in fast mode
@@ -116,13 +138,31 @@ def _lloyd_step_fn(x, mask, centers, *, mode="highest", scatter="segsum"):
     k_ = centers.shape[0]
     prec = (jax.lax.Precision.HIGH if mode == "fast"
             else jax.lax.Precision.HIGHEST)
-    sums = bucket_sum(x * mask[:, None], labels, k_, precision=prec,
-                      strategy=scatter)
-    counts = bucket_sum(mask, labels, k_,
-                        precision=jax.lax.Precision.HIGHEST,
-                        strategy=scatter)  # (k,)
+    with jax.named_scope("lloyd.reduce"):
+        counts = bucket_sum(mask, labels, k_,
+                            precision=jax.lax.Precision.HIGHEST,
+                            strategy=scatter)  # (k,)
+        # mean = a + mean(x - a): what is summed is every row's offset
+        # from an ANCHOR near its current centre, not the row.  A
+        # cluster's sum of rows grows to rows x |centre| and float32
+        # accumulation on the MXU loses what lies under its last bit
+        # (measured on a v5e at 25M x 50: centres off by 2e-3 relative,
+        # PERF.md PR 28); the offsets' accumulator stays 2^-8 of that.
+        # The anchor is the centre rounded to bfloat16, so it stands
+        # still once the centre has settled: the same labels then give
+        # the same sums bit for bit, and Lloyd reaches its exact fixed
+        # point (shift == 0) as it does on sums of rows.
+        if k_ <= _ANCHOR_MAX_CLUSTERS:
+            anchor = jax.lax.reduce_precision(centers, exponent_bits=8,
+                                              mantissa_bits=7)
+            offsets = x - _row_anchors(anchor, labels)
+        else:
+            anchor, offsets = 0.0, x
+        moved = bucket_sum(offsets * mask[:, None], labels, k_,
+                           precision=prec, strategy=scatter)
     safe = safe_denominator(counts)[:, None]
-    new_centers = jnp.where(counts[:, None] > 0, sums / safe, centers)
+    new_centers = jnp.where(counts[:, None] > 0, anchor + moved / safe,
+                            centers)
     shift = jnp.sum((new_centers - centers) ** 2)
     return new_centers, inertia, shift
 
@@ -144,15 +184,11 @@ _lloyd_step = _programs.cached_program(
 )
 
 
-# A fused Pallas Lloyd kernel (ops/lloyd.py) lived here through rounds
-# 2-5 and was DELETED after its win-or-delete chip adjudication: on a
-# TPU v5e the XLA lowering of ``_lloyd_step`` beat every kernel variant
-# — 0.089-0.176x at 2Mx50 k=8 and 0.198x (fast) at 1Mx64 k=64, where
-# lane padding vanishes and the kernel was predicted to win.  XLA's
-# fusion already keeps the round at ~2 HBM passes, so the kernel had no
-# traffic to remove and its Mosaic gemms lost to XLA's MXU scheduling.
-# Full numbers: docs/design.md "Pallas negative result"; resurrection is
-# one git revert away.
+# A fused Pallas Lloyd kernel (ops/lloyd.py) lived here and was deleted
+# after it lost to the XLA lowering of ``_lloyd_step`` on a v5e: XLA's
+# fusion already keeps the round at about two HBM passes, so the kernel
+# had no traffic to remove.  Its records went with PR 21; what the chip
+# reads of the Lloyd round today is in PERF.md section 5.
 
 
 def _lloyd_loop_fn(x, mask, centers, tol, max_iter, *,
@@ -223,97 +259,304 @@ def _assign_fn(x, mask, centers):
 _assign = _programs.cached_program(_assign_fn, name="kmeans.assign")
 
 
-def _valid_d2(x, centers, cvalid):
-    """Distances with INVALID candidate slots pushed out of every min/argmin.
-    The sentinel is +inf selected via ``where`` — never ADDED or multiplied
-    (an additive 1e30 overflows to inf in float16 and 0*inf = NaN would
-    poison every distance; a finite dtype-max sentinel can be beaten by
-    legitimate large distances).  Slot 0 is always valid, so min/argmin
-    always land on a real candidate."""
-    d2 = _sq_dists(x, centers)
-    return jnp.where(cvalid[None, :] > 0, d2, jnp.asarray(jnp.inf, x.dtype))
+# ---------------------------------------------------------------------------
+# k-means|| (Bahmani et al. 2012) in memory bounded by the table
+# ---------------------------------------------------------------------------
+#
+# What is carried across rounds is, per row, the least squared distance to
+# any candidate so far and the slot of that candidate:
+# d2(x, C u C') = min(d2(x, C), d2(x, C')), so a round computes distances to
+# its own at most ``cap`` new slots and folds them in.  Candidates live in
+# one buffer of ``1 + max_rounds * cap`` slots with a validity vector, so one
+# compiled program serves every round, and the candidates' weights are a
+# histogram of the carried slots: no array of rows x slots exists anywhere.
+# Every step is written per shard (``shard_map``): a shard draws, compacts
+# and gathers among its own rows, and what crosses chips is a round's
+# ``shards x cap`` surviving candidate rows, phi and the weights.
+
+#: rows of one block of the first-selected search (``_first_selected``)
+_SELECT_BLOCK = 1024
+#: the largest float32 under 2**32: a probability on the scale of 32 bits
+_BELOW_2_32 = 4294967040.0
 
 
-@jax.jit
-def _phi_and_mind2(x, mask, centers, cvalid):
-    """φ and per-row min distance against only the VALID candidate rows
-    (fixed-capacity compaction pads the candidate set)."""
-    min_d2 = jnp.min(_valid_d2(x, centers, cvalid), axis=1) * mask
-    return jnp.sum(min_d2), min_d2
+def _drawn(bits, p):
+    """Bernoulli(min(p, 1)) of 32 random bits.  A float32 uniform steps by
+    2^-23 and a k-means|| round's p is about ell / rows, so ``u < p``
+    would draw half as many rows again from a 100M-row table as the law
+    says (read on four chips, PERF.md PR 28)."""
+    steps = jnp.round(jnp.minimum(p, 1.0) * _BELOW_2_32).astype(bits.dtype)
+    return (bits < steps) | (p >= 1.0)
 
 
-@_fpartial(jax.jit, static_argnames=("cap",))
-def _sample_candidates(x, mask, u, p, *, cap):
-    """Fixed-size device-side compaction of the Bernoulli draw: the rows
-    with u < p rank first under ``score = selected·(1+u)``; top_k pulls at
-    most ``cap`` of them into a static-shape block with a validity mask.
-    Nothing of O(n) leaves the device (VERDICT round-1 weak #8: the old
-    path shipped a length-n boolean vector to host every round)."""
-    sel = ((u < p) & (mask > 0)).astype(x.dtype)
-    score = sel * (1.0 + u)
-    vals, idx = jax.lax.top_k(score, cap)
-    valid = (vals > 0.0).astype(x.dtype)
-    rows = jnp.take(x, idx, axis=0)
-    return rows, valid
+def _unit_interval(bits):
+    """32 random bits as float32 in (0, 1]: exact steps of 2^-32 near 0."""
+    return (bits.astype(jnp.float32) + 0.5) * 2.0 ** -32
+
+
+def _new_d2(x, x_norm, rows, valid):
+    """Squared distances of ``x`` to one round's candidate ``rows``, with
+    INVALID slots pushed out of every min/argmin.  The sentinel is +inf
+    selected via ``where`` -- never added or multiplied (0 * inf = NaN
+    would poison every distance; a finite dtype-max sentinel can be
+    beaten by legitimate large distances)."""
+    r_norm = jnp.sum(rows * rows, axis=1)
+    d2 = x_norm[:, None] + r_norm[None, :] - 2.0 * jnp.dot(
+        x, rows.T, precision=jax.lax.Precision.HIGHEST)
+    return jnp.where(valid[None, :], jnp.maximum(d2, 0.0),
+                     jnp.asarray(jnp.inf, x.dtype))
+
+
+def _first_selected(sel, k: int):
+    """Positions of the first ``k`` true entries of ``sel`` (n,), and how
+    many of the ``k`` there are -- a compaction without a sort.
+
+    Two levels: the count of each block of ``_SELECT_BLOCK`` entries (one
+    reduction over ``sel``), a running sum over the blocks, and for each
+    wanted rank a binary search for its block and a running sum inside
+    that one block.  A ``top_k`` over the scores reads the same slots but
+    sorts n of them."""
+    n = sel.shape[0]
+    block = min(_SELECT_BLOCK, n)
+    n_blocks = -(-n // block)
+    s = jnp.pad(sel, (0, n_blocks * block - n)).reshape(n_blocks, block)
+    upto = jnp.cumsum(jnp.sum(s, axis=1, dtype=jnp.int32))  # inclusive
+    want = jnp.arange(1, k + 1, dtype=jnp.int32)  # the j-th selected row
+    blk = jnp.minimum(jnp.searchsorted(upto, want, side="left"),
+                      n_blocks - 1).astype(jnp.int32)
+    before = jnp.where(blk > 0, upto[jnp.maximum(blk - 1, 0)], 0)
+    inside = s[blk]  # (k, block)
+    rank = jnp.cumsum(inside.astype(jnp.int32), axis=1)
+    pos = jnp.argmax(inside & (rank == (want - before)[:, None]), axis=1)
+    found = want <= upto[-1]
+    return jnp.where(found, blk * block + pos.astype(jnp.int32), 0), found
+
+
+def _gather_candidates(x, sel, cap: int, row_ax):
+    """One round's candidates: each shard takes the first ``cap`` of its
+    own selected rows, and of the ``shards x cap`` survivors (all that
+    crosses chips) every shard keeps the same first ``cap``."""
+    idx, found = _first_selected(sel, cap)
+    rows = jnp.where(found[:, None], jnp.take(x, idx, axis=0), 0.0)
+    rows = jax.lax.all_gather(rows, row_ax, tiled=True)
+    found = jax.lax.all_gather(found, row_ax, tiled=True)
+    keep, valid = _first_selected(found, cap)
+    return jnp.where(valid[:, None], jnp.take(rows, keep, axis=0), 0.0), valid
+
+
+def _init_first_fn(x, mask, key, *, mesh_holder):
+    """The first candidate, one real row drawn with probability
+    proportional to its weight (each shard's winner of an exponential
+    race, then the winner of those), every row's squared distance to it,
+    and phi."""
+    mesh = mesh_holder.mesh
+    row_ax = data_axes(mesh)
+
+    def local(x_l, m_l, key):
+        # an exponential race, time / weight, the least wins: the winners
+        # are the draws nearest 0, where float32 is fine-grained
+        wait = -jnp.log1p(-_unit_interval(jax.random.bits(
+            jax.random.fold_in(fold_in_shard(key, row_ax), 0), m_l.shape)))
+        wait = jnp.where(m_l > 0, wait / m_l, jnp.inf)
+        best = jnp.argmin(wait)
+        waits = jax.lax.all_gather(wait[best], row_ax)
+        rows = jax.lax.all_gather(x_l[best], row_ax)
+        first = rows[jnp.argmin(waits)]
+        with jax.named_scope("kmeansll.distances"):
+            d2 = _new_d2(x_l, jnp.sum(x_l * x_l, axis=1), first[None, :],
+                         jnp.ones((1,), bool))[:, 0]
+            phi = jax.lax.psum(jnp.sum(d2 * m_l), row_ax)
+        return first, d2, phi
+
+    return _shard_map(
+        local, mesh, in_specs=(P(row_ax, None), P(row_ax), P()),
+        out_specs=(P(), P(row_ax), P()))(x, mask, key)
+
+
+# no donation: the one output of a row's shape (the distances) has the
+# mask's, and the mask stays live in the caller
+# graftlint: disable=donation-miss -- the only same-shape input (mask) stays live in fit; x and key are larger/smaller than every output
+_init_first = _programs.cached_program(
+    _init_first_fn, name="kmeans.init_first",
+    static_argnames=("mesh_holder",))
+
+
+def _row_norms_fn(x):
+    return jnp.sum(x * x, axis=1)
+
+
+# Every round's distances want |x|^2.  A program of its own, queued
+# behind ``kmeans.init_first`` BEFORE the host waits for phi: the chip
+# reads X for it while the host wakes up, sizes the buffer and dispatches
+# the rounds (2.7 ms of idle a fit on a v5e's host, PERF.md PR 28).
+# graftlint: disable=donation-miss -- the one input (the table) stays live; the output is a column
+_row_norms = _programs.cached_program(_row_norms_fn, name="kmeans.row_norms")
+
+
+def _init_rounds_fn(x, mask, x_norm, first, min_d2, key, n_rounds, *, ell,
+                    cap, max_rounds, mesh_holder, scatter):
+    """Every k-means|| round after the first candidate, and the
+    candidates' weights, as ONE program: ``n_rounds`` is a device scalar
+    (at most ``max_rounds``, which sizes the buffer), so the rounds are a
+    ``while_loop`` as the Lloyd iterations are.
+
+    A round draws each row with ``p = min(ell * w * d2 / phi, 1)``, keeps
+    at most ``cap`` of the drawn rows in the round's own slots, computes
+    distances to those slots alone and folds them into the carried least
+    distance and nearest slot.  Returns ``(candidates, valid, weights,
+    rounds)``: the whole buffer, which slots hold a row, for each slot
+    the summed weight of the rows nearest to it, and the rounds run."""
+    from ..ops.scatter import bucket_sum
+
+    mesh = mesh_holder.mesh
+    row_ax = data_axes(mesh)
+    slots = 1 + max_rounds * cap
+
+    def local(x_l, m_l, x_norm, d2_l, first, key, n_rounds):
+        key = fold_in_shard(key, row_ax)
+
+        def total(d2):
+            return jax.lax.psum(jnp.sum(d2 * m_l), row_ax)
+
+        def cond(state):
+            r, phi = state[0], state[1]
+            return (r < n_rounds) & (phi > 0)
+
+        def body(state):
+            r, phi, d2, nearest, cand, cvalid = state
+            with jax.named_scope("kmeansll.sample"):
+                # stream 0 of this shard's key drew the first candidate
+                bits = jax.random.bits(jax.random.fold_in(key, r + 1),
+                                       m_l.shape)
+                sel = _drawn(bits, ell * d2 * m_l / phi) & (m_l > 0)
+                rows, valid = _gather_candidates(x_l, sel, cap, row_ax)
+            base = 1 + r * cap
+            with jax.named_scope("kmeansll.distances"):
+                new = _new_d2(x_l, x_norm, rows, valid)
+                least = jnp.min(new, axis=1)
+                closer = least < d2
+                nearest = jnp.where(
+                    closer, base + jnp.argmin(new, axis=1).astype(jnp.int32),
+                    nearest)
+                d2 = jnp.where(closer, least, d2)
+                phi = total(d2)
+            cand = jax.lax.dynamic_update_slice(cand, rows, (base, 0))
+            cvalid = jax.lax.dynamic_update_slice(cvalid, valid, (base,))
+            return r + 1, phi, d2, nearest, cand, cvalid
+
+        cand = jnp.zeros((slots, x_l.shape[1]), x_l.dtype).at[0].set(first)
+        cvalid = jnp.zeros((slots,), bool).at[0].set(True)
+        state = (jnp.int32(0), total(d2_l), d2_l,
+                 jnp.zeros(m_l.shape, jnp.int32), cand, cvalid)
+        rounds, _, _, nearest, cand, cvalid = jax.lax.while_loop(
+            cond, body, state)
+        with jax.named_scope("kmeansll.weigh"):
+            weights = jax.lax.psum(
+                bucket_sum(m_l, nearest, slots, strategy=scatter,
+                           precision=jax.lax.Precision.HIGHEST), row_ax)
+        return cand, cvalid, weights, rounds
+
+    return _shard_map(
+        local, mesh,
+        in_specs=(P(row_ax, None), P(row_ax), P(row_ax), P(row_ax), P(), P(),
+                  P()),
+        out_specs=(P(), P(), P(), P()),
+    )(x, mask, x_norm, min_d2, first, key, n_rounds)
+
+
+# no donation: no output has a row's shape, so there is nothing for
+# ``min_d2``'s buffer to alias
+# graftlint: disable=donation-miss -- outputs (candidate buffer, weights) are smaller than every row-sized input
+_init_rounds = _programs.cached_program(
+    _init_rounds_fn, name="kmeans.init_scalable",
+    static_argnames=("ell", "cap", "max_rounds", "mesh_holder", "scatter"),
+)
+
+#: the candidate buffer holds this many rounds or a multiple of it, so
+#: that tables whose ``ceil(ln phi)`` differ by a little share one program
+_ROUNDS_QUANTUM = 8
 
 
 def init_scalable(X: ShardedRows, n_clusters: int, key, oversampling_factor=2,
                   init_max_iter=None):
-    """k-means‖ (Bahmani et al. 2012) — reference ``k_means.py :: init_scalable``.
+    """k-means|| (Bahmani et al. 2012) -- reference ``k_means.py :: init_scalable``.
 
-    Device side: distance/φ reductions, per-row Bernoulli sampling AND the
-    candidate compaction (fixed-capacity top-k per round, so shapes stay
-    static and only O(1) scalars sync per round).  Host side: only the
-    final O(k·log n) candidate set and the weighted k-means++ on it
-    (exactly the reference's division of labor, minus the scheduler
-    round-trips).  The per-round capacity is 4·ℓ — the Bernoulli round
-    draws ℓ candidates in expectation, so overflow (dropped candidates) is
-    vanishingly rare and harmless to the sampling guarantee.
+    Device side, two programs and two host syncs: ``kmeans.init_first``
+    draws the first candidate and returns phi, from which the host takes
+    ``n_rounds = ceil(ln phi)``; ``kmeans.init_scalable`` runs every
+    round (the Bernoulli draw, a sort-free compaction of at most ``cap``
+    drawn rows a round, distances to those rows alone, the fold into the
+    carried least distance and nearest slot) and weighs the candidates by
+    a histogram of the nearest slots.  Its largest intermediate is rows x
+    ``cap``; nothing of the table's size leaves a chip or reaches the
+    host.  Host side: one pull of the candidate buffer and its weights,
+    then the weighted k-means++ and 10 Lloyd steps on the O(k log n)
+    valid candidates, exactly the reference's division of labour.  The
+    per-round capacity is 4 * ell: a round draws at most ell rows in
+    expectation, so an overflow (rows drawn and dropped) is vanishingly
+    rare and harmless to the sampling guarantee.
     """
+    return _init_scalable(X, n_clusters, key, oversampling_factor,
+                          init_max_iter)[0]
+
+
+def _sample_candidates(X, n_clusters, key, oversampling_factor,
+                       init_max_iter, behind=None):
+    """The device part of k-means|| and its one pull: the candidate
+    buffer ``(slots, d)``, which slots hold a row, every slot's weight,
+    the rounds run and ``cap``, all on the host.  ``behind()`` is called
+    once the rounds are dispatched and before the pull: what it queues
+    runs on the device while the host works on the candidates."""
+    from ..ops.scatter import scatter_strategy
+
     x, mask = X.data, X.mask
-    n = X.n_samples
     ell = oversampling_factor * n_clusters
-    cap = int(min(max(4 * ell, 8), x.shape[0]))
+    mh = MeshHolder(get_mesh())
+    cap = int(min(max(4 * ell, 8), x.shape[0] // data_axes_size(mh.mesh)))
 
-    # 1. one uniformly-random real point
-    key, sub = jax.random.split(key)
-    idx = jax.random.choice(sub, x.shape[0], p=mask / jnp.sum(mask))
-    centers = x[idx][None, :]
-    cvalid = jnp.ones((1,), dtype=x.dtype)
-
-    phi, _ = _phi_and_mind2(x, mask, centers, cvalid)
-    n_rounds = int(np.ceil(np.log(max(float(phi), 2.0))))
+    # 1. one real point drawn by weight, and phi: the first host sync
+    # (both programs derive their streams from ``key`` inside: an eager
+    # split is a dispatch the chip would wait for)
+    with _obs.span("kmeans.init.first"):
+        first, min_d2, phi = _init_first(x, mask, key, mesh_holder=mh)
+        x_norm = _row_norms(x)  # the chip's work while the host waits
+        phi = float(phi)
+    n_rounds = int(np.ceil(np.log(max(phi, 2.0))))
     if init_max_iter is not None:
         n_rounds = min(n_rounds, int(init_max_iter))
     n_rounds = max(n_rounds, 1)
+    max_rounds = -(-n_rounds // _ROUNDS_QUANTUM) * _ROUNDS_QUANTUM
 
-    for r in range(n_rounds):
-        phi, min_d2 = _phi_and_mind2(x, mask, centers, cvalid)
-        if float(phi) == 0.0:  # O(1) scalar sync — loop control only
-            break
-        key, sub = jax.random.split(key)
-        u = jax.random.uniform(sub, (x.shape[0],), dtype=x.dtype)
-        p = jnp.minimum(ell * min_d2 / phi, 1.0)
-        rows, valid = _sample_candidates(x, mask, u, p, cap=cap)
-        centers = jnp.concatenate([centers, rows], axis=0)
-        cvalid = jnp.concatenate([cvalid, valid])
-        logger.debug("k-means|| round %d: %d candidate slots", r, centers.shape[0])
+    # 2. the rounds and the weights: one program, then ONE host pull of
+    # the O(k log n) candidate buffer at the very end
+    with _obs.span("kmeans.init.rounds", max_rounds=max_rounds):
+        out = _init_rounds(
+            x, mask, x_norm, first, min_d2, key, jnp.int32(n_rounds),
+            ell=float(ell),
+            cap=cap, max_rounds=max_rounds, mesh_holder=mh,
+            scatter=scatter_strategy(1 + max_rounds * cap, histogram=True))
+        if behind is not None:
+            behind()
+        return (*jax.device_get(out), cap)
 
-    # weight candidates by how many points they are closest to (invalid
-    # slots excluded by the same distance sentinel)
-    closest = jnp.argmin(_valid_d2(x, centers, cvalid), axis=1)
-    weights_dev = jnp.sum(
-        jax.nn.one_hot(closest, centers.shape[0], dtype=x.dtype) * mask[:, None], axis=0
-    )
-    # ONE host pull of the O(k·log n) candidate set at the very end
-    keep = np.asarray(cvalid) > 0.0
-    cand = np.asarray(centers, dtype=np.float64)[keep]
-    weights = np.asarray(weights_dev)[keep]
+
+def _init_scalable(X, n_clusters, key, oversampling_factor, init_max_iter,
+                   behind=None):
+    """``init_scalable`` and its counts (``rounds`` run, valid
+    ``candidates``, ``cap``), which ``KMeans.fit`` puts on its span;
+    ``behind``: see ``_sample_candidates``."""
+    cand, keep, weights, rounds, cap = _sample_candidates(
+        X, n_clusters, key, oversampling_factor, init_max_iter, behind)
+    x, n = X.data, X.n_samples
+    cand = np.asarray(cand, dtype=np.float64)[keep]
+    weights = np.asarray(weights, dtype=np.float64)[keep]
+    counts = {"rounds": int(rounds), "candidates": len(cand), "cap": cap}
+    logger.debug("k-means||: %s", counts)
 
     if cand.shape[0] <= n_clusters:
         # degenerate: fewer candidates than clusters — pad with random real
-        # rows gathered device-side
-        key, sub = jax.random.split(key)
+        # rows gathered device-side (a stream no shard's index reaches)
+        sub = jax.random.fold_in(key, 2**31 - 1)
         n_extra = n_clusters - cand.shape[0] + 1
         extra_idx = jax.random.choice(sub, n, (n_extra,), replace=n_extra > n)
         extra = np.asarray(jnp.take(x, extra_idx, axis=0), dtype=np.float64)
@@ -326,8 +569,28 @@ def init_scalable(X: ShardedRows, n_clusters: int, key, oversampling_factor=2,
 
     local = SKKMeans(n_clusters=n_clusters, init="k-means++", n_init=1,
                      max_iter=10, random_state=0)
-    local.fit(cand, sample_weight=np.maximum(weights[: cand.shape[0]], 1e-12))
-    return jnp.asarray(local.cluster_centers_, dtype=x.dtype)
+    with _one_host_thread():
+        local.fit(cand, sample_weight=np.maximum(weights, 1e-12))
+    return jnp.asarray(local.cluster_centers_, dtype=x.dtype), counts
+
+
+_HOST_POOLS = None
+
+
+def _one_host_thread():
+    """The host's BLAS and OpenMP pools held to one thread while sklearn
+    clusters the few hundred candidates: fanned out over the cores that
+    fit waits on its threads' wake-ups (measured on the v5e's host, 13
+    cores, PERF.md PR 28: 9 ms of a 0.67 s fit and most of its run-to-run
+    spread; here a fit of 460 x 50 read 2.3 ms with stalls to 140 ms
+    against 2.0 ms and none).  The controller is kept: finding the
+    loaded pools anew costs more than the fit."""
+    global _HOST_POOLS
+    if _HOST_POOLS is None:
+        import threadpoolctl
+
+        _HOST_POOLS = threadpoolctl.ThreadpoolController()
+    return _HOST_POOLS.limit(limits=1)
 
 
 class KMeans(TransformerMixin, TPUEstimator):
@@ -362,7 +625,10 @@ class KMeans(TransformerMixin, TPUEstimator):
         self.init_max_iter = init_max_iter
         self.fit_checkpoint = fit_checkpoint
 
-    def _init_centers(self, X: ShardedRows, key):
+    def _init_centers(self, X: ShardedRows, key, span=None, behind=None):
+        """The starting centres; k-means||'s counts go on ``span`` (the
+        fit's ``kmeans.init``) and into the always-on registry, and it
+        calls ``behind()`` where device work can hide host work."""
         init = self.init
         if isinstance(init, (np.ndarray, jnp.ndarray)):
             # a COPY, never a view of the user's array: the Lloyd loop
@@ -378,10 +644,16 @@ class KMeans(TransformerMixin, TPUEstimator):
             return centers
         if init == "k-means||":
             with _timer("k-means|| initialization", logger, logging.DEBUG):
-                return init_scalable(
+                centers, counts = _init_scalable(
                     X, self.n_clusters, key, self.oversampling_factor,
-                    self.init_max_iter,
+                    self.init_max_iter, behind,
                 )
+            if span is not None:
+                span.set(**counts)
+            reg = _obs.registry()
+            reg.counter("kmeans.init_rounds").inc(counts["rounds"])
+            reg.counter("kmeans.candidates").inc(counts["candidates"])
+            return centers
         if init == "random":
             p = X.mask / jnp.sum(X.mask)
             idx = jax.random.choice(
@@ -424,6 +696,15 @@ class KMeans(TransformerMixin, TPUEstimator):
         raise ValueError(f"Unknown init: {init!r}")
 
     def fit(self, X, y=None, sample_weight=None):
+        # the fit's spans (live under ``obs.enable()`` or a profiler
+        # session): ``kmeans.fit`` is the root, ``kmeans.init``,
+        # ``kmeans.lloyd`` and ``kmeans.assign`` its children
+        with _obs.span("kmeans.fit", n_clusters=self.n_clusters,
+                       init=(self.init if isinstance(self.init, str)
+                             else "array")) as root:
+            return self._fit(X, sample_weight, root)
+
+    def _fit(self, X, sample_weight, root):
         if self.n_clusters <= 0:
             raise ValueError("n_clusters must be positive")
         X = _ingest_float(self, X)
@@ -431,6 +712,8 @@ class KMeans(TransformerMixin, TPUEstimator):
             raise ValueError(
                 f"n_samples={X.n_samples} < n_clusters={self.n_clusters}"
             )
+        root.set(rows=X.n_samples, features=X.data.shape[1],
+                 chips=len(X.data.sharding.device_set))
         valid_mask = X.mask  # pre-weighting validity, for the tol scale
         if sample_weight is not None:
             # the mask is the per-row weight everywhere downstream: the
@@ -452,17 +735,54 @@ class KMeans(TransformerMixin, TPUEstimator):
             # copy: the loop donates centers; the snapshot's array must
             # stay valid for a retried resume
             centers = jnp.array(state["centers"], dtype=X.data.dtype)
-        else:
-            centers = self._init_centers(X, key)
-
         x, mask = X.data, X.mask
-        # sklearn-style tol scaling: mean of per-feature variances, masked so
-        # pad rows don't inflate the threshold
-        from ..core.sharded import masked_var
+        tol = []  # the stopping threshold, a device scalar, queued once
 
-        # tol from UNWEIGHTED variances: sklearn's _tolerance ignores
-        # sample_weight, so weighting must not move the stopping threshold
-        tol = self.tol * jnp.mean(masked_var(x, valid_mask))  # on device
+        def queue_tol():
+            # sklearn-style tol scaling: mean of per-feature variances,
+            # masked so pad rows don't inflate the threshold, and from
+            # UNWEIGHTED variances: sklearn's _tolerance ignores
+            # sample_weight, so weighting must not move it
+            from ..core.sharded import masked_var
+
+            tol.append((self.tol * jnp.mean(masked_var(x, valid_mask)))
+                       .astype(x.dtype))
+
+        if snap is None:
+            with _obs.span("kmeans.init") as span:
+                # the threshold needs no centres: queued behind k-means||'s
+                # rounds, its three reads of X run while the host clusters
+                # the candidates
+                centers = self._init_centers(X, key, span, behind=queue_tol)
+        with _obs.span("kmeans.lloyd") as span:
+            if not tol:
+                queue_tol()
+            centers, n_iter = self._lloyd(x, mask, tol[0], centers, ckpt, it0)
+            # the assignment is queued behind the loop before anything is
+            # waited for: the chip goes on while the host wakes up
+            labels, inertia = _assign(x, mask, centers)
+            # every caller reads the centres on the host: their copy
+            # starts now, not at the first ``np.asarray``
+            centers.copy_to_host_async()
+            n_iter = int(n_iter)  # the wait for the loop
+            span.set(iters=n_iter)
+        reg = _obs.registry()
+        reg.counter("kmeans.count").inc()
+        reg.counter("kmeans.lloyd_iters").inc(n_iter)
+        with _obs.span("kmeans.assign"):
+            self.inertia_ = float(inertia)  # the wait for the assignment
+
+        self.cluster_centers_ = centers
+        self.labels_ = (labels if labels.shape[0] == X.n_samples
+                        else labels[: X.n_samples])
+        self.n_iter_ = n_iter
+        self.n_features_in_ = x.shape[1]
+        return self
+
+    def _lloyd(self, x, mask, tol, centers, ckpt, it0):
+        """The Lloyd iterations from ``centers`` down to a shift of
+        ``tol`` (a device scalar): ``(centers, n_iter)``; the fused loop
+        leaves ``n_iter`` on the device, for the caller to wait on."""
         from ..resilience.preemption import active_watcher, check_preemption
 
         with _timer("Lloyd loop", logger, logging.DEBUG), \
@@ -477,48 +797,40 @@ class KMeans(TransformerMixin, TPUEstimator):
             if ckpt is None and active_watcher() is None:
                 # the uninstrumented fast path: ONE fused dispatch
                 centers, _, n_iter_dev, _ = _lloyd_loop(
-                    x, mask, centers, tol.astype(x.dtype),
-                    jnp.int32(self.max_iter), mode=mode, scatter=scatter,
+                    x, mask, centers, tol, jnp.int32(self.max_iter),
+                    mode=mode, scatter=scatter,
                 )
-                n_iter = int(n_iter_dev)
-            else:
-                # segmented: the SAME compiled step program in chunks of
-                # the checkpoint cadence, one host boundary per chunk
-                # (snapshot + preemption check + fault-injection point)
-                from ..resilience.testing import maybe_fault
+                return centers, n_iter_dev
+            # segmented: the SAME compiled step program in chunks of
+            # the checkpoint cadence, one host boundary per chunk
+            # (snapshot + preemption check + fault-injection point)
+            from ..resilience.testing import maybe_fault
 
-                chunk = (ckpt.chunk_iters(32) if ckpt is not None
-                         else min(32, int(self.max_iter)))
-                n_iter = it0
-                while n_iter < self.max_iter:
-                    maybe_fault("step")
-                    seg = min(chunk, self.max_iter - n_iter)
-                    centers, _, seg_n_dev, shift = _lloyd_loop(
-                        x, mask, centers, tol.astype(x.dtype),
-                        jnp.int32(seg), mode=mode, scatter=scatter,
-                    )
-                    seg_n = int(seg_n_dev)
-                    n_iter += seg_n
-                    if ckpt is not None and ckpt.due(n_iter):
-                        ckpt.save(self, {"centers": centers}, n_iter)
-                    check_preemption(ckpt, self, {"centers": centers}, n_iter)
-                    # converged: the segment stopped early, or the final
-                    # shift cleared tol exactly at the boundary (the fused
-                    # loop's cond — boundaries must not add iterations)
-                    with _SEG_SYNC.allow():
-                        # graftlint: disable=host-sync-loop -- segment-boundary sync: one scalar fetch per fused 32-iteration segment, not per Lloyd iteration
-                        if seg_n < seg or float(shift) <= float(tol):
-                            break
-                if ckpt is not None:
-                    ckpt.complete()
-        labels, inertia = _assign(x, mask, centers)
-
-        self.cluster_centers_ = centers
-        self.labels_ = labels[: X.n_samples]
-        self.inertia_ = float(inertia)
-        self.n_iter_ = n_iter
-        self.n_features_in_ = x.shape[1]
-        return self
+            chunk = (ckpt.chunk_iters(32) if ckpt is not None
+                     else min(32, int(self.max_iter)))
+            n_iter = it0
+            while n_iter < self.max_iter:
+                maybe_fault("step")
+                seg = min(chunk, self.max_iter - n_iter)
+                centers, _, seg_n_dev, shift = _lloyd_loop(
+                    x, mask, centers, tol, jnp.int32(seg), mode=mode,
+                    scatter=scatter,
+                )
+                seg_n = int(seg_n_dev)
+                n_iter += seg_n
+                if ckpt is not None and ckpt.due(n_iter):
+                    ckpt.save(self, {"centers": centers}, n_iter)
+                check_preemption(ckpt, self, {"centers": centers}, n_iter)
+                # converged: the segment stopped early, or the final
+                # shift cleared tol exactly at the boundary (the fused
+                # loop's cond — boundaries must not add iterations)
+                with _SEG_SYNC.allow():
+                    # graftlint: disable=host-sync-loop -- segment-boundary sync: one scalar fetch per fused 32-iteration segment, not per Lloyd iteration
+                    if seg_n < seg or float(shift) <= float(tol):
+                        break
+            if ckpt is not None:
+                ckpt.complete()
+        return centers, n_iter
 
     def predict(self, X):
         X = _ingest_float(self, X)

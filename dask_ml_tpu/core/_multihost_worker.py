@@ -74,7 +74,12 @@ def main(pid: int, nproc: int, port: str, local_devices: int = 4) -> None:
 
     _scatter = scatter_strategy(2)  # resolved OUTSIDE the jit (static):
     # defaulting it would bake segsum in and drop the TPU onehot policy
-    centers0 = np.stack([Xl[:3].mean(0), Xl[3:6].mean(0) + 2.0]).astype(np.float32)
+    # a replicated operand must hold the SAME value on every process: the
+    # centres come from the shared stream, not from this process's rows
+    # (the anchored Lloyd reduce adds its sums to the centres it was given,
+    # so centres that differed by process would never agree and the
+    # processes would leave the loop at different iterations)
+    centers0 = np.stack([w_true, w_true + 2.0]).astype(np.float32)
     centers, inertia, n_iter = _lloyd_loop(
         Xs.data, Xs.mask, jnp.asarray(centers0),
         jnp.float32(1e-4), jnp.int32(20), scatter=_scatter,

@@ -1,0 +1,322 @@
+"""k-means|| in memory bounded by the table (``cluster/k_means.py``): the
+carried least distance and nearest slot, the fixed-capacity candidate
+buffer, the sort-free compaction and the per-shard program, held to brute
+force and to the benchmark's plain reference
+(``benchmarks/references/kmeans_lloyd.py``, which imports nothing of
+``dask_ml_tpu``) on the 8-device mesh of ``conftest.py``."""
+
+import os
+import re
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from dask_ml_tpu import diagnostics, obs
+from dask_ml_tpu.cluster import KMeans
+from dask_ml_tpu.cluster import k_means as km
+from dask_ml_tpu.core import device_mesh, shard_rows, use_mesh
+from dask_ml_tpu.core.mesh import MeshHolder, get_mesh
+from dask_ml_tpu.utils import reweight_rows
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmarks")
+sys.path.insert(0, BENCH)
+import run as harness  # noqa: E402  (benchmarks/run.py: its loaders only)
+
+CONFIG = harness.load_json(BENCH, "configs", "kmeans-blobs.json")
+REFERENCE = harness.load_module("references", CONFIG["reference"])
+GENERATOR = harness.load_module("generators", CONFIG["generator"])
+
+
+def _blobs(rows=4003, features=6, k=5, seed=0, spread=0.5):
+    """``rows`` rows (no multiple of the mesh) of ``k`` blobs that stand
+    apart, in no order."""
+    r = np.random.default_rng(seed)
+    centres = r.uniform(-10, 10, size=(k, features))
+    X = centres[r.integers(k, size=rows)] + spread * r.normal(
+        size=(rows, features))
+    return X.astype(np.float32)
+
+
+def _brute_weights(X, w, cand):
+    """Every row's weight on the candidate nearest to it, in float64, and
+    how many rows have a runner-up too close for float32 to tell."""
+    d2 = ((X[:, None, :].astype(np.float64) - cand[None, :, :]) ** 2).sum(-1)
+    order = np.sort(d2, axis=1)
+    close = int((order[:, 1] - order[:, 0] < 1e-4 * order[:, 1]).sum())
+    return np.bincount(d2.argmin(axis=1), weights=w,
+                       minlength=len(cand)), close
+
+
+def _sampled(Xs, k=5, key=0, **kw):
+    cand, keep, weights, rounds, cap = km._sample_candidates(
+        Xs, k, jax.random.PRNGKey(key), kw.pop("oversampling_factor", 2),
+        kw.pop("init_max_iter", None))
+    return np.asarray(cand, np.float64), np.asarray(keep), np.asarray(
+        weights, np.float64), int(rounds), cap
+
+
+def test_weights_are_brute_force_nearest_candidate_counts_with_pad_rows():
+    X = _blobs()
+    Xs = shard_rows(X)
+    assert Xs.data.shape[0] > X.shape[0]  # the mesh padded the rows
+    cand, keep, weights, rounds, cap = _sampled(Xs)
+    assert keep[0] and 1 < keep.sum() <= 1 + rounds * cap
+    assert weights[~keep].sum() == 0 and weights.sum() == X.shape[0]
+    brute, close = _brute_weights(X, np.ones(len(X)), cand[keep])
+    assert np.abs(weights[keep] - brute).sum() <= 2 * close
+    # every candidate is a row of the table, none a pad row
+    rows = {r.tobytes() for r in X}
+    assert all(c.astype(np.float32).tobytes() in rows for c in cand[keep])
+
+
+def test_weights_sum_the_sample_weights_of_the_nearest_rows():
+    X = _blobs(seed=1)
+    w = np.random.default_rng(2).uniform(0.5, 3.0, size=len(X)).astype(
+        np.float32)
+    w[::7] = 0.0  # rows of no weight are never drawn and weigh nothing
+    cand, keep, weights, _, _ = _sampled(
+        reweight_rows(shard_rows(X), sample_weight=w), key=3)
+    brute, close = _brute_weights(X, w.astype(np.float64), cand[keep])
+    np.testing.assert_allclose(weights[keep], brute, rtol=1e-5,
+                               atol=3.0 * 2 * close + 1e-3)
+    zero = {r.tobytes() for r in X[::7]} - {r.tobytes() for r in X[w > 0]}
+    assert not any(c.astype(np.float32).tobytes() in zero for c in cand[keep])
+
+
+def _avals(jaxpr):
+    """Every value a jaxpr makes, sub-jaxprs (loops, shard_map) included."""
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield v.aval
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _avals(sub)
+
+
+def test_no_intermediate_of_the_init_has_rows_x_total_slots_elements():
+    rows, d, cap, max_rounds = 4096, 6, 40, 16
+    mh = MeshHolder(get_mesh())
+    shards = len(jax.devices())
+    x = jnp.zeros((rows, d), jnp.float32)
+    v = jnp.zeros((rows,), jnp.float32)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: km._init_rounds_fn(
+            *a, ell=10.0, cap=cap, max_rounds=max_rounds, mesh_holder=mh,
+            scatter="segsum"))(
+        x, v, v, jnp.zeros((d,)), v, jax.random.PRNGKey(0), jnp.int32(3))
+    sizes = [int(np.prod(a.shape)) for a in _avals(jaxpr.jaxpr)
+             if hasattr(a, "shape")]
+    local = rows // shards
+    # the largest is one round's distances, local rows x cap (the table
+    # itself is an input); rows x slots would be 16 times that
+    assert max(sizes) == local * cap
+    assert max(sizes) < local * (1 + max_rounds * cap) // 8
+
+
+def test_the_compiled_init_moves_no_rows_sized_operand_between_chips():
+    """On the 8-device mesh every shard draws, compacts and gathers among
+    its own rows: what crosses chips is a round's shards x cap candidate
+    rows, phi and the weights; nothing is sorted."""
+    Xs = shard_rows(_blobs(rows=8192))
+    shards = len(Xs.data.sharding.device_set)
+    assert shards == len(jax.devices()) > 1
+    mh = MeshHolder(get_mesh())
+    key = jax.random.PRNGKey(0)
+    first = km._init_first.lower(Xs.data, Xs.mask, key, mesh_holder=mh)
+    c0, d2, _ = km._init_first(Xs.data, Xs.mask, key, mesh_holder=mh)
+    rounds = km._init_rounds.lower(
+        Xs.data, Xs.mask, d2, c0, d2, key, jnp.int32(4), ell=10.0, cap=40,
+        max_rounds=8, mesh_holder=mh, scatter="segsum")
+    local = Xs.data.shape[0] // shards
+    for lowered in (first, rounds):
+        hlo = lowered.compile().as_text()
+        ops = set(re.findall(r"[ )]([a-z][a-z-]*)\(", hlo))
+        assert "all-reduce" in ops and "all-gather" in ops
+        assert not ops & {"sort", "all-to-all", "collective-permute"}
+        for shape in re.findall(
+                r"= \(?([a-z0-9]+\[[0-9,]*\])[^=]*? all-gather\(", hlo):
+            dims = [int(n) for n in re.findall(r"\d+", shape.split("[")[1])]
+            assert all(n < local for n in dims), shape
+
+
+def _table(seed, rows, chips):
+    params = dict(CONFIG["generator_params"], block_rows=rows // chips,
+                  features=10)
+    return GENERATOR.make(harness.seed_key(jax, seed), rows, params,
+                          harness.row_sharding(jax.devices()[:chips]))
+
+
+@pytest.mark.parametrize("chips", [1, 8])
+def test_fit_agrees_with_the_plain_reference(chips):
+    """``KMeans.fit`` against plain Lloyd from the generating centres, by
+    the benchmark's own numbers and limits, one device and eight."""
+    data = _table(11, 16_000, chips)
+    with use_mesh(device_mesh(chips)):
+        est = KMeans(**harness.substitute_seed(CONFIG["estimator_args"], 0))
+        est.fit(shard_rows(data["X"]))
+    assert len(est.labels_.sharding.device_set) == chips
+    ref = REFERENCE.build(data, {})
+    got = REFERENCE.compare(
+        ref, data, harness.fetch_answer(np, est, CONFIG["fetch"]),
+        {"labels_": est.labels_})
+    assert set(got) == set(CONFIG["limits"])
+    for name, limit in CONFIG["limits"].items():
+        assert got[name] <= limit, (name, got[name])
+
+
+def _fit_tree():
+    """The last fit's span tree (not ``obs.span_tree()``'s newest root: a
+    thread an earlier test file left behind may open roots meanwhile)."""
+    roots = [r for r in obs.span_records()
+             if r.name == "kmeans.fit" and r.parent_id is None]
+    return obs.span_tree(roots[-1])
+
+
+def _fit_counts(est):
+    tree = _fit_tree()
+    child = {c["name"]: c for c in tree["children"]}
+    return (child["kmeans.init"]["attrs"]["rounds"],
+            child["kmeans.init"]["attrs"]["candidates"],
+            child["kmeans.lloyd"]["attrs"]["iters"])
+
+
+def test_two_run_seeds_mirror_each_other_bit_for_bit():
+    """The run seed flips feature columns: the same rows are drawn in the
+    same rounds, Lloyd runs the same iterations, and the centres come out
+    with the signs."""
+    fits = []
+    for seed in (5, 2**31 + 77):
+        data = _table(seed, 8_000, 1)
+        est = KMeans(n_clusters=8, random_state=0).fit(shard_rows(data["X"]))
+        fits.append((np.asarray(data["X"]), np.asarray(est.cluster_centers_),
+                     est.inertia_, _fit_counts(est)))
+    (xa, ca, ia, na), (xb, cb, ib, nb) = fits
+    signs = np.sign(xa[0] * xb[0])
+    assert set(np.unique(signs)) == {-1.0, 1.0}  # the seeds differ
+    assert np.array_equal(xa * signs, xb)
+    assert np.array_equal(ca * signs, cb) and ia == ib and na == nb
+
+
+def test_one_compile_serves_every_round_and_the_second_fit():
+    Xs = shard_rows(_blobs(rows=2051, seed=4))
+    KMeans(n_clusters=5, random_state=1).fit(Xs)
+    before = diagnostics.program_report()["totals"]
+    est = KMeans(n_clusters=5, random_state=2).fit(Xs)  # other draws
+    after = diagnostics.program_report()["totals"]
+    assert after["misses"] == before["misses"]
+    assert after["hits"] > before["hits"]
+    assert _fit_counts(est)[0] > 1  # many rounds, one program
+
+
+def test_span_tree_and_counters():
+    def counts():
+        c = obs.metrics_snapshot()["counters"]
+        return {k: c.get(k, 0) for k in (
+            "kmeans.count", "kmeans.init_rounds", "kmeans.candidates",
+            "kmeans.lloyd_iters")}
+
+    X = _blobs(rows=1500, seed=6)
+    before = counts()
+    est = KMeans(n_clusters=5, random_state=0).fit(X)
+    tree = _fit_tree()
+    assert tree["attrs"] == {"n_clusters": 5, "init": "k-means||",
+                             "rows": 1500, "features": 6,
+                             "chips": len(jax.devices())}
+    assert [c["name"] for c in tree["children"]] == [
+        "kmeans.init", "kmeans.lloyd", "kmeans.assign"]
+    rounds, candidates, iters = _fit_counts(est)
+    assert iters == est.n_iter_ >= 1
+    assert tree["children"][0]["attrs"]["cap"] == 40
+    assert 1 < candidates <= 1 + rounds * 40
+    after = counts()
+    assert {k: after[k] - before[k] for k in after} == {
+        "kmeans.count": 1, "kmeans.init_rounds": rounds,
+        "kmeans.candidates": candidates, "kmeans.lloyd_iters": iters}
+
+
+def test_init_max_iter_bounds_the_rounds():
+    Xs = shard_rows(_blobs(rows=3000, seed=7))
+    assert _sampled(Xs, init_max_iter=3)[3] == 3
+    assert _sampled(Xs)[3] > 3
+    est = KMeans(n_clusters=5, init_max_iter=2, random_state=0).fit(Xs)
+    assert _fit_counts(est)[0] == 2
+
+
+def test_fewer_candidates_than_clusters_are_padded_with_real_rows():
+    """The degenerate branch: one round on a handful of rows leaves fewer
+    candidates than clusters, and the init still hands back k centres."""
+    X = _blobs(rows=24, k=3, seed=8)
+    cand, keep, *_ = _sampled(shard_rows(X), k=12, init_max_iter=1)
+    assert keep.sum() <= 12
+    est = KMeans(n_clusters=12, init_max_iter=1, random_state=0).fit(X)
+    assert est.cluster_centers_.shape == (12, 6)
+    assert np.isfinite(np.asarray(est.cluster_centers_)).all()
+    assert est.labels_.shape == (24,)
+
+
+def test_first_selected_finds_the_first_true_entries():
+    r = np.random.default_rng(9)
+    for n, k, p in ((5000, 7, 0.01), (1024, 4, 0.0), (3000, 16, 0.5),
+                    (10, 8, 0.3)):
+        sel = r.random(n) < p
+        idx, found = km._first_selected(jnp.asarray(sel), k)
+        want = np.flatnonzero(sel)[:k]
+        assert int(found.sum()) == len(want)
+        assert np.array_equal(np.asarray(idx)[:len(want)], want)
+
+
+def test_rows_are_drawn_on_32_bits_not_on_a_float32_uniform():
+    """A round's p is about ell / rows: under 2^-23, where a float32
+    uniform cannot tell one probability from the next."""
+    bits = jnp.asarray([0, 42, 43, 2**31, 2**32 - 1], jnp.uint32)
+    p = jnp.float32(43 * 2.0 ** -32)  # 1e-8: the 43 smallest of 2^32
+    assert np.asarray(km._drawn(bits, p)).tolist() == [
+        True, True, False, False, False]
+    assert not np.asarray(km._drawn(bits, jnp.float32(0.0))).any()
+    assert np.asarray(km._drawn(bits, jnp.float32(1.0))).all()
+    assert np.asarray(km._drawn(bits, jnp.float32(7.5))).all()
+    half = np.asarray(km._drawn(
+        jax.random.bits(jax.random.PRNGKey(0), (200_000,)), jnp.float32(0.5)))
+    assert abs(half.mean() - 0.5) < 0.01
+    u = np.asarray(km._unit_interval(bits))
+    assert 0 < u[0] < u[1] < 2e-8 and u[-1] <= 1.0
+
+
+def test_first_candidate_is_drawn_by_weight():
+    X = (np.arange(40, dtype=np.float32)[:, None] * np.ones((1, 3), np.float32))
+    w = np.where(np.arange(40) < 20, 3.0, 1.0).astype(np.float32)
+    w[5] = 0.0
+    Xw = reweight_rows(shard_rows(X), sample_weight=w)
+    mh = MeshHolder(get_mesh())
+    firsts = [int(km._init_first(Xw.data, Xw.mask, jax.random.PRNGKey(i),
+                                 mesh_holder=mh)[0][0]) for i in range(400)]
+    assert 5 not in firsts and len(set(firsts)) > 30
+    share = np.mean(np.asarray(firsts) < 20)  # 57 of 77 by weight
+    assert abs(share - 57 / 77) < 0.08
+
+
+def test_histogram_over_many_buckets_equals_segment_sum():
+    from dask_ml_tpu.ops import bucket_sum
+
+    r = np.random.default_rng(10)
+    ids = jnp.asarray(r.integers(0, 2049, size=20_000), jnp.int32)
+    w = jnp.asarray(r.uniform(0, 2, size=20_000), jnp.float32)
+    two = bucket_sum(w, ids, 2049, strategy="onehot2",
+                     precision=jax.lax.Precision.HIGHEST)
+    seg = bucket_sum(w, ids, 2049, strategy="segsum")
+    assert two.shape == seg.shape == (2049,)
+    np.testing.assert_allclose(np.asarray(two), np.asarray(seg), rtol=1e-5)
+
+
+def test_lloyd_sums_offsets_from_the_current_centre():
+    """The per-cluster reduce adds up rows' offsets from their current
+    centre, so a blob far from the origin keeps a mean exact to float32."""
+    r = np.random.default_rng(11)
+    X = (1000.0 + r.normal(size=(40_000, 4))).astype(np.float32)
+    est = KMeans(n_clusters=1, init=X[:1].copy(), max_iter=5).fit(X)
+    exact = X.astype(np.float64).mean(axis=0)
+    assert np.abs(np.asarray(est.cluster_centers_)[0] - exact).max() < 2e-4
