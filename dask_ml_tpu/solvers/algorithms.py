@@ -38,7 +38,7 @@ from ..core.compat import shard_map_unchecked
 from ..core.mesh import MeshHolder, get_mesh
 from ..core.sharded import ShardedRows, shard_rows
 from .families import Family, Logistic
-from .lbfgs_core import lbfgs_minimize, run_line_search
+from .lbfgs_core import LinearObjective, lbfgs_minimize, run_line_search
 from .regularizers import L2, Regularizer, get_regularizer
 
 logger = logging.getLogger(__name__)
@@ -119,9 +119,10 @@ def reset_dispatch_counts():
 #: place of a scalar iteration count, as one small int32 vector so that
 #: the host fetches it in the one transfer ``n_iter_`` already costs: the
 #: solver's own iterations (ADMM rounds; ``n_iter_``), the L-BFGS
-#: iterations inside them, and ``LBFGSState.n_evals`` (a lower bound on
-#: reads of the design matrix)
-SOLVE_COUNTS = ("rounds", "inner_iters", "passes")
+#: iterations inside them, ``LBFGSState.n_evals`` (the local solves'
+#: operations that stream the design matrix) and ``LBFGSState.n_trials``
+#: (the line search's trials on the cached linear predictor, which do not)
+SOLVE_COUNTS = ("rounds", "inner_iters", "passes", "trials")
 #: the solvers that take ``return_counts=True``
 COUNTED_SOLVERS = ("admm", "lbfgs")
 
@@ -147,11 +148,33 @@ def _make_objective(family, reg, x, y, mask, lamduh):
     ``lamduh`` is a traced scalar: zero simply zeroes the penalty term, so
     one compiled program covers every regularization strength.
     """
+    return _lbfgs_objective("black_box", family, x, y, mask,
+                            lambda b: reg.penalty(b, lamduh))
 
-    def obj(b):
-        return family.loss(b, x, y, mask) + reg.penalty(b, lamduh)
 
-    return obj
+def _lbfgs_objective(objective, family, x, y, mask, smooth):
+    """The family's loss plus ``smooth`` (a function of the parameters
+    alone), as a black-box closure or, for ``objective="linear"``, in the
+    parts ``lbfgs_minimize`` can use to search on the cached linear
+    predictor.  ``objective`` is the counted runners' PRIVATE static
+    argument, set by the entry points alone: ``admm()`` and ``lbfgs()``
+    ask for ``"linear"``, a caller that puts the runner under ``vmap``
+    for ``"black_box"``.  Asked for ``"linear"``, two kinds of family get
+    the black box all the same: one with a matrix of parameters
+    (``multinomial``), and one that defines only ``loss`` and so has no
+    parts to hand over.  Both choices are chip readings: PERF.md section
+    6, PR 29.
+    """
+    if objective not in ("black_box", "linear"):
+        raise ValueError(f"unknown objective kind {objective!r}")
+    parts = getattr(family, "pointwise_loss", Family.pointwise_loss)
+    if (objective == "black_box" or parts is Family.pointwise_loss
+            or getattr(family, "params_per_feature", 1) > 1):
+        return lambda b: family.loss(b, x, y, mask) + smooth(b)
+    return LinearObjective(
+        predict=lambda *betas: family.linear_predictors(x, *betas),
+        pointwise=lambda eta: family.pointwise_loss(eta, y, mask),
+        smooth=smooth)
 
 
 def _converged(f_prev, f_new, tol):
@@ -165,14 +188,17 @@ def _converged(f_prev, f_new, tol):
 # ---------------------------------------------------------------- lbfgs --
 
 
-@partial(jax.jit, static_argnames=("family", "reg", "line_search"))
+@partial(jax.jit, static_argnames=(
+    "family", "reg", "line_search", "objective"))
 def _lbfgs_run(x, yv, mask, beta0, lamduh, max_iter, tol, *, family, reg,
-               line_search="backtrack"):
-    obj = _make_objective(family, reg, x, yv, mask, lamduh)
+               line_search="backtrack", objective="black_box"):
+    obj = _lbfgs_objective(objective, family, x, yv, mask,
+                           lambda b: reg.penalty(b, lamduh))
     beta, st = lbfgs_minimize(
         obj, beta0, max_iter=max_iter, tol=tol, line_search=line_search
     )
-    return beta, jnp.stack([st.k, st.k, st.n_evals]).astype(jnp.int32)
+    return beta, jnp.stack(
+        [st.k, st.k, st.n_evals, st.n_trials]).astype(jnp.int32)
 
 
 def lbfgs(X, y, *, family: type[Family] = Logistic, regularizer=L2,
@@ -186,8 +212,8 @@ def lbfgs(X, y, *, family: type[Family] = Logistic, regularizer=L2,
     ``return_counts=True`` returns ``(beta, counts)``, the device vector
     :data:`SOLVE_COUNTS` lays out.
 
-    ``line_search="auto"`` resolves to the measured per-platform winner
-    (probe_grid on TPU, backtrack on CPU — :func:`line_search_strategy`).
+    ``line_search="auto"`` resolves per platform (probe_grid on TPU,
+    backtrack on CPU — :func:`line_search_strategy`).
     """
     line_search = line_search_strategy(line_search)
     reg = get_regularizer(regularizer)
@@ -203,6 +229,7 @@ def lbfgs(X, y, *, family: type[Family] = Logistic, regularizer=L2,
         x, yv, mask, beta0, jnp.asarray(lamduh, _param_dtype(x)),
         jnp.int32(max_iter), jnp.asarray(tol, _param_dtype(x)),
         family=family, reg=reg, line_search=line_search,
+        objective="linear",
     )
     return _with_counts(beta, counts, return_n_iter, return_counts)
 
@@ -406,10 +433,11 @@ def newton(X, y, *, family: type[Family] = Logistic, regularizer=L2,
 
 @partial(jax.jit, static_argnames=(
     "family", "reg", "mesh_holder", "inner_iter", "line_search",
-    "adaptive_rho"))
+    "adaptive_rho", "objective"))
 def _admm_run(x, yv, mask, lamduh, rho, abstol, reltol, inner_tol, max_it,
               z_init, *, family, reg, mesh_holder, inner_iter,
-              line_search="backtrack", adaptive_rho=True):
+              line_search="backtrack", adaptive_rho=True,
+              objective="black_box"):
     mesh = mesh_holder.mesh
     # rows shard over ('dcn', 'data') on a hierarchical multi-slice mesh
     # (core.distributed.global_mesh(hierarchical=True)) — the psums below
@@ -425,10 +453,9 @@ def _admm_run(x, yv, mask, lamduh, rho, abstol, reltol, inner_tol, max_it,
     def one_shard(xb, yb, mb, z_rep, beta_b, u_b, rho_c):
         u0, b0 = u_b[0], beta_b[0]
 
-        def local_obj(b):
-            return family.loss(b, xb, yb, mb) + 0.5 * rho_c * jnp.sum(
-                (b - z_rep + u0) ** 2
-            )
+        local_obj = _lbfgs_objective(
+            objective, family, xb, yb, mb,
+            lambda b: 0.5 * rho_c * jnp.sum((b - z_rep + u0) ** 2))
 
         with jax.named_scope("admm.local_solve"):
             b_new, st = lbfgs_minimize(
@@ -445,7 +472,8 @@ def _admm_run(x, yv, mask, lamduh, rho, abstol, reltol, inner_tol, max_it,
             beta_norm_sq = lax.psum(jnp.sum(b_new ** 2), row_ax)
             u_norm_sq = lax.psum(jnp.sum(u_new ** 2), row_ax)
             # the round lasts as long as its slowest shard's solve
-            work = lax.pmax(jnp.stack([st.k, st.n_evals]), row_ax)
+            work = lax.pmax(
+                jnp.stack([st.k, st.n_evals, st.n_trials]), row_ax)
         return (b_new[None], u_new[None], z_new, primal_sq, beta_norm_sq,
                 u_norm_sq, work)
 
@@ -548,7 +576,7 @@ def _admm_run(x, yv, mask, lamduh, rho, abstol, reltol, inner_tol, max_it,
     z0 = z_init.astype(_param_dtype(x))
     init = (jnp.int32(0), beta_l0, u_l0, z0,
             jnp.asarray(rho, _param_dtype(x)), inf, inf, zero, zero,
-            jnp.asarray(False), jnp.zeros(2, jnp.int32))
+            jnp.asarray(False), jnp.zeros(3, jnp.int32))
     final = lax.while_loop(cond, body, init)
     return final[3], jnp.concatenate([final[0][None], final[-1]])
 
@@ -576,17 +604,12 @@ def admm(X, y, *, family: type[Family] = Logistic, regularizer=L2,
     stalled below 85% train accuracy at max_iter=150 on separable data
     (tests/test_properties.py :: TestAdversarialSolvers).
 
-    ``line_search`` defaults to ``backtrack`` (not ``auto``).  The chip
-    A/B (``admm_inner_line_search_11000000x28``) measured probe_grid
-    26.9× faster per outer at accuracy parity — but the mechanism is
-    NOT pure line-search efficiency: under the bench's fixed-work
-    config (``inner_tol=0``, ``inner_iter=30``) probe_grid's
-    grid-exhaustion failure exit truncates warm inner solves after a
-    few iterations while backtrack runs all 30; the honest per-work
-    bandwidth win is the standalone lbfgs number (1.24–1.38×).
-    Production configs with ``inner_tol > 0`` get the same early exit
-    from the tolerance itself, so the default stays the conservative
-    backtrack; pass ``auto``/``probe_grid`` explicitly to opt in.
+    ``line_search`` defaults to ``backtrack`` (not ``auto``); pass
+    ``auto``/``probe_grid`` explicitly to opt in.  Either way the local
+    solves search on the cached linear predictor and read X twice an
+    L-BFGS iteration (``lbfgs_core.LinearObjective``); what the two
+    strategies cost on the chip is in PERF.md section 5 (the two
+    ``admm-higgs`` cells run one each).
 
     ``return_counts=True`` returns ``(beta, counts)``, the device vector
     :data:`SOLVE_COUNTS` lays out (per round the slowest shard's inner
@@ -606,7 +629,7 @@ def admm(X, y, *, family: type[Family] = Logistic, regularizer=L2,
         _init_beta(beta0, x, family),
         family=family, reg=reg, mesh_holder=MeshHolder(mesh),
         inner_iter=inner_iter, line_search=line_search,
-        adaptive_rho=adaptive_rho,
+        adaptive_rho=adaptive_rho, objective="linear",
     )
     return _with_counts(beta, counts, return_n_iter, return_counts)
 
@@ -653,14 +676,11 @@ def line_search_strategy(requested: str = "auto") -> str:
     """Resolve a line-search choice, ``DASK_ML_TPU_LINE_SEARCH`` =
     ``auto`` | ``backtrack`` | ``probe_grid``.
 
-    ``auto`` (the :func:`lbfgs` default) picks the measured per-platform
-    winner: ``probe_grid`` on TPU (chip-measured 1.383× over backtrack
-    on the 1M×28 L-BFGS solve, ``bench_chip_evidence.jsonl``
-    ``lbfgs_line_search`` —
-    batching every candidate step into ONE objective pass is
-    bandwidth-optimal when each pass streams the whole dataset from
-    HBM), ``backtrack`` on CPU (probe_grid measured 0.585×, r4: the
-    grid's extra objective evaluations are pure cost when compute-bound).
+    ``auto`` (the :func:`lbfgs` default) picks per platform:
+    ``probe_grid`` on TPU, ``backtrack`` on CPU (the grid evaluates 34
+    candidates where backtracking evaluates a few).  On the chip the two
+    differ by the cost of their trials on vectors of a row's length, not
+    by reads of the design matrix: PERF.md section 5 has the readings.
     An explicit ``requested`` value wins over the env knob; the env knob
     wins over ``auto``.  Resolution must happen OUTSIDE jit (same
     trace-time-staleness rule as ``ops.scatter.scatter_strategy``).
@@ -751,6 +771,11 @@ def packed_solve(solver: str, X, Y, *, family: type[Family] = Logistic,
         # and resolves through the policy for every solver, matching
         # the direct entry points' contract
         line_search = line_search_strategy(line_search)
+    # the same rule for the line search's cached linear predictor: under
+    # vmap it is K x rows floats twice over, so packed lanes keep the
+    # black-box objective; one solve a dispatch caches, as admm() and
+    # lbfgs() do
+    objective = "black_box" if strategy == "packed" else "linear"
     x, _, mask = _prep(X, Y[0])
     dt = _param_dtype(x)
     Yd = jnp.asarray(Y).astype(dt)
@@ -796,6 +821,7 @@ def packed_solve(solver: str, X, Y, *, family: type[Family] = Logistic,
                 jnp.asarray(inner_tol, dt), jnp.int32(max_iter), b0,
                 family=family, reg=reg, mesh_holder=mh,
                 inner_iter=inner_iter, line_search=line_search,
+                objective=objective,
             )
             return beta, counts[0]
 
@@ -823,6 +849,8 @@ def packed_solve(solver: str, X, Y, *, family: type[Family] = Logistic,
     extra_kw = (
         {} if solver == "proximal_grad" else {"line_search": line_search}
     )
+    if solver == "lbfgs":
+        extra_kw["objective"] = objective
 
     def one(yv, b0):
         beta, n_it = run(
@@ -856,6 +884,7 @@ def lambda_sweep(solver: str, X, y, lams, *, family: type[Family] = Logistic,
     reg = get_regularizer(regularizer)
     if line_search != "backtrack":
         line_search = "backtrack"  # same vmap-lane rule as packed_solve
+    objective = "black_box"  # and the same rule for the cached predictor
     x, yd, mask = _prep(X, y)
     dt = _param_dtype(x)
     lam_v = jnp.asarray(np.asarray(lams), dt)
@@ -877,6 +906,7 @@ def lambda_sweep(solver: str, X, y, lams, *, family: type[Family] = Logistic,
                 jnp.zeros(_pdim(x, family), dtype=dt),
                 family=family, reg=reg, mesh_holder=mh,
                 inner_iter=inner_iter, line_search=line_search,
+                objective=objective,
             )
             return beta, counts[0]
 
@@ -902,6 +932,8 @@ def lambda_sweep(solver: str, X, y, lams, *, family: type[Family] = Logistic,
     extra_kw = (
         {} if solver == "proximal_grad" else {"line_search": line_search}
     )
+    if solver == "lbfgs":
+        extra_kw["objective"] = objective
 
     def one(lam, b0):
         beta, n_it = run(

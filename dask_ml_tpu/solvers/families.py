@@ -4,6 +4,16 @@
 TPU-first twist: families only define the masked scalar loss; gradients are
 ``jax.grad`` of it (no hand-derived gradient code to keep in sync), and the
 Newton solver asks for per-sample hessian weights only.
+
+The loss has two parts, and ``loss`` is their composition: the linear
+predictor ``X @ beta`` (the only part that reads the design matrix) and the
+masked pointwise loss of it, ``pointwise_loss(eta, y, mask)``, which is
+what a family defines.  A line search that has ``eta = X @ x`` and
+``u = X @ p`` needs only the second part to evaluate any step along ``p``
+(``solvers/lbfgs_core.py :: LinearObjective``).  A family that overrides
+``loss`` alone still works everywhere: ``admm`` and ``lbfgs`` then search
+on the black-box objective, as they do for a matrix of parameters
+(``algorithms._lbfgs_objective``).
 """
 
 from __future__ import annotations
@@ -11,12 +21,50 @@ from __future__ import annotations
 from functools import lru_cache
 
 import jax.numpy as jnp
+from jax import lax
 
 
 class Family:
+    #: parameters per feature: the flat beta reshapes to (features, K)
+    params_per_feature = 1
+
+    @classmethod
+    def linear_predictor(cls, beta, X):  # eta = X @ beta; (n, K) when K > 1
+        if cls.params_per_feature > 1:
+            beta = beta.reshape(X.shape[1], cls.params_per_feature)
+        return X @ beta
+
+    @classmethod
+    def linear_predictors(cls, X, *betas):
+        """``linear_predictor`` of each of a few parameter vectors in ONE
+        read of ``X``, as a tuple: separate products would each read ``X``.
+        Each result is computed as ``linear_predictor`` computes it.  A
+        matrix of parameters (K > 1) is a matrix product already, so
+        several are one product with their columns side by side.  A vector
+        (K == 1) is a multiply-and-sum in the accumulation dtype, which is
+        what XLA makes of a matrix-vector product, so several are one
+        reduction with several results: a dot with their columns side by
+        side would go to the matrix unit at the backend's default
+        precision instead."""
+        if len(betas) == 1:
+            return (cls.linear_predictor(betas[0], X),)
+        k = cls.params_per_feature
+        if k > 1:
+            eta = X @ jnp.concatenate(
+                [b.reshape(X.shape[1], k) for b in betas], axis=1)
+            return tuple(jnp.split(eta, len(betas), axis=1))
+        terms = tuple(X * b for b in betas)  # (n, d)
+        return lax.reduce(
+            terms, tuple(jnp.zeros((), t.dtype) for t in terms),
+            lambda acc, new: tuple(a + b for a, b in zip(acc, new)), (1,))
+
     @staticmethod
-    def loss(beta, X, y, mask):  # total masked negative log-likelihood
+    def pointwise_loss(eta, y, mask):  # masked total loss of a predictor
         raise NotImplementedError
+
+    @classmethod
+    def loss(cls, beta, X, y, mask):  # total masked negative log-likelihood
+        return cls.pointwise_loss(cls.linear_predictor(beta, X), y, mask)
 
     @staticmethod
     def hessian_weights(eta):  # per-sample d²loss/deta² at linear predictor eta
@@ -31,8 +79,7 @@ class Logistic(Family):
     """y ∈ {0,1}; loss = Σ log(1+exp(Xβ)) − y·Xβ."""
 
     @staticmethod
-    def loss(beta, X, y, mask):
-        eta = X @ beta
+    def pointwise_loss(eta, y, mask):
         # log(1+e^eta) computed stably
         return jnp.sum(mask * (jnp.logaddexp(0.0, eta) - y * eta))
 
@@ -50,8 +97,7 @@ class Normal(Family):
     """Gaussian: loss = ½ Σ (y − Xβ)²."""
 
     @staticmethod
-    def loss(beta, X, y, mask):
-        eta = X @ beta
+    def pointwise_loss(eta, y, mask):
         return 0.5 * jnp.sum(mask * (y - eta) ** 2)
 
     @staticmethod
@@ -82,11 +128,9 @@ def multinomial(n_classes: int) -> type[Family]:
         params_per_feature = n_classes
 
         @staticmethod
-        def loss(beta, X, y, mask):
+        def pointwise_loss(eta, y, mask):  # eta (n, K)
             import jax
 
-            B = beta.reshape(X.shape[1], n_classes)
-            eta = X @ B  # (n, K)
             lse = jax.nn.logsumexp(eta, axis=1)
             onehot = jax.nn.one_hot(
                 y.astype(jnp.int32), n_classes, dtype=eta.dtype
@@ -108,8 +152,7 @@ class Poisson(Family):
     """Counts: loss = Σ exp(Xβ) − y·Xβ."""
 
     @staticmethod
-    def loss(beta, X, y, mask):
-        eta = X @ beta
+    def pointwise_loss(eta, y, mask):
         return jnp.sum(mask * (jnp.exp(eta) - y * eta))
 
     @staticmethod

@@ -9,35 +9,64 @@ recursion as ``lax.fori_loop``, the whole optimizer one ``lax.while_loop``
 ``vmap`` (many small models at once).
 
 Two weak-Wolfe line-search strategies, selected STATICALLY per context
-(:func:`run_line_search`):
+(:func:`run_line_search`), and two ways to evaluate a trial step, selected
+by the KIND of objective (:func:`lbfgs_minimize`).  The strategies are one
+piece of code over a scalar function ``phi(t)`` of the step:
 
 * ``backtrack`` (the default, and REQUIRED under vmap — packed
   one-vs-rest, model cohorts): classic backtrack-then-expand while_loops.
   Under vmap lanes run in lockstep (masked) at the max lane's probe
   count; a ``lax.cond`` grid would execute both branches in every lane.
 * ``probe_grid`` (opt-in for sequential solves): probe the unit step,
-  else evaluate EVERY candidate step 2^k in one vmapped value_and_grad
-  call — XLA batches the candidate matvecs into two S-column gemm
-  passes, so the whole backtrack-and-expand cascade costs ~two
-  design-matrix passes regardless of how many probes sequential search
-  would have made.  Honest CPU measurement (100k x 16 logistic,
-  controlled, interleaved): backtrack 0.29 s vs probe_grid 0.77 s for 4
-  sequential solves — the grid pays all 34 candidates whenever the unit
-  probe fails, which on small compute-bound problems outweighs the saved
-  passes.  On big bandwidth-bound TPU solves the accounting reverses ON
-  PAPER (2 X-passes vs 4+ per backtracking iteration); the default stays
-  backtrack until bench.py's ``line_search`` extra measures the delta on
-  a live chip ("measure before claiming" — the Pallas-Lloyd precedent).
+  else evaluate EVERY candidate step 2^k in one vmapped call of ``phi``.
+
+How ``phi`` is made is what differs between the kinds of objective:
+
+* a black-box callable: ``phi(t)`` evaluates the objective and its
+  gradient at ``x + t p``, so every trial reads whatever the objective
+  reads (for a GLM loss, the whole design matrix);
+* a :class:`LinearObjective` (a pointwise function of a LINEAR map of
+  the parameters, plus a smooth term in the parameters alone): one
+  product gives the map of ``x`` and of ``p`` together, every trial is
+  then a reduction over vectors of the map's length, and one transposed
+  product gives the gradient at the accepted step.  An iteration reads
+  the data twice whatever the search does.
+
+What each costs on the chip is measured, not argued: PERF.md sections 5
+and 6 (the ``admm-higgs`` cells run the two strategies).
 """
 
 from __future__ import annotations
 
-from functools import partial
 from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+#: curvature constant of the weak-Wolfe test
+_C2 = 0.9
+
+
+class LinearObjective(NamedTuple):
+    """``f(b) = pointwise(predict(b)[0]) + smooth(b)``: an objective
+    whose only read of the data is a LINEAR map of the parameters.
+
+    ``predict`` takes a few parameter vectors and returns the tuple of
+    their images in ONE read of the data (``Family.linear_predictors``);
+    ``pointwise`` maps one image to a scalar (``Family.pointwise_loss``
+    with the targets and the mask closed over); ``smooth`` is the part
+    that sees the parameters alone (a penalty; ADMM's proximity term).
+    Calling the objective composes the three, so it also serves wherever
+    a black box is wanted.
+    """
+
+    predict: Callable
+    pointwise: Callable
+    smooth: Callable
+
+    def __call__(self, b):
+        return self.pointwise(self.predict(b)[0]) + self.smooth(b)
 
 
 class LBFGSState(NamedTuple):
@@ -50,10 +79,16 @@ class LBFGSState(NamedTuple):
     k: jax.Array  # iterations taken
     n_updates: jax.Array  # history entries written
     converged: jax.Array
-    # calls of the objective or of value_and_grad at one point, a batched
-    # grid of candidate steps counting once: each reads the data at least
-    # once, so this is a lower bound on passes over the design matrix
+    # operations that read the data, so a lower bound on passes over the
+    # design matrix.  A black-box objective: its calls, or value_and_grad's,
+    # at one point, a batched grid of candidate steps counting once.  A
+    # LinearObjective: the first value_and_grad, then the product and the
+    # transposed product of each iteration (1 + 2 k), never a trial
     n_evals: jax.Array
+    # a LinearObjective's trials: values of phi taken from the cached
+    # images, a batched grid counting once; 0 for a black box, whose
+    # trials are in n_evals
+    n_trials: jax.Array
 
 
 def _two_loop(g, S, Y, rho, n_updates, m):
@@ -87,9 +122,48 @@ def _two_loop(g, S, Y, rho, n_updates, m):
     return lax.fori_loop(0, m, fwd, r)
 
 
-def _backtrack_wolfe(value_and_grad, x, f0, g, p, c1, c2, max_backtracks):
+def _black_box_phi(value_and_grad, x, p):
+    """``phi(t) -> (f, slope, gradient)`` at ``x + t p`` by evaluating the
+    objective there: every call reads what the objective reads."""
+
+    def phi(t):
+        f, g = value_and_grad(x + t * p)
+        return f, jnp.dot(g, p), g
+
+    return phi
+
+
+def _cached_phi(obj: LinearObjective, x, p):
+    """``phi(t) -> (f, slope, ())`` along ``x + t p`` for a
+    :class:`LinearObjective`, from ONE product: the images ``eta`` of
+    ``x`` and ``u`` of ``p`` (the image of ``x + t p`` is ``eta + t u``).
+    Also returns ``gradient_at(t)``, the objective's gradient at
+    ``x + t p`` by one transposed product of the pointwise derivative at
+    ``eta + t u``.  The images live for one search: none is carried
+    between iterations, so none drifts from its product."""
+    eta, u = obj.predict(x, p)
+
+    def image(t):
+        return eta + t * u
+
+    def value(t):
+        return obj.pointwise(image(t)) + obj.smooth(x + t * p)
+
+    def phi(t):
+        f, slope = jax.jvp(value, (t,), (jnp.ones_like(t),))
+        return f, slope, ()
+
+    def gradient_at(t):
+        r = jax.grad(obj.pointwise)(image(t))
+        (g,) = jax.linear_transpose(lambda b: obj.predict(b)[0], x)(r)
+        return g + jax.grad(obj.smooth)(x + t * p)
+
+    return phi, gradient_at
+
+
+def _backtrack_wolfe(phi, f0, dg, c1, c2, max_backtracks):
     """Sequential weak-Wolfe search: Armijo backtracking, then step
-    expansion while the curvature condition gᵀ(x+tp)·p ≥ c2·gᵀp fails but
+    expansion while the curvature condition phi'(t) ≥ c2·phi'(0) fails but
     Armijo still holds at 2t.  Guarantees useful s·y on accepted steps so
     the L-BFGS history builds even in curved nonconvex valleys.
 
@@ -99,8 +173,8 @@ def _backtrack_wolfe(value_and_grad, x, f0, g, p, c1, c2, max_backtracks):
     iteration; these while_loops run lanes in lockstep (masked) at the
     max lane's probe count, which measures far cheaper for packed solves.
     """
-    fun = lambda z: value_and_grad(z)[0]  # noqa: E731
-    dg = jnp.dot(g, p)
+    value = lambda t: phi(t)[0]  # noqa: E731
+    slope = lambda t: phi(t)[1]  # noqa: E731
 
     def bt_cond(carry):
         t, f_new, j = carry
@@ -110,11 +184,11 @@ def _backtrack_wolfe(value_and_grad, x, f0, g, p, c1, c2, max_backtracks):
     def bt_body(carry):
         t, _, j = carry
         t = 0.5 * t
-        return t, fun(x + t * p), j + 1
+        return t, value(t), j + 1
 
     t0 = jnp.asarray(1.0, dtype=f0.dtype)
-    t, f_new, j = lax.while_loop(bt_cond, bt_body, (t0, fun(x + p), 0))
-    n_evals = 1 + j  # the unit step, then one objective call a backtrack
+    t, f_new, j = lax.while_loop(bt_cond, bt_body, (t0, value(t0), 0))
+    n_calls = 1 + j  # the unit step, then one value a backtrack
     failed = (j >= max_backtracks) & (f_new > f0 + c1 * t * dg)
     t = jnp.where(failed, 0.0, t)
     f_new = jnp.where(failed, f0, f_new)
@@ -123,28 +197,44 @@ def _backtrack_wolfe(value_and_grad, x, f0, g, p, c1, c2, max_backtracks):
 
         def ex_cond(carry):
             t, f_t, j = carry
-            g_t = value_and_grad(x + t * p)[1]
-            curv_ok = jnp.dot(g_t, p) >= c2 * dg
+            curv_ok = slope(t) >= c2 * dg
             t2 = 2.0 * t
-            armijo2 = fun(x + t2 * p) <= f0 + c1 * t2 * dg
+            armijo2 = value(t2) <= f0 + c1 * t2 * dg
             return jnp.logical_not(curv_ok) & armijo2 & (j < 8) & (t > 0)
 
         def ex_body(carry):
             t, _, j = carry
             t = 2.0 * t
-            return t, fun(x + t * p), j + 1
+            return t, value(t), j + 1
 
         t, f_new, j_ex = lax.while_loop(ex_cond, ex_body, (t, f_new, 0))
         # every test of the condition (one more than the expansions
-        # taken) evaluates the gradient at t and the objective at 2t;
-        # every expansion evaluates the objective once more
-        n_evals = n_evals + 2 * (j_ex + 1) + j_ex
-    return t, f_new, None, failed, n_evals
+        # taken) takes the slope at t and the value at 2t; every
+        # expansion takes the value once more
+        n_calls = n_calls + 2 * (j_ex + 1) + j_ex
+    return t, f_new, None, failed, n_calls
+
+
+def _search(strategy, phi, f0, dg, c1, c2, max_backtracks):
+    """The search's decisions over ``phi(t) -> (value, slope, aux)``,
+    whichever way ``phi`` is made; ``dg`` is the slope at 0.  Returns
+    ``(t, f_new, aux_or_None, failed, n_calls)``: ``probe_grid`` hands
+    back the ``aux`` of the accepted step (a black box's gradient there),
+    ``backtrack`` None; ``n_calls`` counts the calls of ``phi``, a
+    batched grid counting once."""
+    if strategy == "backtrack":
+        return _backtrack_wolfe(phi, f0, dg, c1, c2, max_backtracks)
+    if strategy == "probe_grid":
+        return _grid_line_search(phi, f0, dg, c1, c2, max_backtracks)
+    raise ValueError(
+        f"line_search must be 'probe_grid' or 'backtrack'; got {strategy!r}"
+    )
 
 
 def run_line_search(strategy, value_and_grad, x, f0, g, p, c1,
-                    max_backtracks, c2=0.9):
-    """Dispatch on the STATIC strategy string.
+                    max_backtracks, c2=_C2):
+    """Line search on a black-box ``value_and_grad``, dispatched on the
+    STATIC strategy string.
 
     Returns ``(t, f_new, g_new_or_None, failed, n_evals)`` —
     ``probe_grid`` already evaluated the gradient at the accepted step
@@ -157,78 +247,69 @@ def run_line_search(strategy, value_and_grad, x, f0, g, p, c1,
     gᵀ(x+tp)·p ≥ c2·gᵀp); ``c2=None`` (STATIC) disables the curvature
     test entirely — pure Armijo, the gradient-descent/newton semantics.
     ``probe_grid`` (sequential contexts): unit-step probe, then one
-    batched grid over every candidate step — fewest objective passes
-    when the data is big.  ``backtrack`` (vmapped contexts): classic
-    sequential backtrack-then-expand in lockstep across lanes.
+    batched grid over every candidate step.  ``backtrack`` (vmapped
+    contexts): classic sequential backtrack-then-expand in lockstep
+    across lanes.
     """
-    if strategy == "backtrack":
-        return _backtrack_wolfe(
-            value_and_grad, x, f0, g, p, c1, c2, max_backtracks
-        )
-    if strategy == "probe_grid":
-        return _grid_line_search(
-            value_and_grad, x, f0, g, p, c1, c2, max_backtracks
-        )
-    raise ValueError(
-        f"line_search must be 'probe_grid' or 'backtrack'; got {strategy!r}"
-    )
+    t, f_new, g_new, failed, n_evals = _search(
+        strategy, _black_box_phi(value_and_grad, x, p), f0, jnp.dot(g, p),
+        c1, c2, max_backtracks)
+    if g_new is not None:
+        # failed: x_new == x, so the caller's current gradient is exact
+        g_new = jnp.where(failed, g, g_new)
+    return t, f_new, g_new, failed, n_evals
 
 
-def _grid_line_search(value_and_grad, x, f0, g, p, c1, c2, max_backtracks,
-                      expansions=3):
+def _grid_line_search(phi, f0, dg, c1, c2, max_backtracks, expansions=3):
     """Weak-Wolfe line search over a geometric step grid, batched evals.
 
     Candidates t_j = 2^(expansions-j), j = 0..expansions+max_backtracks
     (the same 2^-max_backtracks floor sequential backtracking reached,
     plus >1 expansion steps standing in for the sequential expansion
-    phase).  All candidate values AND directional derivatives come from
-    one ``vmap``'d value_and_grad call — for GLM losses XLA batches the
-    candidate matvecs into two S-column gemm passes, so the whole
-    backtrack-and-expand cascade costs ~two design-matrix passes.
+    phase).  All candidate values AND slopes come from one ``vmap``'d
+    call of ``phi``.
     Selection prefers the LARGEST step satisfying Armijo + curvature
     (full weak Wolfe — keeps s·y useful so the L-BFGS history builds in
     curved valleys); if no candidate passes curvature, the largest
     Armijo-passing step; (0, f0, failed=True) when even Armijo never
     holds.  NaN/inf values fail the comparisons and are skipped.
     """
-    dg = jnp.dot(g, p)
     # phase 1: probe the unit step alone — L-BFGS accepts t=1 in the
     # large majority of iterations once the history warms up, and a
     # single-candidate eval costs a fraction of the batched grid
-    f1, g1 = value_and_grad(x + p)
+    one = jnp.asarray(1.0, f0.dtype)
+    f1, s1, aux1 = phi(one)
     unit_ok = f1 <= f0 + c1 * dg
     if c2 is not None:
-        unit_ok = unit_ok & (jnp.dot(g1, p) >= c2 * dg)
+        unit_ok = unit_ok & (s1 >= c2 * dg)
 
     def accept_unit(_):
-        one = jnp.asarray(1.0, f0.dtype)
-        return one, f1, g1, jnp.asarray(False), jnp.asarray(1)
+        return one, f1, aux1, jnp.asarray(False), jnp.asarray(1)
 
     def grid(_):
         n_steps = expansions + 1 + max_backtracks
         ts = jnp.exp2(expansions - jnp.arange(n_steps)).astype(f0.dtype)
-        fs, gs = jax.vmap(lambda t: value_and_grad(x + t * p))(ts)
+        fs, slopes, auxs = jax.vmap(phi)(ts)
         armijo = fs <= f0 + c1 * ts * dg
         any_a = jnp.any(armijo)
         # descending ts: argmax = first True = largest passing step
         if c2 is not None:
-            wolfe = armijo & (gs @ p >= c2 * dg)
+            wolfe = armijo & (slopes >= c2 * dg)
             idx = jnp.where(jnp.any(wolfe), jnp.argmax(wolfe),
                             jnp.argmax(armijo))
         else:
             idx = jnp.argmax(armijo)
         t = jnp.where(any_a, ts[idx], 0.0)
         f_new = jnp.where(any_a, fs[idx], f0)
-        # failed: x_new == x, so the caller's current gradient is exact
-        g_new = jnp.where(any_a, gs[idx], g)
+        aux = jax.tree_util.tree_map(lambda a: a[idx], auxs)
         # the unit probe, and all candidates in one batched call
-        return t, f_new, g_new, jnp.logical_not(any_a), jnp.asarray(2)
+        return t, f_new, aux, jnp.logical_not(any_a), jnp.asarray(2)
 
     return lax.cond(unit_ok, accept_unit, grid, None)
 
 
 def lbfgs_minimize(
-    fun: Callable,
+    fun: Callable | LinearObjective,
     x0,
     *,
     max_iter: int = 100,
@@ -239,6 +320,15 @@ def lbfgs_minimize(
     line_search: str = "backtrack",
 ):
     """Minimize a traceable scalar function; returns (x, LBFGSState).
+
+    ``fun`` is a black-box callable, or a :class:`LinearObjective`: then
+    an iteration reads the data twice (the images of ``x`` and ``p`` in
+    one product before the search, one transposed product for the
+    gradient at the accepted step) and every trial of the search works
+    on the cached images.  Direction, history update, the Wolfe tests
+    and the three exits are the same for both.  A caller about to
+    ``vmap`` this function passes a plain callable: under vmap the
+    cached images are lanes x their length.
 
     Convergence: ‖g‖_∞ ≤ tol (scipy's ``pgtol``), OR relative objective
     decrease ≤ 10·eps(dtype) (scipy's ``factr``-style stagnation exit,
@@ -251,12 +341,11 @@ def lbfgs_minimize(
     line-search-failure exit still fires — a lane that cannot take any
     step has no further work worth timing), which is how the bench gets
     its fixed-iteration-count runs.
-    ``line_search``: ``backtrack`` (default — the measured-safe choice on
-    CPU; REQUIRED under ``vmap``) or ``probe_grid`` (batched grid — the
-    bandwidth-optimal candidate for big-n TPU solves; flip per solve via
-    ``solver_kwargs`` once the chip delta is measured — see
-    :func:`run_line_search` and bench.py's ``line_search`` extra).
+    ``line_search``: ``backtrack`` (default; REQUIRED under ``vmap``) or
+    ``probe_grid`` (batched grid); what each costs is in PERF.md
+    section 5.
     """
+    linear = isinstance(fun, LinearObjective)
     value_and_grad = jax.value_and_grad(fun)
     m = history
     d = x0.shape[0]
@@ -274,6 +363,7 @@ def lbfgs_minimize(
         n_updates=jnp.asarray(0),
         converged=jnp.max(jnp.abs(g0)) <= tol,
         n_evals=jnp.asarray(1),
+        n_trials=jnp.asarray(0),
     )
 
     def cond(st: LBFGSState):
@@ -288,16 +378,26 @@ def lbfgs_minimize(
             descent = jnp.dot(p, st.g) < 0
             p = jnp.where(descent, p, -st.g)
         with jax.named_scope("lbfgs.line_search"):
-            t, f_ls, g_ls, failed, n_evals = run_line_search(
-                line_search, value_and_grad, st.x, st.f, st.g, p, c1,
-                max_backtracks,
-            )
-            x_new = st.x + t * p
-            if g_ls is None:  # static per strategy: backtrack re-evaluates
-                f_new, g_new = value_and_grad(x_new)
-                n_evals = n_evals + 1
-            else:  # probe_grid already evaluated (f, g) at the accepted step
-                f_new, g_new = f_ls, g_ls
+            if linear:  # static per kind of objective
+                phi, gradient_at = _cached_phi(fun, st.x, p)
+                t, f_new, _, failed, n_trials = _search(
+                    line_search, phi, st.f, jnp.dot(st.g, p), c1, _C2,
+                    max_backtracks)
+                x_new = st.x + t * p
+                g_new = gradient_at(t)
+                n_evals = 2  # the product and the transposed product
+            else:
+                t, f_ls, g_ls, failed, n_evals = run_line_search(
+                    line_search, value_and_grad, st.x, st.f, st.g, p, c1,
+                    max_backtracks,
+                )
+                n_trials = 0
+                x_new = st.x + t * p
+                if g_ls is None:  # static per strategy: backtrack re-evaluates
+                    f_new, g_new = value_and_grad(x_new)
+                    n_evals = n_evals + 1
+                else:  # probe_grid evaluated (f, g) at the accepted step
+                    f_new, g_new = f_ls, g_ls
         with jax.named_scope("lbfgs.update"):
             s = x_new - st.x
             y = g_new - st.g
@@ -323,6 +423,7 @@ def lbfgs_minimize(
                 x=x_new, f=f_new, g=g_new, S=S, Y=Y, rho=rho,
                 k=st.k + 1, n_updates=n_updates, converged=converged,
                 n_evals=st.n_evals + n_evals,
+                n_trials=st.n_trials + n_trials,
             )
 
     final = lax.while_loop(cond, body, init)

@@ -800,7 +800,7 @@ class TestProfilerSessionArmsSpans:
     def test_set_reaches_the_open_annotation(self, traced):
         (_, _, stats), = traced["host"]["glm.solve"]
         solve = next(r for r in traced["records"] if r["name"] == "glm.solve")
-        for count in ("rounds", "inner_iters", "passes"):
+        for count in ("rounds", "inner_iters", "passes", "trials"):
             assert stats[count] == solve["attrs"][count] > 0
         (_, _, fstats), = traced["host"]["glm.fit"]
         assert fstats["rows"] == 600  # set() after entering, on the root
@@ -820,7 +820,7 @@ class TestProfilerSessionArmsSpans:
         assert a["passes"] >= a["rounds"] + a["inner_iters"]
         c = traced["counters"]
         assert c["solve.count"] == 1
-        for count in ("rounds", "inner_iters", "passes"):
+        for count in ("rounds", "inner_iters", "passes", "trials"):
             assert c[f"solve.{count}"] / c["solve.count"] == a[count]
 
 

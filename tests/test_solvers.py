@@ -311,6 +311,20 @@ class TestLambdaSweep:
             lambda_sweep("lbfgs", X, y, [[0.1, 1.0]], family=Logistic)
 
 
+def _force_objective(monkeypatch, kind):
+    """Every dispatch of the counted runners asks for ``kind``, the
+    runners' private static argument: what ``packed_solve`` and
+    ``lambda_sweep`` ask for their vmapped lanes, here for the single
+    solves ``admm()`` / ``lbfgs()`` they are compared with."""
+    from dask_ml_tpu.solvers import algorithms
+
+    for name in ("_admm_run", "_lbfgs_run"):
+        run = getattr(algorithms, name)
+        monkeypatch.setattr(
+            algorithms, name,
+            lambda *a, _run=run, **kw: _run(*a, **{**kw, "objective": kind}))
+
+
 class TestSolveCounts:
     """ISSUE 26 part C: ``LBFGSState.n_evals`` and the ``SOLVE_COUNTS``
     vector the counted runners carry out of the solve."""
@@ -351,13 +365,15 @@ class TestSolveCounts:
         X, y, _ = logistic_data
         kw = dict(family=Logistic, lamduh=1.0, line_search=line_search)
         beta, counts = solvers.admm(X, y, return_counts=True, **kw)
-        rounds, inner, passes = (int(c) for c in counts)
+        rounds, inner, passes, trials = (int(c) for c in counts)
         assert solvers.algorithms.SOLVE_COUNTS == (
-            "rounds", "inner_iters", "passes")
-        assert counts.dtype == jnp.int32 and counts.shape == (3,)
-        # every round evaluates once at its start and at least once an
-        # inner iteration
-        assert rounds >= 1 and passes >= rounds + inner
+            "rounds", "inner_iters", "passes", "trials")
+        assert counts.dtype == jnp.int32 and counts.shape == (4,)
+        # every round reads X once at its start (a value_and_grad) and
+        # twice an inner iteration (the product, the gradient); every
+        # iteration tries at least its unit step
+        assert rounds >= 1 and passes == rounds + 2 * inner
+        assert trials >= inner
         beta2, n_it = solvers.admm(X, y, return_n_iter=True, **kw)
         assert int(n_it) == rounds  # the scalar contract is unchanged
         np.testing.assert_array_equal(np.asarray(beta), np.asarray(beta2))
@@ -369,10 +385,13 @@ class TestSolveCounts:
         X, y, _ = logistic_data
         _, counts = solvers.lbfgs(X, y, lamduh=1.0, return_counts=True,
                                   line_search="backtrack")
-        rounds, inner, passes = (int(c) for c in counts)
+        rounds, inner, passes, trials = (int(c) for c in counts)
         _, n_it = solvers.lbfgs(X, y, lamduh=1.0, return_n_iter=True,
                                 line_search="backtrack")
-        assert rounds == inner == int(n_it) and passes >= 1 + inner
+        assert rounds == inner == int(n_it) and passes == 1 + 2 * inner
+        # backtrack: the unit step's value, then the curvature test's
+        # slope at t and value at 2t, at least
+        assert trials >= 3 * inner
 
     @pytest.mark.parametrize("solver", ["admm", "lbfgs"])
     @pytest.mark.parametrize("strategy", ["packed", "sequential"])
@@ -381,6 +400,11 @@ class TestSolveCounts:
         from dask_ml_tpu.solvers import packed_solve
 
         monkeypatch.setenv("DASK_ML_TPU_PACK", strategy)
+        if strategy == "packed":
+            # lanes under vmap run the black-box objective: compare them
+            # with a single solve of the same kind (the sequential arm
+            # dispatches what admm() / lbfgs() dispatch)
+            _force_objective(monkeypatch, "black_box")
         X, y, _ = logistic_data
         sX = shard_rows(X)
         Y = np.zeros((2, sX.data.shape[0]), np.float32)
@@ -399,7 +423,9 @@ class TestSolveCounts:
 
     @pytest.mark.parametrize("solver", ["admm", "lbfgs"])
     def test_lambda_sweep_keeps_scalar_iterations(
-            self, logistic_data, solver):
+            self, logistic_data, monkeypatch, solver):
+        # the sweep's lanes run the black-box objective, as packed lanes do
+        _force_objective(monkeypatch, "black_box")
         X, y, _ = logistic_data
         lams = [0.1, 1.0, 10.0]
         betas, n_its = lambda_sweep(solver, X, y, lams, family=Logistic)
@@ -411,3 +437,413 @@ class TestSolveCounts:
             assert int(n_its[lane]) == int(n_it)
             np.testing.assert_allclose(np.asarray(betas[lane]),
                                        np.asarray(single), atol=2e-4)
+
+    @pytest.mark.parametrize("solver,lamduh,atol", [
+        # one L-BFGS run to its own tolerance: the two kinds of objective
+        # take the same iterations and end apart by float32 rounding
+        # (1.2e-7 here)
+        ("lbfgs", 0.1, 1e-5),
+        ("lbfgs", 1.0, 1e-5),
+        # ADMM stops by Boyd's rule (abstol 1e-4, reltol 1e-2) while still
+        # 2e-2 from the optimum on these 38-row shards, and each round's
+        # local solves start from the last round's rounding: the same
+        # rounds, and answers 2.1e-5 apart at lamduh 1 ...
+        ("admm", 1.0, 2e-4),
+        # ... and 1.0e-3 apart under the weaker penalty, a twentieth of
+        # the distance either stands from the optimum
+        ("admm", 0.1, 2e-3),
+    ])
+    def test_black_box_and_cached_predictor_single_solves(
+            self, logistic_data, monkeypatch, solver, lamduh, atol):
+        """ISSUE 29 left the vmapped callers on the black-box objective
+        and moved ``admm()`` / ``lbfgs()`` to the cached linear predictor:
+        how far apart the two end on one problem is stated here, and not
+        hidden in the lane-against-single comparisons above."""
+        X, y, _ = logistic_data
+        kw = dict(family=Logistic, lamduh=lamduh, return_n_iter=True,
+                  line_search="backtrack")
+        linear, n_linear = getattr(solvers, solver)(X, y, **kw)
+        _force_objective(monkeypatch, "black_box")
+        black_box, n_black_box = getattr(solvers, solver)(X, y, **kw)
+        assert int(n_linear) == int(n_black_box)
+        np.testing.assert_allclose(
+            np.asarray(linear), np.asarray(black_box), atol=atol)
+
+
+def _family_problem(name, rng, n=256, d=5):
+    """A small table for each family: ``(family, x, y, mask, lam)`` with
+    the last rows masked out, as ``shard_rows`` pads."""
+    X = rng.normal(size=(n, d)).astype(np.float32)
+    w = rng.normal(size=d)
+    if name == "logistic":
+        family = Logistic
+        y = (rng.uniform(size=n) < 1 / (1 + np.exp(-(X @ w)))).astype(
+            np.float32)
+    elif name == "normal":
+        family = Normal
+        y = (X @ w + 0.1 * rng.normal(size=n)).astype(np.float32)
+    elif name == "poisson":
+        family = Poisson
+        y = rng.poisson(np.exp(0.3 * (X @ w))).astype(np.float32)
+    else:
+        family = multinomial(3)
+        y = np.argmax(X @ rng.normal(size=(d, 3))
+                      + rng.gumbel(size=(n, 3)), axis=1).astype(np.float32)
+    mask = (np.arange(n) < n - 8).astype(np.float32)
+    return family, jnp.asarray(X), jnp.asarray(y), jnp.asarray(mask), 0.5
+
+
+FAMILIES = ["logistic", "normal", "poisson", "multinomial3"]
+
+
+class TestLinearObjective:
+    """ISSUE 29: the line search on the cached linear predictor.  A
+    ``LinearObjective`` and the black box of the same loss take the same
+    steps; what differs is what an iteration reads."""
+
+    def _objectives(self, name, rng):
+        """``(pdim, structured, black box)`` of one small problem.  The
+        structured objective is put together here from the family's
+        parts, for every family: what the entry points hand each family
+        is ``test_which_families_search_on_the_cached_predictor``."""
+        from dask_ml_tpu.solvers.algorithms import _pdim
+        from dask_ml_tpu.solvers.lbfgs_core import LinearObjective
+
+        family, x, y, mask, lam = _family_problem(name, rng)
+        smooth = lambda b: L2.penalty(b, lam)  # noqa: E731
+        linear = LinearObjective(
+            predict=lambda *betas: family.linear_predictors(x, *betas),
+            pointwise=lambda eta: family.pointwise_loss(eta, y, mask),
+            smooth=smooth)
+        return (_pdim(x, family), linear,
+                lambda b: family.loss(b, x, y, mask) + smooth(b))
+
+    @pytest.mark.parametrize("family,cached", [
+        ("logistic", True), ("normal", True), ("poisson", True),
+        # a matrix of parameters: the cached predictor is (rows, K) twice
+        # and a solve on it read slower on the chip than the black box
+        # (PERF.md section 6, PR 29), so the entry points keep the latter
+        ("multinomial3", False),
+    ])
+    def test_which_families_search_on_the_cached_predictor(
+            self, rng, family, cached):
+        from dask_ml_tpu.solvers.algorithms import _lbfgs_objective
+        from dask_ml_tpu.solvers.lbfgs_core import LinearObjective
+
+        fam, x, y, mask, lam = _family_problem(family, rng)
+        smooth = lambda b: L2.penalty(b, lam)  # noqa: E731
+        asked = _lbfgs_objective("linear", fam, x, y, mask, smooth)
+        assert isinstance(asked, LinearObjective) == cached
+        assert not isinstance(
+            _lbfgs_objective("black_box", fam, x, y, mask, smooth),
+            LinearObjective)
+        with pytest.raises(ValueError, match="unknown objective kind"):
+            _lbfgs_objective("cached", fam, x, y, mask, smooth)
+
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_loss_is_the_composition_of_its_parts(self, rng, family):
+        fam, x, y, mask, _ = _family_problem(family, rng)
+        d = x.shape[1] * fam.params_per_feature
+        b, c = (jnp.asarray(rng.normal(size=d), jnp.float32)
+                for _ in range(2))
+        eta = fam.linear_predictor(b, x)
+        assert eta.shape == ((256,) if fam.params_per_feature == 1
+                             else (256, 3))
+        np.testing.assert_allclose(
+            float(fam.loss(b, x, y, mask)),
+            float(fam.pointwise_loss(eta, y, mask)), rtol=1e-6)
+        # several predictors in one reduction equal the products one by one
+        one, = fam.linear_predictors(x, b)
+        np.testing.assert_array_equal(np.asarray(one), np.asarray(eta))
+        pair = fam.linear_predictors(x, b, c)
+        for got, beta in zip(pair, (b, c)):
+            np.testing.assert_allclose(
+                np.asarray(got), np.asarray(fam.linear_predictor(beta, x)),
+                rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_phi_from_cached_images_equals_the_objective_on_the_line(
+            self, rng, family):
+        import jax
+
+        from dask_ml_tpu.solvers.lbfgs_core import (
+            _black_box_phi, _cached_phi)
+
+        d, linear, black_box = self._objectives(family, rng)
+        x = jnp.asarray(0.3 * rng.normal(size=d), jnp.float32)
+        p = jnp.asarray(0.3 * rng.normal(size=d), jnp.float32)
+        vg = jax.value_and_grad(black_box)
+        phi, gradient_at = _cached_phi(linear, x, p)
+        reference = _black_box_phi(vg, x, p)
+        for t in (0.0, 0.125, 1.0, 2.0):
+            t = jnp.float32(t)
+            f, slope, aux = phi(t)
+            f_ref, g_ref = vg(x + t * p)
+            assert aux == ()
+            np.testing.assert_allclose(float(f), float(f_ref), rtol=2e-6)
+            np.testing.assert_allclose(
+                float(slope), float(jnp.dot(g_ref, p)), rtol=2e-4, atol=1e-4)
+            np.testing.assert_allclose(
+                float(slope), float(reference(t)[1]), rtol=2e-4, atol=1e-4)
+            np.testing.assert_allclose(
+                np.asarray(gradient_at(t)), np.asarray(g_ref),
+                rtol=2e-4, atol=2e-4)
+        # called as a function, the structured objective is the black box
+        np.testing.assert_allclose(
+            float(linear(x)), float(black_box(x)), rtol=2e-6)
+
+    @pytest.mark.parametrize("line_search", ["backtrack", "probe_grid"])
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_same_iterations_steps_and_answer_as_the_black_box(
+            self, rng, family, line_search):
+        d, linear, black_box = self._objectives(family, rng)
+        x0 = jnp.zeros(d, jnp.float32)
+        kw = dict(max_iter=60, tol=1e-4, line_search=line_search)
+        x_lin, lin = lbfgs_minimize(linear, x0, **kw)
+        x_bb, bb = lbfgs_minimize(black_box, x0, **kw)
+        k = int(lin.k)
+        assert k == int(bb.k) and k >= 3
+        assert bool(lin.converged) and bool(bb.converged)
+        np.testing.assert_allclose(
+            np.asarray(x_lin), np.asarray(x_bb), rtol=1e-4, atol=1e-5)
+        # the accepted steps s = t p still in the history (the last ten)
+        np.testing.assert_allclose(
+            np.asarray(lin.S), np.asarray(bb.S), rtol=1e-3, atol=1e-5)
+        # reads of the data: the first value_and_grad, then the product
+        # and the transposed product of each iteration; the black box's
+        # count holds its trials, and has none apart
+        assert int(lin.n_evals) == 1 + 2 * k
+        assert int(bb.n_trials) == 0
+        # the trials are the black box's evaluations inside the searches:
+        # all but the first and, under backtrack, the one after each search
+        extra = k if line_search == "backtrack" else 0
+        assert int(lin.n_trials) == int(bb.n_evals) - 1 - extra
+
+    @staticmethod
+    def _quadratic(diag):
+        """0.5 x' diag x as a LinearObjective: predict scales by the
+        root of the diagonal, pointwise is half the squared norm."""
+        from dask_ml_tpu.solvers.lbfgs_core import LinearObjective
+
+        root = jnp.sqrt(jnp.asarray(diag, jnp.float32))
+        return LinearObjective(
+            predict=lambda *bs: tuple(root * b for b in bs),
+            pointwise=lambda eta: 0.5 * jnp.sum(eta ** 2),
+            smooth=lambda b: jnp.float32(0.0))
+
+    @pytest.mark.parametrize("line_search,per_iter", [
+        # the unit step's value, then the curvature test's slope at t
+        # and value at 2t
+        ("backtrack", 3),
+        # the unit probe gives value and slope at once
+        ("probe_grid", 1),
+    ])
+    def test_counts_by_hand_when_every_unit_step_is_accepted(
+            self, line_search, per_iter):
+        x, st = lbfgs_minimize(self._quadratic([0.6, 0.8, 1.0]),
+                               jnp.ones(3), tol=1e-6,
+                               line_search=line_search)
+        k = int(st.k)
+        assert k >= 4 and bool(st.converged)
+        assert int(st.n_evals) == 1 + 2 * k
+        assert int(st.n_trials) == per_iter * k
+
+    @pytest.mark.parametrize("line_search,trials", [
+        # from (1, 1) along -g = -(1, 100): Armijo fails at t = 1, 1/2,
+        # ..., 1/32 and holds at 1/64 (seven values), where the slope is
+        # positive, so the one curvature test (slope at t, value at 2t)
+        # ends the search
+        ("backtrack", 9),
+        # the unit probe fails, then the grid: one batched call
+        ("probe_grid", 2),
+    ])
+    def test_counts_by_hand_on_a_step_that_backtracks(
+            self, line_search, trials):
+        _, st = lbfgs_minimize(self._quadratic([1.0, 100.0]), jnp.ones(2),
+                               tol=1e-6, max_iter=1,
+                               line_search=line_search)
+        assert int(st.k) == 1
+        assert int(st.n_evals) == 3 and int(st.n_trials) == trials
+
+    @pytest.mark.parametrize("solver", ["admm", "lbfgs"])
+    def test_a_family_with_loss_alone_solves_on_the_black_box(
+            self, logistic_data, monkeypatch, solver):
+        """A ``Family`` subclass written before ISSUE 29 overrides
+        ``loss`` and has no ``pointwise_loss``: ``admm()`` / ``lbfgs()``
+        ask for the cached predictor and such a family gets the black
+        box, the program it had."""
+        from dask_ml_tpu.solvers.families import Family
+
+        class LossAlone(Family):
+            @staticmethod
+            def loss(beta, X, y, mask):
+                eta = X @ beta
+                return jnp.sum(mask * (jnp.logaddexp(0.0, eta) - y * eta))
+
+        X, y, _ = logistic_data
+        kw = dict(lamduh=1.0, return_counts=True, line_search="backtrack")
+        beta, counts = getattr(solvers, solver)(X, y, family=LossAlone, **kw)
+        assert int(counts[3]) == 0  # no trial came from a cached predictor
+        _force_objective(monkeypatch, "black_box")
+        ref, ref_counts = getattr(solvers, solver)(
+            X, y, family=Logistic, **kw)
+        np.testing.assert_array_equal(np.asarray(counts),
+                                      np.asarray(ref_counts))
+        np.testing.assert_allclose(np.asarray(beta), np.asarray(ref),
+                                   rtol=1e-6, atol=1e-7)
+
+    def test_glm_solve_span_carries_trials(self, logistic_data):
+        from dask_ml_tpu import obs
+        from dask_ml_tpu.linear_model import LogisticRegression
+
+        was_on = obs.enabled()
+        obs.enable()
+        try:
+            X, y, _ = logistic_data
+            LogisticRegression(solver="admm").fit(X, y)
+            solve = next(c for c in obs.span_tree()["children"]
+                         if c["name"] == "glm.solve")
+        finally:
+            if not was_on:
+                obs.disable()
+        a = solve["attrs"]
+        assert set(solvers.algorithms.SOLVE_COUNTS) <= set(a)
+        assert a["passes"] == a["rounds"] + 2 * a["inner_iters"]
+        assert a["trials"] >= 3 * a["inner_iters"]
+
+    @pytest.mark.parametrize("solver", ["admm", "lbfgs"])
+    def test_vmapped_callers_keep_the_black_box_program(
+            self, logistic_data, monkeypatch, mesh, solver):
+        """``packed_solve`` and ``lambda_sweep`` put the runners under
+        ``vmap`` and ask for the black-box objective: their programs
+        hold the 14 products with X they held before ISSUE 29, and no
+        reduction with two results (the pair product)."""
+        import jax
+
+        from dask_ml_tpu.solvers import packed_solve
+
+        def eqns(jaxpr):
+            for eqn in jaxpr.eqns:
+                yield eqn
+                for v in eqn.params.values():
+                    for j in (v if isinstance(v, (tuple, list)) else (v,)):
+                        j = getattr(j, "jaxpr", j)
+                        if hasattr(j, "eqns"):
+                            yield from eqns(j)
+
+        def census(fn, shape):
+            # X whole, or one shard's rows of it (admm's shard_map)
+            shapes = (shape, (shape[0] // mesh.devices.size, shape[1]))
+            found = list(eqns(jax.make_jaxpr(fn)().jaxpr))
+            dots = sum(
+                e.primitive.name == "dot_general" and any(
+                    tuple(v.aval.shape) in shapes for v in e.invars)
+                for e in found)
+            pairs = sum(e.primitive.name == "reduce" and len(e.outvars) > 1
+                        for e in found)
+            return dots, pairs
+
+        X, y, _ = logistic_data
+        sX = shard_rows(X)
+        shape = tuple(sX.data.shape)
+        Y = np.zeros((2, shape[0]), np.float32)
+        Y[0, :len(y)], Y[1, :len(y)] = y, 1 - y
+        kw = dict(family=Logistic, lamduh=1.0)
+        monkeypatch.setenv("DASK_ML_TPU_PACK", "packed")
+        assert census(lambda: packed_solve(
+            solver, sX, jnp.asarray(Y), **kw), shape) == (14, 0)
+        assert census(lambda: lambda_sweep(
+            solver, sX, Y[0], [0.1, 1.0], family=Logistic), shape) == (14, 0)
+        # one solve a dispatch: the first value_and_grad (two products),
+        # then the pair and one transposed product an iteration
+        monkeypatch.setenv("DASK_ML_TPU_PACK", "sequential")
+        dots, pairs = census(lambda: packed_solve(
+            solver, sX, jnp.asarray(Y), **kw), shape)
+        assert (dots, pairs) == (2 * 3, 2 * 1)
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One chip of a DESCRIBED v5e (the TPU's compiler runs here without
+    the chip): a sharding to give ``jax.ShapeDtypeStruct``s.  Described
+    inside the fixture, never at import: one process at a time may load
+    the TPU's library."""
+    from jax.experimental import topologies
+    from jax.sharding import Mesh
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here, or its lock is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+
+
+class TestCompiledForTheChip:
+    """ISSUE 29, at the benchmark's size (31,250,000 x 29 on one v5e):
+    what the chip's compiler makes of the whole-solve program.  Compiled,
+    never run: no time or result comes from here."""
+
+    ROWS, D = 31_250_000, 29
+
+    @pytest.fixture()
+    def lower(self, v5e_chip):
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from dask_ml_tpu.core.mesh import MeshHolder
+        from dask_ml_tpu.solvers.algorithms import _admm_run
+
+        def S(shape, spec, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(
+                shape, dtype, sharding=NamedSharding(v5e_chip, spec))
+
+        scalars = [S((), P())] * 5
+        args = (S((self.ROWS, self.D), P("data", None)),
+                S((self.ROWS,), P("data")), S((self.ROWS,), P("data")),
+                *scalars, S((), P(), jnp.int32), S((self.D,), P()))
+        # a compile for a described chip is written to the persistent
+        # cache and cannot be read back without one: keep it out
+        from jax.experimental.compilation_cache import compilation_cache
+
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield lambda **kw: _admm_run.lower(
+                *args, family=Logistic, reg=L2,
+                mesh_holder=MeshHolder(v5e_chip), inner_iter=30,
+                **kw).compile()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            compilation_cache.reset_cache()
+
+    @pytest.mark.parametrize("line_search", ["backtrack", "probe_grid"])
+    def test_cached_predictor_holds_four_row_vectors_and_one_pair_product(
+            self, lower, line_search):
+        import re
+
+        compiled = lower(line_search=line_search, objective="linear")
+        vector = self.ROWS * 4
+        # a CEILING, and over the one ISSUE 29 set (the black box's two
+        # vectors and 80 MB): eta, u, the residual and the hoisted
+        # -y * mask, which the black box holds too.  The residual does
+        # not take eta's place, as it does in the black box, because eta
+        # and u are the two results of ONE fusion (PERF.md section 7).
+        # Under probe_grid the 34 candidates are one fused reduction over
+        # the four, and a rows x 34 array (4.25 GB) is never made
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < 4.1 * vector
+        # the pair X @ [x | p]: ONE fusion with two results of a row's
+        # length, fed by the table
+        pair = re.findall(
+            r"= \(f32\[%d\]\S*, f32\[%d\]\S*\) fusion\(" % (
+                self.ROWS, self.ROWS), compiled.as_text())
+        assert len(pair) == 1
+
+    def test_black_box_program_is_the_parents(self, lower):
+        # what packed_solve's and lambda_sweep's lanes run, here without
+        # the vmap: two row vectors of temporaries, as before ISSUE 29
+        compiled = lower(line_search="backtrack", objective="black_box")
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert 2 * self.ROWS * 4 < temp < 2.1 * self.ROWS * 4  # 252,606,464
