@@ -160,7 +160,7 @@ def project_digest(sources, select=None) -> str:
         # the contract-baseline-drift rule reads the committed ratchet
         # files next to the docs root; rebaselining must invalidate
         root = os.path.dirname(os.path.dirname(api_md))
-        for stem in ("perf", "drill", "lock"):
+        for stem in ("drill", "lock"):
             bl = os.path.join(root, "tools", f"{stem}_baseline.json")
             try:
                 with open(bl, "rb") as fh:

@@ -3,8 +3,8 @@
 Nineteen PRs of planes coordinate almost entirely through STRING
 contracts: ``RequestRejected(reason=...)`` strings the fleet router
 classifies as retryable, graftpath verdict classes keyed into the
-autopilot POLICY table, registry metric families pinned by the perf
-baseline and scraped via ``/metrics``, injection-point names drilled by
+autopilot POLICY table, registry metric families scraped via
+``/metrics``, injection-point names drilled by
 the chaos ratchet, thread/lock names rostered in ``rules/_spmd.py``,
 knob names resolved through ``control/knobs.KNOBS``.  Nothing *ran*
 when one side drifted: a renamed reason silently turns a retryable
@@ -24,7 +24,7 @@ Families (the design.md §23 table, one row per entry here):
   ``_NON_RETRYABLE`` rosters (serve/fleet.py).
 * **verdict-class** — declared by ``BOTTLENECK_CLASSES``
   (obs/critical.py); consumed by the ``POLICY`` table keys
-  (control/pilot.py) and the perf baseline's bottleneck pins.
+  (control/pilot.py).
 * **metric-family** — produced by ``registry.counter/gauge/histogram
   (name, ...)`` (literal or f-string prefix); consumed by
   ``registry.family(name)`` lookups, ``_PROGRESS_FAMILIES``, and the
@@ -39,8 +39,7 @@ Families (the design.md §23 table, one row per entry here):
   rosters (``KNOWN_THREAD_NAMES``, ``LOCK_THREAD_CONTRACTS``) and the
   lock baseline's edge set.
 * **knob-name** — declared by ``Knob(name, env, ...)``; consumed by
-  ``knobs.set_knob/override/override_or/observe/knob(name)`` and the
-  perf baseline's ``knob_trajectory``.
+  ``knobs.set_knob/override/override_or/observe/knob(name)``.
 
 Pure ``ast`` like the rest of the engine — never imports the package
 under analysis.  Extraction is conservative: a reason/name the

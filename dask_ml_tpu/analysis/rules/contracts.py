@@ -23,7 +23,7 @@ from .. import contracts as _c
 
 #: baseline-drift checks: committed tools/<stem>_baseline.json file →
 #: which contract family pins its keys
-_PERF_STEM, _DRILL_STEM, _LOCK_STEM = "perf", "drill", "lock"
+_DRILL_STEM, _LOCK_STEM = "drill", "lock"
 
 
 def _finding(rule, site: _c.Site, message: str):
@@ -264,52 +264,13 @@ class ContractBaselineDriftRule(Rule):
     project_wide = True
     summary = (
         "committed tools/*_baseline.json pins a contract string the "
-        "code no longer produces (verdict class, knob, injection "
-        "point, lock name) — the ratchet would compare against a "
+        "code no longer produces (injection point, lock name) — the "
+        "ratchet would compare against a "
         "family that can never recur"
     )
 
     def run_project(self, project):
         model = _c.model_for(project)
-        perf = model.committed_baseline(_PERF_STEM)
-        if perf and model.verdict_classes:
-            classes = {s.value for s in model.verdict_classes}
-            knobs = model.declared_knobs()
-            anchor = model.verdict_classes[0]
-            knob_anchor = model.knob_declared[0] \
-                if model.knob_declared else None
-            for wname, wk in sorted(perf.get("workloads", {}).items()):
-                cls = (wk.get("bottleneck") or {}).get("class")
-                if cls is not None and cls not in classes:
-                    yield _finding(
-                        self, anchor,
-                        f"perf baseline workload {wname!r} pins "
-                        f"bottleneck class {cls!r} which is not in "
-                        f"BOTTLENECK_CLASSES — the v3 class-flip gate "
-                        f"compares against a verdict graftpath can "
-                        f"never emit (rebaseline or restore the "
-                        f"class)",
-                    )
-                for move in wk.get("knob_trajectory", ()):
-                    mcls = move.get("class")
-                    if mcls is not None and mcls not in classes:
-                        yield _finding(
-                            self, anchor,
-                            f"perf baseline workload {wname!r} "
-                            f"trajectory pins verdict class {mcls!r} "
-                            f"outside BOTTLENECK_CLASSES",
-                        )
-                    mknob = move.get("knob")
-                    if knob_anchor is not None and mknob is not None \
-                            and mknob not in knobs:
-                        yield _finding(
-                            self, knob_anchor,
-                            f"perf baseline workload {wname!r} "
-                            f"trajectory moves knob {mknob!r} which "
-                            f"control/knobs.KNOBS does not declare — "
-                            f"the controller convergence entry pins a "
-                            f"lever that no longer exists",
-                        )
         drill = model.committed_baseline(_DRILL_STEM)
         if drill and model.injection_roster:
             points = {s.value for s in model.injection_roster}

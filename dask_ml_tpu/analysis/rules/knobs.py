@@ -9,7 +9,7 @@ and benches cannot audit.  The rule collects every env read
 and ``_env_number`` helpers, and ``Knob(name, env, ...)`` registry
 declarations) whose name is a ``DASK_ML_TPU_``-prefixed string — literal or a
 resolvable constant like ``DEPTH_ENV`` — and checks it against the
-table (wildcard rows like ``DASK_ML_TPU_BENCH_*`` allow prefixes).
+table (wildcard rows like ``DASK_ML_TPU_TEST_*`` allow prefixes).
 
 When no ``docs/api.md`` is reachable above the linted tree (snippet
 linting, vendored subsets) the rule stays silent rather than flagging

@@ -170,10 +170,9 @@ def _lloyd_step_fn(x, mask, centers, *, mode="highest", scatter="segsum"):
 # The Lloyd hot programs route through the central program cache
 # (design.md §12): compile books + compile-ahead for the step, and —
 # now that the cache captures XLA cost_analysis per signature — the
-# roofline attribution that turned "Lloyd at 2% of bandwidth" from a
-# bench hand-estimate into device_report()'s measured per-program
-# fraction.  ``centers`` is donated in both: the (k, d) output centers
-# alias the dead input buffer in HBM.  ``x``/``mask`` are deliberately
+# per-program roofline attribution of device_report().  ``centers`` is
+# donated in both: the (k, d) output centers alias the dead input
+# buffer in HBM.  ``x``/``mask`` are deliberately
 # NOT donated — fit reuses them across segments (and _assign reads x
 # after the loop), so that donation would delete live buffers.
 from .. import programs as _programs  # noqa: E402
@@ -237,8 +236,8 @@ def _lloyd_loop_fn(x, mask, centers, tol, max_iter, *,
 # fused while program's body ONCE — the trip count is data-dependent —
 # so the loop's attributed flops/bytes (hence roofline_frac) are a
 # floor over the whole dispatch, not a per-round measurement.  The
-# per-round number lives in bench.py's lloyd section, which pins the
-# round count.
+# per-round number is the benchmark's ``lloyd.hbm_roof_pct``, which
+# multiplies by the rounds the fit reports (PERF.md section 3).
 _lloyd_loop = _programs.cached_program(
     _lloyd_loop_fn, name="kmeans.lloyd_loop",
     static_argnames=("mode", "scatter"), donate_argnames=("centers",),

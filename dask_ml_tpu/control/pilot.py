@@ -46,8 +46,7 @@ controller:
 
 And one HARD guard ahead of everything else: **saturation freeze**.
 When the process is CPU-pinned (Δprocess_time/Δwall ≥ 0.9 over the
-cycle — the same ``cpu_over_wall`` definition bench.py uses for its
-``saturation_pinned`` label), more host threads cannot help and every
+cycle), more host threads cannot help and every
 move would thrash the GIL, so the pilot freezes
 (``control.freeze{saturation_pinned}``) — the 1-core gate box can never
 be thrashed, and the seeded false-verdict liveness test asserts this
@@ -104,7 +103,7 @@ INJECT_ENV = "DASK_ML_TPU_PILOT_INJECT"
 PILOT_THREAD_NAME = "dask-ml-tpu-pilot"
 
 _DEFAULT_CADENCE_MS = 100.0
-#: bench.py's saturation_pinned definition: cpu_over_wall >= 0.9
+#: saturation_pinned: Δprocess_time/Δwall over a cycle >= 0.9
 _SATURATION_FRAC = 0.9
 #: minimum progress events in a settle window before the before/after
 #: rate comparison is trusted (see :meth:`Autopilot._settle_pending`)
@@ -277,8 +276,8 @@ class Autopilot:
         if self.running():
             return self
         # the verdict engine reads span records; arm tracing if the host
-        # has not (same posture as obs.perf.run_workload — the spine's
-        # overhead ratchet bounds the cost at <=3% of traced wall)
+        # has not (the cost is three records a streamed block:
+        # tests/test_obs.py; PERF.md section 6, PR 26, on the chip)
         if not _spans.enabled():
             _spans.enable()
         self._stop.clear()
@@ -583,8 +582,7 @@ class Autopilot:
     # -- reporting -------------------------------------------------------
     def converged(self, quiet_cycles: int | None = None) -> bool:
         """True once the pilot has gone ``quiet_cycles`` (default: one
-        cooldown) cycles without a move — the bench/perf convergence
-        criterion."""
+        cooldown) cycles without a move."""
         q = self.cooldown if quiet_cycles is None else int(quiet_cycles)
         return self._cycles_since_move >= q and self._pending is None
 

@@ -147,8 +147,8 @@ class ShardedDataset:
         a generous budget (a persistently-crashing shard must fail
         loudly, not loop).
       fetch_latency_s: per-block sleep INSIDE the reader before the
-        read — the bench's remote-store emulation hook (an object-store
-        GET has RTT this box's page cache does not); 0 everywhere else.
+        read — a remote-store emulation hook (an object-store GET has
+        RTT this box's page cache does not); 0 by default.
     """
 
     #: the elastic pipeline contract: a pull that raised did not lose

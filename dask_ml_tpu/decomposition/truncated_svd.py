@@ -86,7 +86,7 @@ class TruncatedSVD(ComponentsOutMixin, TransformerMixin, TPUEstimator):
 
     def fit_streamed(self, blocks, n_features=None):
         """Fit from a RE-ITERABLE stream of sparse/dense row blocks without
-        ever materializing the dense corpus (VERDICT r2 next #9).
+        ever materializing the dense corpus.
 
         ``blocks`` is a zero-argument callable returning a fresh iterator
         of row blocks (scipy.sparse or ndarray, each ``(b, n_features)``)

@@ -131,8 +131,7 @@ def serve_report() -> dict:
     client's number), queue-wait and batch-occupancy histograms,
     rejections by reason, and each live server's residency/budget
     state.  The same ``serve.*`` registry families export through the
-    live ``/metrics`` endpoint and ratchet through the committed
-    ``serve_latency`` perf workload."""
+    live ``/metrics`` endpoint."""
     from . import serve
 
     return serve.report()

@@ -51,7 +51,7 @@ def _check_docs(raw):
 def _chunks(seq, size):
     """Lazily batch an iterable of documents into lists of ``size``.
 
-    The corpus is NEVER materialized whole (VERDICT round-1 weak #6): a
+    The corpus is NEVER materialized whole: a
     generator of documents streams through with at most one chunk buffered
     here — the out-of-core path the reference gets from dask.bag.
     """

@@ -24,8 +24,8 @@ Zero host round-trips either way; the whole factorization (including the
 guarded fallback) is a single XLA program.  Strategy is resolved OUTSIDE
 jit and threaded through as a static argument (the scatter-knob staleness
 lesson — ADVICE r4): ``DASK_ML_TPU_TSQR`` = ``householder`` | ``cholqr2``
-| ``auto`` (default; platform winner, measured by ``bench.py``'s tsqr
-A/B).
+| ``auto`` (default: ``cholqr2``; no chip reading exists, PERF.md
+section 7 row 6).
 
 Padding note: zero rows contribute nothing to R (or to the Gram) and
 produce zero rows of Q, so the pad+mask ingest discipline composes
@@ -55,13 +55,12 @@ _CHOLQR2_DEV_MAX = 0.125
 def tsqr_strategy() -> str:
     """Local-factorization policy, overridable via ``DASK_ML_TPU_TSQR``.
 
-    ``auto`` is ``cholqr2`` on every platform — measured, not assumed
-    (``bench.py :: tsqr_strategy_ab``): two agreeing CPU runs at 3.96×
-    (IQR-disjoint) and the round-5 chip run (``bench_chip_evidence.jsonl``
-    ``tsqr_strategy_ab``) both decide
-    cholqr2; the guarded Householder fallback inside the same program
-    covers the ill-conditioned regime, so the fast default costs no
-    correctness.
+    ``auto`` is ``cholqr2`` on every platform: every heavy op is an MXU
+    gemm and its one collective a d×d psum, and the guarded Householder
+    fallback inside the same program covers the ill-conditioned regime,
+    so the default costs no correctness.  No chip reading of the two
+    strategies exists (PERF.md section 7 row 6 is the cell that would
+    give one).
     """
     from ..utils import env_choice
 

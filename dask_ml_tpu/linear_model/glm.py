@@ -409,8 +409,8 @@ class LogisticRegression(ClassifierMixin, _GLM):
             Xi = self._prepare(X, root, span, classes=K)
             if sample_weight is not None or self.class_weight is not None:
                 # weights scale the mask: every masked reduction in the
-                # solvers becomes the sklearn weighted loss (VERDICT r2
-                # missing #6 — the mask machinery IS the per-row weight)
+                # solvers becomes the sklearn weighted loss (the mask
+                # machinery IS the per-row weight)
                 from ..utils import host_class_weight_rows, reweight_rows
 
                 if self.class_weight is not None and yv is not None:
@@ -513,7 +513,6 @@ class LogisticRegression(ClassifierMixin, _GLM):
                 # vmapped XLA program (solvers.packed_solve) — the
                 # reference dispatches a task graph per class; a K-long
                 # Python loop of device solves was the round-2 shape
-                # (VERDICT r2 missing #4)
                 from ..solvers import packed_solve
 
                 self.betas_, n_iter_runs = packed_solve(  # betas_ (K, p)

@@ -21,8 +21,8 @@ staged block dispatches while another's program runs and a third's
 block H2D-stages on the host workers), and homogeneous survivors
 re-pack into vmapped cohorts after every halving round.  This closes
 the single-controller sequentialization bound round 5 accepted as a
-"known asterisk" (measured 1.53× wall); the ``search`` bench section
-carries the A/B.  ``DASK_ML_TPU_SEARCH_CONCURRENCY=off`` restores the
+"known asterisk" (no chip reading exists: PERF.md section 7 row 9).
+``DASK_ML_TPU_SEARCH_CONCURRENCY=off`` restores the
 serialized round loop exactly.
 """
 

@@ -841,9 +841,8 @@ class BaseIncrementalSearchCV(TPUEstimator):
                 await asyncio.gather(*futs)
 
         def _record_round(t0_round: float) -> None:
-            # per-round latency feeds the `search.round_s` histogram the
-            # committed `search_util` perf workload ratchets (p50/p99
-            # round latency under search load, design.md §17)
+            # per-round latency feeds the `search.round_s` histogram
+            # (p50/p99 round latency under search load, design.md §17)
             _obs.registry().histogram("search.round_s").record(
                 time.perf_counter() - t0_round)
 
@@ -973,7 +972,6 @@ class BaseIncrementalSearchCV(TPUEstimator):
             # host (sklearn) models score host arrays; device models keep
             # the held-out split SHARDED — unsharding here would pull it
             # to host once and re-upload it at every scoring round
-            # (VERDICT r2 missing #3, `_incremental.py:480`)
             X_test = (
                 unshard(X_test) if isinstance(X_test, ShardedRows) else X_test
             )

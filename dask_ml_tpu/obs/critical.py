@@ -79,9 +79,7 @@ a *different* lane (1.0 = the pipeline hides everything it stages;
 because its parse, stage, and compute share one lane).  Hiding is
 judged against the host-side dispatch-scope spans, not the device
 intervals, whose end-detection slack on a GIL-starved box would
-fabricate overlap where none exists.  The perf ratchet (:mod:`.perf`, v3) floors it per
-workload and pins the bottleneck class, so a pipeline that silently
-stops overlapping fails the gate even when p50 stays inside its band.
+fabricate overlap where none exists.
 
 Everything here is pure host stdlib (no jax, no numpy) — legal on any
 thread, same posture as the rest of :mod:`dask_ml_tpu.obs`.
@@ -118,8 +116,8 @@ CRITICAL_TOL_ENV = "DASK_ML_TPU_CRITICAL_TOL"
 
 #: policy knob: the share the winning category needs for a CONFIDENT
 #: verdict (default 0.35) — below it the verdict still names the
-#: largest category but ``confident`` is False and the perf ratchet's
-#: bottleneck pin does not bite (a 32/30/28 split is not a bottleneck).
+#: largest category but ``confident`` is False (a 32/30/28 split is not
+#: a bottleneck).
 CRITICAL_DOMINANCE_ENV = "DASK_ML_TPU_CRITICAL_DOMINANCE"
 
 _DEFAULT_TOL = 0.05
@@ -546,7 +544,7 @@ def serve_critical(*, tolerance: float | None = None,
     ``tag`` restricts the aggregation to one latency-histogram tag —
     normally a model name, or a replica tag (``r0``, ``r1``, ...) when
     the servers were built with ``metrics_tag`` (the fleet's
-    per-replica bottleneck verdicts in ``bench.py``'s fleet section);
+    per-replica bottleneck verdicts);
     ``None`` keeps the global all-tags sum."""
     tol = resolve_tolerance(tolerance)
     dom = resolve_dominance(dominance)

@@ -6,8 +6,8 @@ once per XLA backend compile (never on a cache hit) — the same signal
 graftsan's compile detector attributes per-region.  This listener is the
 UNGATED twin: it publishes ``compile.count`` / ``compile.duration_s``
 into the metrics registry on every compile, sanitizer or not, so
-``diagnostics.run_report()`` and the bench per-workload ``obs`` blocks
-can trend compilation alongside throughput in any process.
+``diagnostics.run_report()`` can trend compilation alongside
+throughput in any process.
 
 Kept out of ``obs/__init__`` imports deliberately: the rest of the obs
 package is pure stdlib (provably host-only for graftlint's

@@ -7,9 +7,8 @@ per tag, graftsan its compile/dispatch/d2h counters, checkpoints their
 save counts.  The pre-existing reporters (``pipeline_report()``,
 ``fault_stats()``, ``sanitize_report()``) keep their shapes as VIEWS
 over (or alongside) this registry, so nothing downstream breaks while
-new consumers — ``diagnostics.run_report()``, the bench per-workload
-``obs`` block, the future serving plane's latency SLOs — read one
-coherent store.
+new consumers — ``diagnostics.run_report()``, the serving plane's
+latency SLOs — read one coherent store.
 
 Instruments are cheap and thread-safe: a counter increment is one lock
 plus one integer add; a histogram record is one lock, one ``math.log``

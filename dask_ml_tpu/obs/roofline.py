@@ -3,9 +3,8 @@
 graftscope (:mod:`.scope`) measures per-program device *time*; this
 module supplies the other two axes the ROADMAP ``[speed]`` lane needs —
 **work** (FLOPs, bytes moved) and **capability** (the platform's peak
-FLOP/s and bytes/s) — so "Lloyd runs at 2% of roofline" becomes a
-measured, per-program, CI-ratchetable quantity instead of a hand
-estimate next to a bench table.
+FLOP/s and bytes/s) — so a program's roofline share is a per-program
+quantity instead of a hand estimate.
 
 Work comes from XLA itself: at compile time the program cache
 (:mod:`dask_ml_tpu.programs.cache`) calls :func:`capture_cost` on each
@@ -150,8 +149,8 @@ def try_peaks_for(device_kind: str | None) -> dict | None:
     """:func:`peaks_for` for the accounting hot paths (the scope
     sampler's sweep): a malformed :data:`PEAKS_ENV` returns None (one
     warning) instead of raising — the strict parse must surface on the
-    loud reporting surfaces (``device_report``, the bench, the perf
-    ratchet), never kill the daemon sampler or abort a fit from inside
+    loud reporting surface (``device_report``), never kill the daemon
+    sampler or abort a fit from inside
     dispatch-time accounting."""
     try:
         return peaks_for(device_kind)

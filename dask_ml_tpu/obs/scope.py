@@ -37,8 +37,7 @@ the critical-path engine (:mod:`.critical`).
 
 Honesty contract: ``t1`` carries a detection slack of at most one
 sampler period (~2 ms) — fine for the ms-scale block programs this
-repo streams, and the committed perf ratchet (:mod:`.perf`) is
-calibrated under the same cadence.  An interval covers enqueue→ready,
+repo streams.  An interval covers enqueue→ready,
 i.e. queue wait counts as *fed*, not idle — exactly the currency a
 scheduler that wants to keep the device fed should budget.  The XProf
 device trace is the authority for reported device time; this lane is
@@ -439,8 +438,7 @@ def device_report(since: int | None = None, *, settle_s: float = 0.0,
 
     The window is ``[first interval start, last interval end]`` of the
     retained (``since``-scoped) timeline — i.e. utilization of the
-    period the device was actually in use, the number the perf ratchet
-    floors.  ``settle_s > 0`` first waits (bounded) for in-flight
+    period the device was actually in use.  ``settle_s > 0`` first waits (bounded) for in-flight
     dispatches so a *post-fit* report closes its last interval; a live
     scrape must pass 0 (the default — never wait on the device in a
     handler thread).

@@ -12,10 +12,9 @@ per-class moments:
 - one-hot matmul — builds the (n, k) indicator and rides the MXU.  The
   k-means header's historical choice on TPU.
 
-Which wins on TPU is measured, not assumed: the bench's scatter section
-records ``hist_onehot_vs_segsum_speedup`` per platform and the k=64
-Lloyd variants exercise the gemm form.  The policy here is the single
-place both consumers consult:
+No chip reading of the two lowerings against each other exists (ROADMAP
+D6: ``SCATTER`` waits for a cell on either side).  The policy here is
+the single place both consumers consult:
 
 ``DASK_ML_TPU_SCATTER`` = ``segsum`` | ``onehot`` | ``auto`` (default).
 ``auto`` picks ``onehot`` on TPU and ``segsum`` elsewhere, EXCEPT when

@@ -10,9 +10,8 @@ Every streaming fit in this repo moves blocks through three stages:
    fused XLA program for the device-native estimators).
 
 The seed ran them strictly serially: the device idled through every
-parse and upload (``streamed_loader_fed`` against ``streamed_sgd`` in
-``bench_chip_evidence.jsonl``: ~137k rows/s feeding a ~10M rows/s
-device consumer).  This module is the
+parse and upload (no chip reading of a streamed fit exists yet: PERF.md
+section 7 row 8).  This module is the
 tf.data-style fix: a single **host-only worker thread** runs stages 1–2
 for block *k+1* while the consumer thread runs stage 3 for block *k*,
 through a bounded queue of ``depth`` staged blocks — double-buffering at

@@ -8,15 +8,14 @@ waiting on the prefetch queue (**stall**, the un-hidden remainder of
 parse+transfer).  The round-5 verdict's complaint was that the
 disk→device bottleneck was asserted, never measured; this split is the
 measurement, surfaced through :func:`dask_ml_tpu.diagnostics.
-pipeline_report` and the ``streamed_loader_overlap`` bench workload.
+pipeline_report`.
 
 Books are process-global: the LAST completed stream is kept whole for
 "what did that fit do", and the session-cumulative tally lives in the
 grafttrace metrics registry (``pipeline.*`` histograms + counters,
 docs/design.md §11) — :func:`pipeline_report` is a VIEW over that
-registry, so the same numbers feed ``diagnostics.run_report()``, the
-bench per-workload ``obs`` blocks, and this report without double
-bookkeeping.  Writers touch disjoint fields from at most two threads
+registry, so the same numbers feed ``diagnostics.run_report()`` and
+this report without double bookkeeping.  Writers touch disjoint fields from at most two threads
 (the prefetch worker owns parse/transfer, the consumer owns
 compute/stall), so per-field accumulation needs no lock; the
 per-stream registry publication at ``finish()`` does take the

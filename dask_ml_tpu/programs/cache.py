@@ -28,8 +28,7 @@ owns:
 
 Hit / miss / ahead-hit / fallback counters and compile seconds land in
 the obs metrics registry (``program.*``, tagged per program name) and
-in :func:`report` — surfaced as ``diagnostics.program_report()`` and
-ratcheted by the ``recompile_tax`` bench workload.
+in :func:`report` — surfaced as ``diagnostics.program_report()``.
 
 jax's persistent compilation cache (cold starts across processes: chip
 runs, multihost workers) is armed when the package is imported; its

@@ -618,11 +618,17 @@ def _drill_data_reader_crash_sgd(depth, m):
 def _drill_exporter_enospc_mbk(depth, m):
     """Disk-full on the grafttrace JSONL sink mid-fit: the sink is
     dropped with one warning (ring + flight recording continue) and the
-    fit — and its model — are untouched."""
+    fit — and its model — are untouched.
+
+    Recording is left armed or disarmed as the drill found it.  A JSONL
+    sink the CALLER had armed is the one thing lost: arming the drill's
+    own sink closes it (``obs.enable`` holds one sink), and the drill
+    re-arms the rings only."""
     import tempfile
 
     from .. import obs
 
+    was_enabled = obs.enabled()
     blocks = _row_blocks(offset=0)
     twin = _twin(f"mbk_d{depth}", lambda: _fit_mbk(list(blocks), depth))
     fd, path = tempfile.mkstemp(prefix="graftdrill-trace-",
@@ -645,7 +651,9 @@ def _drill_exporter_enospc_mbk(depth, m):
         m["recovered"] = m["faults_injected"] == 1
         m["model_match"], m["max_rel_diff"] = _match(model, twin)
     finally:
-        obs.disable()
+        obs.disable()  # closes the drill's sink
+        if was_enabled:
+            obs.enable()
         try:
             os.unlink(path)
         except OSError:
@@ -696,14 +704,21 @@ def _drill_fleet_kill_sgd(depth, m):
             futs = [fleet.submit("m", Xq) for _ in range(12)]
             results = [f.result(timeout=30.0) for f in futs]
         # the kill lands at the victim's NEXT loop cycle — anything it
-        # still held replays on the survivors via the futures above.
-        # Wait for the corpse (budget 0: death is terminal), then keep
-        # serving: the routing sweep must respawn the dead slot
+        # still held replays on the survivors via the futures above,
+        # and a replay that meets the corpse respawns the slot at once
+        # (``_note_trouble``); a victim that held nothing stays a corpse
+        # (budget 0: death is terminal) until the routing sweep of the
+        # predicts below.  Either is the death this drill plants
+        def _victim_down():
+            return (reg.counter("fleet.respawn").value > respawns0
+                    or any(rep.state() == "dead"
+                           for rep in fleet._replicas))
+
         for _ in range(500):
-            if any(rep.state() == "dead" for rep in fleet._replicas):
+            if _victim_down():
                 break
             _time.sleep(0.01)
-        died = any(rep.state() == "dead" for rep in fleet._replicas)
+        died = _victim_down()
         results.extend(fleet.predict("m", Xq, timeout=30.0)
                        for _ in range(3))
         respawned = reg.counter("fleet.respawn").value - respawns0

@@ -13,8 +13,7 @@ subsystem, built entirely on substrate earlier PRs shipped:
   sanitizer-verified;
 * **model residency** (:mod:`.residency`): many fitted models stay
   device-resident at once under an HBM budget with LRU parking, and
-  homogeneous models lane-pack into one vmapped program per window
-  (the K=4–64 packing measured 1.6–7.6× on chip);
+  homogeneous models lane-pack into one vmapped program per window;
 * **admission control**: a bounded request queue sheds load with an
   explicit ``queue_full`` rejection, per-request deadlines drop stale
   work before dispatch — backpressure is a fast error, never silent
@@ -23,8 +22,7 @@ subsystem, built entirely on substrate earlier PRs shipped:
   unit (``/healthz`` flips when it dies, restarts ride the fault
   budget with the in-flight batch replayed), and per-model p50/p99
   request latency, batch occupancy, and rejection counters export
-  through the live ``/metrics`` endpoint and the committed perf
-  ratchet (``serve_latency`` in tools/perf_baseline.json).
+  through the live ``/metrics`` endpoint.
 
 Quick start::
 

@@ -10,9 +10,8 @@ Four programs cover every resident linear model:
 * :data:`lane_margins` — the vmap of the same gemm over a stacked model
   axis: requests for DIFFERENT homogeneous models that land in the same
   micro-batch window dispatch as ONE program over the residency
-  registry's lane-packed state (the lane-packing the solvers'
-  ``packed_ovr_fixedwork`` rows of ``bench_chip_evidence.jsonl``
-  measured) instead of M separate launches.
+  registry's lane-packed state (the lane-packing of the solvers'
+  ``packed_solve``) instead of M separate launches.
 * :data:`proba` — the probability transform of a margins buffer, with
   the **margins donated**: the output has the margins' exact shape
   (sigmoid / clip per class column, normalized along the class axis),

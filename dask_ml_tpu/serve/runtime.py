@@ -21,7 +21,7 @@
 * per-model request latency (``serve.request_s``), queue wait, batch
   occupancy, and rejection counters land in the obs metrics registry —
   the live ``/metrics`` endpoint (obs/serve.py) exports them with no
-  extra wiring, and the committed perf ratchet pins the latency SLO.
+  extra wiring.
 
 Honesty contract (mirrors graftscope's): request latency INCLUDES queue
 wait and the adaptive gather window — the number a client experiences —
@@ -168,13 +168,13 @@ class ModelServer:
         self._crash_armed = False
         self._hb = None
         self._thread: threading.Thread | None = None
-        #: perf-harness hook: an injected per-dispatch sleep the
-        #: committed latency ratchet must fail on (obs/perf.py)
+        #: test hook: an injected per-dispatch sleep (the fleet's slow
+        #: replica, the serve drills, tests/test_serve.py)
         self._test_dispatch_delay_s = 0.0
         #: slowest request seen (monotone): the flight-recorder
         #: exemplar threshold — serve-loop-only state, no lock needed
         self._slowest_s = 0.0
-        #: perf-harness hook: an injected per-control sleep so tests can
+        #: test hook: an injected per-control sleep so tests can
         #: pin the /readyz warmup window deterministically
         self._test_control_delay_s = 0.0
         self._start_loop()

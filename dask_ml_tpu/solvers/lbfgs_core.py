@@ -339,8 +339,8 @@ def lbfgs_minimize(
     that has numerically converged must not burn max_iter failing the
     pgtol test.  ``tol = 0`` disables both CONVERGENCE tests (the
     line-search-failure exit still fires — a lane that cannot take any
-    step has no further work worth timing), which is how the bench gets
-    its fixed-iteration-count runs.
+    step has no further work worth timing), which gives a caller a
+    fixed iteration count.
     ``line_search``: ``backtrack`` (default; REQUIRED under ``vmap``) or
     ``probe_grid`` (batched grid); what each costs is in PERF.md
     section 5.

@@ -66,7 +66,7 @@ class ParallelPostFit(TPUEstimator):
         ]
         return np.concatenate(outs)
 
-    # -- streaming inference (VERDICT r2 weak #10) ---------------------
+    # -- streaming inference --------------------------------------------
     def predict_blocks(self, X, method="predict", chunk_size=100_000):
         """Yield per-chunk inference results instead of concatenating
         them in host memory — the "inference over huge X" form of

@@ -2,8 +2,8 @@
 
 The model lives ON DEVICE; blocks stream through it and are dropped —
 only one block is ever resident, so the total stream can exceed device
-memory (the driver-verified >HBM path in bench.py uses this exact loop
-at 70 x 1M-row blocks = 17.9 GB on a 16 GB chip).
+memory (no chip reading of a streamed fit exists yet: PERF.md section 7
+row 8).
 """
 import pathlib
 import sys

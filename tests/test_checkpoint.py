@@ -374,7 +374,7 @@ class TestDeviceEstimatorRoundtrips:
 
 
 class TestCrashMatrix:
-    """VERDICT r5 target: resume from a crash at EVERY point of a
+    """Resume from a crash at EVERY point of a
     Hyperband run, including mid-bracket and double-crash — each resume
     must reach the uninterrupted run's exact result.  A single crash
     point (the old test) can miss state that only goes stale deeper

@@ -323,7 +323,7 @@ class TestReviewRegressions:
 
 
 class TestStreamedTruncatedSVD:
-    """VERDICT r2 next #9: sparse stream -> SVD without densifying the
+    """Sparse stream -> SVD without densifying the
     corpus; peak dense memory is O(n_features * sketch)."""
 
     def _sparse_blocks(self, rng, n=1200, d=300, block=100, density=0.05):

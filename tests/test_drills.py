@@ -69,6 +69,25 @@ class TestDrillGate:
         assert drills.is_clean(delta), delta
 
 
+class TestTracingLeftAsFound:
+    @pytest.mark.parametrize("armed", [True, False])
+    def test_exporter_drill_leaves_recording_as_it_found_it(self, armed):
+        """The drill arms its own JSONL sink and closes it; a caller
+        that was recording still is afterwards (it used to end
+        disarmed, and every later span tree of the process with it),
+        and one that was not is not switched on."""
+        from dask_ml_tpu import obs
+
+        was = obs.enabled()
+        (obs.enable if armed else obs.disable)()
+        try:
+            m = drills.run_drill("exporter_enospc_mbk_d0")
+            assert not m.get("error") and m["recovered"], m
+            assert obs.enabled() is armed
+        finally:
+            (obs.enable if was else obs.disable)()
+
+
 # ---------------------------------------------------------------------------
 # ratchet semantics (pure-python, no fits)
 # ---------------------------------------------------------------------------

@@ -459,14 +459,12 @@ class TestKnobNames:
 # ---------------------------------------------------------------------------
 
 class TestCommittedBaselinePins:
-    def _tree(self, tmp_path, perf=None, drill=None, lock=None):
+    def _tree(self, tmp_path, drill=None, lock=None):
         (tmp_path / "docs").mkdir()
         (tmp_path / "docs" / "api.md").write_text(
             "| `pipeline.blocks` | counter | — | blocks |\n")
         tools = tmp_path / "tools"
         tools.mkdir()
-        if perf is not None:
-            (tools / "perf_baseline.json").write_text(json.dumps(perf))
         if drill is not None:
             (tools / "drill_baseline.json").write_text(json.dumps(drill))
         if lock is not None:
@@ -477,38 +475,6 @@ class TestCommittedBaselinePins:
 
     def _lint(self, pkg):
         return lint_paths([str(pkg)], select=CONTRACT_RULES)[0]
-
-    def test_valid_perf_pin_is_clean(self, tmp_path):
-        pkg = self._tree(tmp_path, perf={"workloads": {"w": {
-            "bottleneck": {"class": "device-bound", "share": 0.8}}}})
-        (pkg / "mod.py").write_text(
-            'BOTTLENECK_CLASSES = ("unknown", "device-bound")\n')
-        assert not active(self._lint(pkg))
-
-    def test_perf_class_drift_flagged(self, tmp_path):
-        pkg = self._tree(tmp_path, perf={"workloads": {"w": {
-            "bottleneck": {"class": "zebra-bound", "share": 0.8}}}})
-        (pkg / "mod.py").write_text(
-            'BOTTLENECK_CLASSES = ("unknown", "device-bound")\n')
-        fs = active(self._lint(pkg))
-        assert rule_ids(fs) == ["contract-baseline-drift"]
-        assert "zebra-bound" in fs[0].message
-
-    def test_perf_trajectory_knob_drift_flagged(self, tmp_path):
-        pkg = self._tree(tmp_path, perf={"workloads": {"controller": {
-            "bottleneck": {"class": "device-bound", "share": 0.8},
-            "knob_trajectory": [
-                {"knob": "ghost_knob", "class": "device-bound"}]}}})
-        (pkg / "mod.py").write_text(
-            'BOTTLENECK_CLASSES = ("unknown", "device-bound")\n'
-            'class Knob:\n'
-            '    def __init__(self, name, env, kind):\n'
-            '        self.name = name\n'
-            'KNOBS = {k.name: k for k in ('
-            'Knob("real_knob", "DASK_ML_TPU_REAL", int),)}\n')
-        fs = active(self._lint(pkg))
-        assert rule_ids(fs) == ["contract-baseline-drift"]
-        assert "ghost_knob" in fs[0].message
 
     def test_drill_point_drift_flagged(self, tmp_path):
         pkg = self._tree(tmp_path,
@@ -730,8 +696,8 @@ class TestCacheDigest:
         (tmp_path / "docs").mkdir()
         (tmp_path / "docs" / "api.md").write_text("knobs\n")
         (tmp_path / "tools").mkdir()
-        (tmp_path / "tools" / "perf_baseline.json").write_text(
-            '{"workloads": {}}')
+        (tmp_path / "tools" / "drill_baseline.json").write_text(
+            '{"drills": {}}')
         pkg = tmp_path / "pkg"
         pkg.mkdir()
         mod = pkg / "mod.py"
@@ -751,8 +717,8 @@ class TestCacheDigest:
     def test_committed_ratchet_keys_the_digest(self, tmp_path):
         src = self._sources(tmp_path)
         d0 = lint_cache.project_digest(src)
-        (tmp_path / "tools" / "perf_baseline.json").write_text(
-            '{"workloads": {"w": {}}}')
+        (tmp_path / "tools" / "drill_baseline.json").write_text(
+            '{"drills": {"d": {}}}')
         assert lint_cache.project_digest(src) != d0
 
     def test_analyzer_sources_key_the_digest(self, tmp_path, monkeypatch):

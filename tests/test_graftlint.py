@@ -1281,7 +1281,7 @@ class TestUndocumentedKnob:
         (tmp_path / "docs").mkdir()
         (tmp_path / "docs" / "api.md").write_text(
             f"| `{documented}` | int | a knob | — |\n"
-            f"`DASK_ML_TPU_BENCH_*` harness knobs\n"
+            f"`DASK_ML_TPU_TEST_*` harness knobs\n"
         )
         pkg = tmp_path / "pkg"
         pkg.mkdir()
@@ -1322,7 +1322,7 @@ class TestUndocumentedKnob:
 
     def test_wildcard_prefix_allows(self, tmp_path):
         pkg = self._tree(tmp_path, "DASK_ML_TPU_DEPTH",
-                         "DASK_ML_TPU_BENCH_SEED")
+                         "DASK_ML_TPU_TEST_SEED")
         findings, _ = lint_paths([pkg])
         assert not active(findings)
 

@@ -619,7 +619,7 @@ class TestDataFrameSplit:
 
 
 class TestDeviceResidentSearch:
-    """VERDICT r2 next #4: sharded data stays on device through the CV
+    """Sharded data stays on device through the CV
     searches — fold slicing by device gather, scoring by scalar fetch."""
 
     def _tpu_est(self, **kw):

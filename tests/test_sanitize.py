@@ -266,6 +266,13 @@ class TestTransferDetector:
 # the committed per-workload contract (the tier-1 gate)
 # ---------------------------------------------------------------------------
 
+def _gated_workloads():
+    """Every workload the suite runs or the committed snapshot names
+    (one in either alone is a new or a stale entry, and fails)."""
+    committed = san_baseline.load(SAN_BASELINE)["workloads"]
+    return sorted(set(WORKLOADS) | set(committed))
+
+
 class TestWorkloadGate:
     @pytest.fixture(scope="class")
     def smoke_results(self):
@@ -295,11 +302,20 @@ class TestWorkloadGate:
             assert PREFETCH_THREAD_NAME not in \
                 smoke_results[wl]["dispatch_threads"], wl
 
-    def test_committed_baseline_matches(self, smoke_results):
-        """The ratchet gate: the run must be clean against the COMMITTED
-        snapshot — new compiles/transfers fail, stale entries fail."""
+    @pytest.mark.parametrize("workload", _gated_workloads())
+    def test_committed_baseline_matches(self, smoke_results, workload):
+        """The ratchet gate, a case a workload so that a drifted count
+        is named by the workload it drifted in: the run must be clean
+        against the COMMITTED snapshot — new compiles/transfers fail,
+        a workload the snapshot lacks fails, a stale entry fails."""
         snap = san_baseline.load(SAN_BASELINE)
-        delta = san_baseline.compare(snap, smoke_results)
+
+        def only(table):
+            return {k: v for k, v in table.items() if k == workload}
+
+        delta = san_baseline.compare(
+            {**snap, "workloads": only(snap["workloads"])},
+            only(smoke_results))
         assert san_baseline.is_clean(delta), delta
 
     def test_whole_array_fits_compile_free_on_refit(self, smoke_results):

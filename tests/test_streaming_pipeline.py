@@ -217,8 +217,7 @@ class TestStreamedClustering:
 
 class TestStreamedBlocksFit:
     """SURVEY §7 hard-part (b): a stream larger than device memory fits
-    through partial_fit with only one live block (bench.py's streamed_sgd
-    workload runs this same path at >HBM scale on chip)."""
+    through partial_fit with only one live block."""
 
     def test_stream_fit_accuracy_and_laziness(self, mesh):
         from dask_ml_tpu.datasets import stream_classification_blocks

@@ -122,7 +122,7 @@ class TestBoundedRSSStreaming:
         reason="set DASK_ML_TPU_TEST_BIG=1 for the >=10 GB tier",
     )
     def test_12gb_stream_bounded_rss(self, tmp_path):
-        """The VERDICT r4 item-#6 scale: >=10 GB on disk, RSS bounded.
+        """The out-of-core scale: >=10 GB on disk, RSS bounded.
         Run manually (DASK_ML_TPU_TEST_BIG=1) — result recorded in
         docs/design.md §6."""
         p = tmp_path / "huge.csv"

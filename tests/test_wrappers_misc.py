@@ -363,13 +363,17 @@ class TestBlockwiseParallelFits:
     def test_sklearn_threadpool_speedup(self, rng):
         import time as _t
 
+        from conftest import PeakInside
         from sklearn.base import BaseEstimator
 
         from dask_ml_tpu.ensemble import BlockwiseVotingRegressor
 
+        asleep = PeakInside()
+
         class Sleepy(BaseEstimator):
             def fit(self, X, y=None):
-                _t.sleep(0.08)
+                with asleep:
+                    _t.sleep(0.08)
                 self.fitted_ = True
                 return self
 
@@ -378,10 +382,8 @@ class TestBlockwiseParallelFits:
 
         X = rng.normal(size=(80, 3))
         y = np.zeros(80)
-        t0 = _t.perf_counter()
         BlockwiseVotingRegressor(Sleepy(), n_blocks=8).fit(X, y)
-        wall = _t.perf_counter() - t0
-        assert wall < 8 * 0.08 / 1.5, wall  # overlapped, not serial
+        assert asleep.peak >= 2  # blocks fit at the same time, not serially
 
     def test_parity_with_serial_semantics(self, rng):
         # thread-pool fits must produce the same members as the old serial

@@ -23,24 +23,6 @@
 # model-equality-vs-unfaulted-twin, and retry-ceiling invariants).
 # Tier-1 runs the same gate via tests/test_drills.py.
 #
-# --perf runs the graftscope perf suite (obs/perf.py): streamed-fit
-# workloads whose p50/p99 block latency, device utilization, and stall
-# fraction ratchet against tools/perf_baseline.json with tolerance
-# BANDS (not exact times — the gate box is loaded; the ratchet catches
-# the order-of-magnitude class: a sleep in a step program, a pipeline
-# that stopped overlapping, an idling device).  Since v2 every
-# workload also prints + ratchets its PER-PROGRAM ROOFLINE columns
-# (busy_s / flops / bytes / roofline_frac vs the obs/roofline.py peak
-# table, design.md §16) with a x0.25 per-program floor and a
-# program-set drift gate.  Since v3 every workload also prints +
-# ratchets its GRAFTPATH columns (design.md §19): overlap efficiency
-# (hidden host time / host time, floored at x0.5 of the committed
-# value) and the bottleneck verdict (device/parse/stage/dispatcher/
-# queue-bound with its share; a CONFIDENT class flip — both shares
-# >= 0.5 — fails the gate even when every wall band holds, which is
-# exactly what --inject-slowdown demonstrates).  Tier-1 runs the same
-# gate via tests/test_graftscope.py.
-#
 # --locks runs graftlock's RUNTIME half (sanitize/locks.py): the whole
 # graftsan smoke suite plus triple_plane (serve + search + ingest in one
 # process) under instrumented package locks, ratcheting the observed
@@ -72,14 +54,11 @@
 #   tools/lint.sh --json          # same, JSON output (CI trending)
 #   tools/lint.sh --sanitize      # static gate + runtime sanitizer gate
 #   tools/lint.sh --drills        # static gate + chaos drill gate
-#   tools/lint.sh --perf          # static gate + perf ratchet gate
 #   tools/lint.sh --locks         # static gate + runtime lockset gate
 #   tools/lint.sh --contracts     # static gate + contract drift gate
-#   tools/lint.sh --rebaseline    # refresh ALL SIX committed baselines
-#                                 # (lint, sanitize, drills, perf —
-#                                 # including the graftpilot
-#                                 # `controller` convergence entry —
-#                                 # locks, contracts) after intentional
+#   tools/lint.sh --rebaseline    # refresh ALL FIVE committed baselines
+#                                 # (lint, sanitize, drills, locks,
+#                                 # contracts) after intentional
 #                                 # changes — each write self-gates its
 #                                 # hard invariants; a half-updated set
 #                                 # cannot be committed green
@@ -90,7 +69,6 @@ cd "$(dirname "$0")/.."
 BASELINE=tools/graftlint_baseline.json
 SAN_BASELINE=tools/sanitize_baseline.json
 DRILL_BASELINE=tools/drill_baseline.json
-PERF_BASELINE=tools/perf_baseline.json
 LOCK_BASELINE=tools/lock_baseline.json
 CONTRACT_BASELINE=tools/contract_baseline.json
 CONTRACT_RULES=contract-orphan-producer,contract-dead-consumer
@@ -99,7 +77,6 @@ CONTRACT_RULES+=,contract-undocumented-metric
 MODE=gate
 SANITIZE=0
 DRILLS=0
-PERF=0
 LOCKS=0
 CONTRACTS=0
 EXTRA=()
@@ -109,7 +86,6 @@ for a in "$@"; do
     --rebaseline) MODE=rebaseline ;;
     --sanitize) SANITIZE=1 ;;
     --drills) DRILLS=1 ;;
-    --perf) PERF=1 ;;
     --locks) LOCKS=1 ;;
     --contracts) CONTRACTS=1 ;;
     *) EXTRA+=("$a") ;;
@@ -133,9 +109,6 @@ if [[ "$MODE" == rebaseline ]]; then
   echo "== graftdrill (rebaseline: full chaos drill suite) =="
   JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
     python -m dask_ml_tpu.resilience.drills --write-baseline "$DRILL_BASELINE"
-  echo "== graftscope perf (rebaseline: cold-run latency/utilization) =="
-  JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m dask_ml_tpu.obs.perf --write-baseline "$PERF_BASELINE"
   echo "== graftlock (rebaseline: lock smoke suite, cold edge union) =="
   JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
     python -m dask_ml_tpu.sanitize.locks --write-baseline "$LOCK_BASELINE"
@@ -234,7 +207,7 @@ if [[ "$SANITIZE" == 1 ]]; then
     python -m dask_ml_tpu.sanitize --baseline "$SAN_BASELINE"
   echo "== grafttrace (obs smoke: tests/test_obs.py) =="
   # the observability spine's own suite rides the runtime smoke path:
-  # span stitching, exporters, the overhead ratchet (<=3% traced wall)
+  # span stitching, exporters, the overhead gate (records a block)
   JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
     python -m pytest tests/test_obs.py -q -p no:cacheprovider
 fi
@@ -243,12 +216,6 @@ if [[ "$DRILLS" == 1 ]]; then
   echo "== graftdrill (chaos drill suite vs $DRILL_BASELINE) =="
   JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
     python -m dask_ml_tpu.resilience.drills --baseline "$DRILL_BASELINE"
-fi
-
-if [[ "$PERF" == 1 ]]; then
-  echo "== graftscope perf (latency/utilization ratchet vs $PERF_BASELINE) =="
-  JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m dask_ml_tpu.obs.perf --baseline "$PERF_BASELINE"
 fi
 
 if [[ "$LOCKS" == 1 ]]; then
