@@ -306,6 +306,45 @@ def _new_d2(x, x_norm, rows, valid):
                      jnp.asarray(jnp.inf, x.dtype))
 
 
+def _fold_widths(ell: float, cap: int) -> tuple:
+    """The static widths a round's fold is compiled at: ``ell``, one and
+    a half and twice ``ell`` rounded up to whole sublanes of 8, and
+    ``cap``.  A round draws ``ell`` rows in expectation (variance at most
+    ``ell``), so the first two serve nearly every round and the last next
+    to none; equal widths merge, and a ``cap`` of 8 leaves one.  (On a
+    v5e at 25M x 50 the fold reads X in 7.6 ms and costs 9.0 / 9.6 / 11.4
+    / 19.8 ms at 16 / 24 / 32 / 64 columns: PERF.md section 5.)"""
+    return tuple(sorted({min(-(-int(np.ceil(m * ell)) // 8) * 8, cap)
+                         for m in (1, 1.5, 2)} | {cap}))
+
+
+def _fold_candidates(x, x_norm, rows, valid, d2, nearest, base, widths):
+    """Fold one round's candidate ``rows`` (the ``valid`` ones packed
+    first, as ``_gather_candidates`` leaves them) into every row's least
+    squared distance ``d2`` and ``nearest`` slot (``base`` + the column):
+    ``(d2, nearest, width)``.  The distances are computed at the narrowest
+    of the static ``widths`` that holds the valid count, chosen in the
+    program.  Slots past the count read +inf at any width, so the width
+    changes no min and no argmin; no branch holds a collective, and the
+    count is the same on every shard."""
+    def fold_at(width):
+        def fold(d2, nearest):
+            new = _new_d2(x, x_norm, rows[:width], valid[:width])
+            least = jnp.min(new, axis=1)
+            closer = least < d2
+            return (jnp.where(closer, least, d2), jnp.where(
+                closer, base + jnp.argmin(new, axis=1).astype(jnp.int32),
+                nearest))
+        return fold
+
+    table = jnp.asarray(widths, jnp.int32)
+    # how many widths the count passes: the index of the first that holds it
+    branch = jnp.searchsorted(table[:-1], jnp.sum(valid, dtype=jnp.int32))
+    d2, nearest = jax.lax.switch(
+        branch, [fold_at(w) for w in widths], d2, nearest)
+    return d2, nearest, table[branch]
+
+
 def _first_selected(sel, k: int):
     """Positions of the first ``k`` true entries of ``sel`` (n,), and how
     many of the ``k`` there are -- a compaction without a sort.
@@ -401,15 +440,19 @@ def _init_rounds_fn(x, mask, x_norm, first, min_d2, key, n_rounds, *, ell,
 
     A round draws each row with ``p = min(ell * w * d2 / phi, 1)``, keeps
     at most ``cap`` of the drawn rows in the round's own slots, computes
-    distances to those slots alone and folds them into the carried least
-    distance and nearest slot.  Returns ``(candidates, valid, weights,
-    rounds)``: the whole buffer, which slots hold a row, for each slot
-    the summed weight of the rows nearest to it, and the rounds run."""
+    distances to the slots it filled (``_fold_candidates``: as many
+    columns as the draw needs, not ``cap``) and folds them into the
+    carried least distance and nearest slot.  Returns ``(candidates,
+    valid, weights, counts)``: the whole buffer, which slots hold a row,
+    for each slot the summed weight of the rows nearest to it, and in one
+    ``int32[2]`` (one transfer) the rounds run and the distance columns
+    they computed in all."""
     from ..ops.scatter import bucket_sum
 
     mesh = mesh_holder.mesh
     row_ax = data_axes(mesh)
     slots = 1 + max_rounds * cap
+    widths = _fold_widths(ell, cap)
 
     def local(x_l, m_l, x_norm, d2_l, first, key, n_rounds):
         key = fold_in_shard(key, row_ax)
@@ -422,7 +465,7 @@ def _init_rounds_fn(x, mask, x_norm, first, min_d2, key, n_rounds, *, ell,
             return (r < n_rounds) & (phi > 0)
 
         def body(state):
-            r, phi, d2, nearest, cand, cvalid = state
+            r, phi, d2, nearest, cand, cvalid, folded = state
             with jax.named_scope("kmeansll.sample"):
                 # stream 0 of this shard's key drew the first candidate
                 bits = jax.random.bits(jax.random.fold_in(key, r + 1),
@@ -431,29 +474,24 @@ def _init_rounds_fn(x, mask, x_norm, first, min_d2, key, n_rounds, *, ell,
                 rows, valid = _gather_candidates(x_l, sel, cap, row_ax)
             base = 1 + r * cap
             with jax.named_scope("kmeansll.distances"):
-                new = _new_d2(x_l, x_norm, rows, valid)
-                least = jnp.min(new, axis=1)
-                closer = least < d2
-                nearest = jnp.where(
-                    closer, base + jnp.argmin(new, axis=1).astype(jnp.int32),
-                    nearest)
-                d2 = jnp.where(closer, least, d2)
+                d2, nearest, width = _fold_candidates(
+                    x_l, x_norm, rows, valid, d2, nearest, base, widths)
                 phi = total(d2)
             cand = jax.lax.dynamic_update_slice(cand, rows, (base, 0))
             cvalid = jax.lax.dynamic_update_slice(cvalid, valid, (base,))
-            return r + 1, phi, d2, nearest, cand, cvalid
+            return r + 1, phi, d2, nearest, cand, cvalid, folded + width
 
         cand = jnp.zeros((slots, x_l.shape[1]), x_l.dtype).at[0].set(first)
         cvalid = jnp.zeros((slots,), bool).at[0].set(True)
         state = (jnp.int32(0), total(d2_l), d2_l,
-                 jnp.zeros(m_l.shape, jnp.int32), cand, cvalid)
-        rounds, _, _, nearest, cand, cvalid = jax.lax.while_loop(
+                 jnp.zeros(m_l.shape, jnp.int32), cand, cvalid, jnp.int32(0))
+        rounds, _, _, nearest, cand, cvalid, folded = jax.lax.while_loop(
             cond, body, state)
         with jax.named_scope("kmeansll.weigh"):
             weights = jax.lax.psum(
                 bucket_sum(m_l, nearest, slots, strategy=scatter,
                            precision=jax.lax.Precision.HIGHEST), row_ax)
-        return cand, cvalid, weights, rounds
+        return cand, cvalid, weights, jnp.stack([rounds, folded])
 
     return _shard_map(
         local, mesh,
@@ -484,16 +522,17 @@ def init_scalable(X: ShardedRows, n_clusters: int, key, oversampling_factor=2,
     draws the first candidate and returns phi, from which the host takes
     ``n_rounds = ceil(ln phi)``; ``kmeans.init_scalable`` runs every
     round (the Bernoulli draw, a sort-free compaction of at most ``cap``
-    drawn rows a round, distances to those rows alone, the fold into the
-    carried least distance and nearest slot) and weighs the candidates by
-    a histogram of the nearest slots.  Its largest intermediate is rows x
-    ``cap``; nothing of the table's size leaves a chip or reaches the
-    host.  Host side: one pull of the candidate buffer and its weights,
-    then the weighted k-means++ and 10 Lloyd steps on the O(k log n)
-    valid candidates, exactly the reference's division of labour.  The
-    per-round capacity is 4 * ell: a round draws at most ell rows in
-    expectation, so an overflow (rows drawn and dropped) is vanishingly
-    rare and harmless to the sampling guarantee.
+    drawn rows a round, distances to those rows alone -- the product is
+    as wide as the round's draw, one of ``_fold_widths``, not ``cap`` --
+    the fold into the carried least distance and nearest slot) and weighs
+    the candidates by a histogram of the nearest slots.  Its largest
+    intermediate is rows x ``cap``; nothing of the table's size leaves a
+    chip or reaches the host.  Host side: one pull of the candidate
+    buffer and its weights, then the weighted k-means++ and 10 Lloyd
+    steps on the O(k log n) valid candidates, exactly the reference's
+    division of labour.  The per-round capacity is 4 * ell: a round draws
+    at most ell rows in expectation, so an overflow (rows drawn and
+    dropped) is vanishingly rare and harmless to the sampling guarantee.
     """
     return _init_scalable(X, n_clusters, key, oversampling_factor,
                           init_max_iter)[0]
@@ -503,9 +542,10 @@ def _sample_candidates(X, n_clusters, key, oversampling_factor,
                        init_max_iter, behind=None):
     """The device part of k-means|| and its one pull: the candidate
     buffer ``(slots, d)``, which slots hold a row, every slot's weight,
-    the rounds run and ``cap``, all on the host.  ``behind()`` is called
-    once the rounds are dispatched and before the pull: what it queues
-    runs on the device while the host works on the candidates."""
+    the rounds run, the distance columns they computed and ``cap``, all
+    on the host.  ``behind()`` is called once the rounds are dispatched
+    and before the pull: what it queues runs on the device while the host
+    works on the candidates."""
     from ..ops.scatter import scatter_strategy
 
     x, mask = X.data, X.mask
@@ -536,20 +576,24 @@ def _sample_candidates(X, n_clusters, key, oversampling_factor,
             scatter=scatter_strategy(1 + max_rounds * cap, histogram=True))
         if behind is not None:
             behind()
-        return (*jax.device_get(out), cap)
+        cand, keep, weights, (rounds, slots) = jax.device_get(out)
+        return cand, keep, weights, rounds, slots, cap
 
 
 def _init_scalable(X, n_clusters, key, oversampling_factor, init_max_iter,
                    behind=None):
     """``init_scalable`` and its counts (``rounds`` run, valid
-    ``candidates``, ``cap``), which ``KMeans.fit`` puts on its span;
-    ``behind``: see ``_sample_candidates``."""
-    cand, keep, weights, rounds, cap = _sample_candidates(
+    ``candidates``, ``cap``, and ``slots``: the distance columns the
+    rounds computed, ``rounds * cap`` if every fold were ``cap`` wide),
+    which ``KMeans.fit`` puts on its span; ``behind``: see
+    ``_sample_candidates``."""
+    cand, keep, weights, rounds, slots, cap = _sample_candidates(
         X, n_clusters, key, oversampling_factor, init_max_iter, behind)
     x, n = X.data, X.n_samples
     cand = np.asarray(cand, dtype=np.float64)[keep]
     weights = np.asarray(weights, dtype=np.float64)[keep]
-    counts = {"rounds": int(rounds), "candidates": len(cand), "cap": cap}
+    counts = {"rounds": int(rounds), "candidates": len(cand), "cap": cap,
+              "slots": int(slots)}
     logger.debug("k-means||: %s", counts)
 
     if cand.shape[0] <= n_clusters:
@@ -652,6 +696,7 @@ class KMeans(TransformerMixin, TPUEstimator):
             reg = _obs.registry()
             reg.counter("kmeans.init_rounds").inc(counts["rounds"])
             reg.counter("kmeans.candidates").inc(counts["candidates"])
+            reg.counter("kmeans.init_slots").inc(counts["slots"])
             return centers
         if init == "random":
             p = X.mask / jnp.sum(X.mask)

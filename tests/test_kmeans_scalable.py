@@ -5,6 +5,7 @@ force and to the benchmark's plain reference
 (``benchmarks/references/kmeans_lloyd.py``, which imports nothing of
 ``dask_ml_tpu``) on the 8-device mesh of ``conftest.py``."""
 
+import functools
 import os
 import re
 import sys
@@ -53,7 +54,7 @@ def _brute_weights(X, w, cand):
 
 
 def _sampled(Xs, k=5, key=0, **kw):
-    cand, keep, weights, rounds, cap = km._sample_candidates(
+    cand, keep, weights, rounds, _, cap = km._sample_candidates(
         Xs, k, jax.random.PRNGKey(key), kw.pop("oversampling_factor", 2),
         kw.pop("init_max_iter", None))
     return np.asarray(cand, np.float64), np.asarray(keep), np.asarray(
@@ -256,6 +257,172 @@ def test_fewer_candidates_than_clusters_are_padded_with_real_rows():
     assert est.cluster_centers_.shape == (12, 6)
     assert np.isfinite(np.asarray(est.cluster_centers_)).all()
     assert est.labels_.shape == (24,)
+
+
+# -- the fold at the width of the round's draw (ISSUE 31) -------------------
+#
+# The tables below hold small whole numbers: every product and partial sum
+# of a squared distance is then exact in float32, in whatever order a
+# backend's matrix product adds it up.  (The CPU's dot rounds a column
+# differently at another width of the right-hand side, so on a table of
+# real numbers two widths agree to an ulp and not to the bit here; on the
+# chip a slot's distance contracts K whole at any width, PERF.md PR 31.)
+
+_ELL, _CAP = 16.0, 64  # the benchmark's cell: the widths 16 / 24 / 32 / 64
+
+
+def _whole_numbers(rows, features, seed):
+    return np.random.default_rng(seed).integers(
+        -8, 9, size=(rows, features)).astype(np.float32)
+
+
+@pytest.mark.parametrize("ell, cap, widths", [
+    (16.0, 64, (16, 24, 32, 64)),  # the benchmark's cell: k = 8
+    (200.0, 800, (200, 304, 400, 800)),  # k = 100
+    (10.0, 40, (16, 24, 40)),
+    (4.0, 16, (8, 16)),           # ceil8 of ell, 1.5 ell, 2 ell: merged
+    (2.0, 8, (8,)),               # a cap of 8 keeps one branch
+    (16.0, 20, (16, 20)),         # cap cut to a small shard's rows
+    (16.0, 5, (5,)),
+    (2.5, 10, (8, 10)),           # a fractional oversampling factor
+])
+def test_fold_widths_follow_ell_and_never_pass_cap(ell, cap, widths):
+    assert km._fold_widths(ell, cap) == widths
+
+
+@functools.cache
+def _fold_on(chips, widths):
+    """``_fold_candidates`` as one jitted per-shard program over ``chips``
+    devices (compiled once for all the counts); also hands back the width
+    every shard chose."""
+    mesh = device_mesh(chips)
+    row_ax = km.data_axes(mesh)
+    spec = km.P
+
+    def local(x, x_norm, rows, valid, d2, nearest, base):
+        d2, nearest, width = km._fold_candidates(
+            x, x_norm, rows, valid, d2, nearest, base, widths)
+        return d2, nearest, width[None]
+
+    return jax.jit(km._shard_map(
+        local, mesh,
+        in_specs=(spec(row_ax, None), spec(row_ax), spec(), spec(),
+                  spec(row_ax), spec(row_ax), spec()),
+        out_specs=(spec(row_ax), spec(row_ax), spec(row_ax))))
+
+
+def _fold_case(count):
+    """A table, ``_CAP`` slots of which the first ``count`` hold one of
+    its rows, and a carried state some rows of which no slot improves."""
+    X = _whole_numbers(2048, 6, seed=20)
+    rows = np.zeros((_CAP, 6), np.float32)
+    rows[:count] = X[np.random.default_rng(21).choice(
+        len(X), size=count, replace=False)]
+    valid = np.arange(_CAP) < count
+    d2 = ((X - X[7]) ** 2).sum(axis=1)
+    d2[::3] = 1.0  # nearer than any slot but the row's own
+    nearest = np.arange(len(X), dtype=np.int32) % 5
+    return X, rows, valid, d2.astype(np.float32), nearest
+
+
+#: 0, 1, and each width with the count that first passes it, up to cap
+_COUNTS = [0, 1, 16, 17, 24, 25, 32, 33, 64]
+
+
+@pytest.mark.parametrize("count", _COUNTS)
+def test_narrowed_fold_equals_the_cap_wide_fold_bit_for_bit(count):
+    widths = km._fold_widths(_ELL, _CAP)
+    X, rows, valid, d2, nearest = _fold_case(count)
+    args = (X, (X * X).sum(axis=1), rows, valid, d2, nearest, np.int32(81))
+    narrow = [np.asarray(a) for a in _fold_on(1, widths)(*args)]
+    wide = [np.asarray(a) for a in _fold_on(1, (_CAP,))(*args)]
+    assert narrow[2].tolist() == [min(w for w in widths if w >= count)]
+    assert wide[2].tolist() == [_CAP]
+    assert np.array_equal(narrow[0], wide[0])
+    assert np.array_equal(narrow[1], wide[1])
+    # and both are the brute-force fold over the filled slots
+    new = ((X[:, None, :] - rows[None, :count, :]) ** 2).sum(-1)
+    least = new.min(axis=1, initial=np.inf)
+    closer = least < d2
+    assert np.array_equal(narrow[0], np.where(closer, least, d2))
+    if count:  # ties go to the first slot, here as in numpy
+        assert np.array_equal(narrow[1][closer],
+                              81 + new[closer].argmin(axis=1))
+    assert np.array_equal(narrow[1][~closer], nearest[~closer])
+    assert closer.any() == (count > 0) and not closer.all()
+
+
+@pytest.mark.parametrize("count", _COUNTS)
+def test_two_shards_and_one_shard_take_the_same_branch(count):
+    """The count is the same on every shard after the all-gather, so
+    every shard takes the one branch a single shard would."""
+    widths = km._fold_widths(_ELL, _CAP)
+    X, rows, valid, d2, nearest = _fold_case(count)
+    args = (X, (X * X).sum(axis=1), rows, valid, d2, nearest, np.int32(81))
+    one = [np.asarray(a) for a in _fold_on(1, widths)(*args)]
+    two = [np.asarray(a) for a in _fold_on(2, widths)(*args)]
+    assert two[2].tolist() == one[2].tolist() * 2
+    assert np.array_equal(one[0], two[0]) and np.array_equal(one[1], two[1])
+
+
+def _round_counts(keep, rounds, cap):
+    """How many slots each round filled, from the buffer's valid flags."""
+    return [int(keep[1 + r * cap: 1 + (r + 1) * cap].sum())
+            for r in range(rounds)]
+
+
+#: a key under which three of the 14 rounds on ``_whole_numbers(2003, 4,
+#: 12)`` draw 9 rows where ell is 4 (a round in fifty does)
+_OVERFLOW_KEY = 10
+
+
+def test_a_round_that_draws_more_than_w2_takes_the_cap_branch(monkeypatch):
+    """... and the program returns what the parent's does, whose every
+    fold is ``cap`` wide: the same candidates in the same slots, the same
+    weights."""
+    Xs = shard_rows(_whole_numbers(2003, 4, seed=12))
+    key = jax.random.PRNGKey(_OVERFLOW_KEY)
+    cand, keep, weights, rounds, slots, cap = km._sample_candidates(
+        Xs, 2, key, 2, None)
+    widths = km._fold_widths(4.0, cap)
+    assert (cap, widths) == (16, (8, 16))
+    counts = _round_counts(np.asarray(keep), int(rounds), cap)
+    assert max(counts) > widths[-2]  # the draw the test is made for
+    assert int(slots) == sum(
+        min(w for w in widths if w >= c) for c in counts)
+    assert int(rounds) * widths[0] < int(slots) < int(rounds) * cap
+
+    def cap_wide(*args, **static):  # a new function: traced anew
+        return km._init_rounds_fn(*args, **static)
+
+    monkeypatch.setattr(km, "_fold_widths", lambda ell, cap: (cap,))
+    monkeypatch.setattr(km, "_init_rounds", jax.jit(
+        cap_wide, static_argnames=(
+            "ell", "cap", "max_rounds", "mesh_holder", "scatter")))
+    parent = km._sample_candidates(Xs, 2, key, 2, None)
+    assert int(parent[4]) == int(parent[3]) * cap  # every fold cap wide
+    for got, want in zip((cand, keep, weights, rounds), parent):
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_slots_on_the_span_and_in_the_registry_are_the_widths_summed():
+    def counter():
+        return obs.metrics_snapshot()["counters"].get("kmeans.init_slots", 0)
+
+    Xs = shard_rows(_blobs(rows=1500, seed=6))
+    before = counter()
+    KMeans(n_clusters=5, random_state=3).fit(Xs)
+    attrs = next(c for c in _fit_tree()["children"]
+                 if c["name"] == "kmeans.init")["attrs"]
+    _, keep, _, rounds, slots, cap = km._sample_candidates(
+        Xs, 5, jax.random.PRNGKey(3), 2, None)  # the fit's own draws
+    widths = km._fold_widths(10.0, cap)
+    assert (cap, widths, attrs["cap"]) == (40, (16, 24, 40), 40)
+    by_hand = sum(min(w for w in widths if w >= c) for c in _round_counts(
+        np.asarray(keep), int(rounds), cap))
+    assert attrs["slots"] == int(slots) == by_hand == counter() - before
+    assert attrs["rounds"] == int(rounds)
+    assert attrs["rounds"] * widths[0] <= by_hand < attrs["rounds"] * cap
 
 
 def test_first_selected_finds_the_first_true_entries():

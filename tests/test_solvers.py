@@ -1,3 +1,5 @@
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -779,6 +781,23 @@ def v5e_chip():
     return Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
 
 
+@contextlib.contextmanager
+def _compile_cache_off():
+    """A compile for a described chip is written to the persistent cache
+    and cannot be read back without one: keep it out."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
 class TestCompiledForTheChip:
     """ISSUE 29, at the benchmark's size (31,250,000 x 29 on one v5e):
     what the chip's compiler makes of the whole-solve program.  Compiled,
@@ -802,21 +821,11 @@ class TestCompiledForTheChip:
         args = (S((self.ROWS, self.D), P("data", None)),
                 S((self.ROWS,), P("data")), S((self.ROWS,), P("data")),
                 *scalars, S((), P(), jnp.int32), S((self.D,), P()))
-        # a compile for a described chip is written to the persistent
-        # cache and cannot be read back without one: keep it out
-        from jax.experimental.compilation_cache import compilation_cache
-
-        was = jax.config.jax_enable_compilation_cache
-        jax.config.update("jax_enable_compilation_cache", False)
-        compilation_cache.reset_cache()
-        try:
+        with _compile_cache_off():
             yield lambda **kw: _admm_run.lower(
                 *args, family=Logistic, reg=L2,
                 mesh_holder=MeshHolder(v5e_chip), inner_iter=30,
                 **kw).compile()
-        finally:
-            jax.config.update("jax_enable_compilation_cache", was)
-            compilation_cache.reset_cache()
 
     @pytest.mark.parametrize("line_search", ["backtrack", "probe_grid"])
     def test_cached_predictor_holds_four_row_vectors_and_one_pair_product(
@@ -847,3 +856,70 @@ class TestCompiledForTheChip:
         compiled = lower(line_search="backtrack", objective="black_box")
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert 2 * self.ROWS * 4 < temp < 2.1 * self.ROWS * 4  # 252,606,464
+
+
+class TestKMeansInitCompiledForTheChip:
+    """ISSUE 31, at the benchmark's size (25,000,000 x 50 on one v5e):
+    what the chip's compiler makes of ``kmeans.init_scalable`` with the
+    fold at the width of the round's draw.  Compiled, never run.  (In
+    this file because one process at a time may load the TPU's library:
+    ``v5e_chip``.)"""
+
+    ROWS, D, ELL, CAP = 25_000_000, 50, 16.0, 64
+
+    def test_every_branch_is_one_fusion_fed_by_the_table(self, v5e_chip):
+        import re
+
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from dask_ml_tpu.cluster import k_means as km
+        from dask_ml_tpu.core.mesh import MeshHolder
+
+        def S(shape, spec, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(
+                shape, dtype, sharding=NamedSharding(v5e_chip, spec))
+
+        row = S((self.ROWS,), P("data"))
+        args = (S((self.ROWS, self.D), P("data", None)), row, row,
+                S((self.D,), P()), row, S((2,), P(), jnp.uint32),
+                S((), P(), jnp.int32))
+        with _compile_cache_off():
+            compiled = km._init_rounds.lower(
+                *args, ell=self.ELL, cap=self.CAP, max_rounds=32,
+                mesh_holder=MeshHolder(v5e_chip), scatter="onehot2").compile()
+        widths = km._fold_widths(self.ELL, self.CAP)
+        assert widths == (16, 24, 32, 64)
+        # six vectors of a row's length (601,733,632 B), and no branch
+        # adds a rows x width array (1.6 to 6.4 GB) of its own.  The
+        # parent's 500,955,136 B were five, the carried distances among
+        # them in fast memory (95 MiB, "color 1" of the compiler's buffer
+        # assignment); so they are with up to three branches, and with
+        # four all six lie in HBM (PERF.md section 6, PR 31)
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.61e9
+        hlo = compiled.as_text()
+        bodies = dict(re.findall(
+            r"^(?:ENTRY )?(%[\w.-]+) [^\n]*\{\n(.*?)^\}", hlo, re.M | re.S))
+        fused = set(re.findall(r"calls=(%[\w.-]+)", hlo))
+        # rows x width exists inside a fusion only: no instruction of a
+        # computation that is not fused (so none with a buffer) has it
+        for name, body in bodies.items():
+            if name not in fused:
+                assert not re.search(
+                    r"= f32\[%d,(%s)\]" % (
+                        self.ROWS, "|".join(map(str, widths))), body), name
+        (branches,) = re.findall(r"branch_computations=\{([^}]*)\}", hlo)
+        branches = branches.split(", ")
+        assert len(branches) == len(widths)
+        table = r"f32\[%d,%d\]" % (self.ROWS, self.D)
+        for branch, width in zip(branches, widths):
+            # one fusion reads the table, and its product is width wide
+            (param,) = re.findall(
+                r"(%%[\w.-]+) = %s\S* get-tuple-element" % table,
+                bodies[branch])
+            (fusion,) = re.findall(
+                r"fusion\([^)]*%s[,)].*?calls=(%%[\w.-]+)" % re.escape(param),
+                bodies[branch])
+            assert re.search(
+                r"= f32\[%d,%d\]\S* convolution\(.*operand_precision="
+                r"\{highest,highest\}" % (self.ROWS, width), bodies[fusion])
