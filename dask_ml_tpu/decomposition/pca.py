@@ -5,21 +5,62 @@ column block (tall-skinny), ``svd_solver ∈ {auto, full, tsqr, randomized}``,
 fitted attrs ``components_``, ``explained_variance_(ratio_)``,
 ``singular_values_``, ``mean_``, ``noise_variance_`` (SURVEY.md §3.4).
 
-TPU design: masked mean-centering zeroes the padded rows, then TSQR (exact)
-or Halko (randomized) runs as one shard_map program; every fitted statistic
-comes out of the same compiled computation.
+TPU design: the exact solvers (``full``, ``tsqr``) fit from the (d, d)
+``R`` of the centred table alone (``linalg/tsqr.py :: tsqr_r``: the mean,
+the Gram and the CholeskyQR2 repair as three reads of the table that keep
+(d, d) state; no ``Q``, no centred copy), then one small program for the
+SVD of ``R``, the sign flip and every fitted statistic.  A fit is two
+programs and one wait.  ``randomized`` centres a copy and runs Halko.
 """
 
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from .. import obs as _obs
+from .. import programs as _programs
 from ..base import ComponentsOutMixin, TPUEstimator, TransformerMixin
 from ..core.sharded import ShardedRows, masked_mean
-from ..linalg import randomized_svd, tsqr_svd
+from ..linalg import randomized_svd
+from ..linalg.tsqr import factor_r, householder_r
 from ..preprocessing.data import _ingest_float, _like_input, _masked_or_plain
 from ..utils import svd_flip
+
+
+def _spectrum_fn(r, n, *, k):
+    """From the (d, d) ``R`` of the centred table to the fitted arrays of
+    an exact fit keeping ``k`` components: ``(components, explained
+    variance, its ratio, singular values, noise variance)``."""
+    with jax.named_scope("pca.svd"):
+        _, s, vt = jnp.linalg.svd(r, full_matrices=False)
+    # sklearn >= 1.5 flips on V (deterministic whatever the row order or
+    # the padding); match it so components_ agree elementwise
+    _, vt = svd_flip(None, vt, u_based_decision=False)
+    explained = (s ** 2) / (n - 1)
+    total = jnp.sum(explained)
+    rank = min(r.shape)
+    noise = ((total - jnp.sum(explained[:k])) / (rank - k) if k < rank
+             else jnp.zeros((), s.dtype))
+    return vt[:k], explained[:k], explained[:k] / total, s[:k], noise
+
+
+# graftlint: disable=donation-miss -- (d, d) in, (k, d) and vectors out; R is the caller's
+_spectrum = _programs.cached_program(
+    _spectrum_fn, name="pca.spectrum", static_argnames=("k",))
+
+
+@partial(jax.jit, static_argnames=("k",))
+def _project(x, mask, mean, components, scale, *, k):
+    """``(x - mean) @ components[:k].T * scale``, the pad rows zero: one
+    program, so the centred rows are an operand of the product and never
+    a table in memory."""
+    out = jnp.matmul(x - mean, components[:k].T,
+                     precision=jax.lax.Precision.HIGHEST)
+    return out * scale * mask[:, None].astype(out.dtype)
 
 
 class PCA(ComponentsOutMixin, TransformerMixin, TPUEstimator):
@@ -60,6 +101,13 @@ class PCA(ComponentsOutMixin, TransformerMixin, TPUEstimator):
         return self
 
     def _fit(self, X):
+        # the fit's spans (live under ``obs.enable()`` or a profiler
+        # session): ``pca.fit`` is the root, ``pca.factor`` and
+        # ``pca.spectrum`` its children
+        with _obs.span("pca.fit", solver=self.svd_solver) as root:
+            return self._fit_spanned(X, root)
+
+    def _fit_spanned(self, X, root):
         X = _ingest_float(self, X)
         n, d = X.n_samples, X.data.shape[1]
         if n < d:
@@ -78,40 +126,79 @@ class PCA(ComponentsOutMixin, TransformerMixin, TPUEstimator):
                 )
             k_request = n_components
 
+        root.set(rows=n, features=d, chips=len(X.data.sharding.device_set))
+        if solver == "randomized":
+            return self._fit_randomized(X, n_components, k_request)
+
+        k = d if isinstance(n_components, float) else n_components
+        n_f = np.asarray(n, X.data.dtype)  # goes with the dispatch
+        with _obs.span("pca.factor") as span:
+            r, mean, info = factor_r(X, center="mean")
+            # queued behind the factorization before anything is waited
+            # for: the chip goes on while the host wakes up
+            fitted = _spectrum(r, n_f, k=k)
+            passes, held = (int(v) for v in np.asarray(info))  # the wait
+            if not held:  # the guard's verdict: the backward-stable arm
+                r, fitted = householder_r(X, mean), None
+                passes += 1
+            span.set(passes=passes, fallback=int(not held))
+        with _obs.span("pca.spectrum"):
+            if fitted is None:
+                fitted = _spectrum(r, n_f, k=k)
+            if isinstance(n_components, float):
+                # the least k whose ratios reach the fraction: a host
+                # number, so one fetch and a second, smaller, spectrum
+                cum = np.cumsum(np.asarray(fitted[2]))
+                k = min(int(np.searchsorted(cum, n_components, side="left"))
+                        + 1, d)
+                fitted = _spectrum(r, n_f, k=k)
+            # every caller reads the fitted arrays on the host: their
+            # copies start now, and the fit ends when they are there
+            for value in fitted + (mean,):
+                value.copy_to_host_async()
+            jax.block_until_ready(fitted)
+        reg = _obs.registry()
+        reg.counter("pca.count").inc()
+        reg.counter("pca.passes").inc(passes)
+        reg.counter("pca.fallbacks").inc(int(not held))
+
+        self.n_components_ = k
+        (self.components_, self.explained_variance_,
+         self.explained_variance_ratio_, self.singular_values_,
+         self.noise_variance_) = fitted
+        self.mean_ = mean
+        self.n_samples_ = n
+        self.n_features_in_ = d
+        self.n_passes_ = passes
+        return X
+
+    def _fit_randomized(self, X, n_components, k_request):
+        """Halko's range finder on a centred copy of the table (the copy
+        is the solver's: it multiplies the table ``2 * iterated_power + 2``
+        times)."""
+        n, d = X.n_samples, X.data.shape[1]
         centered, mean = self._center(X)
-        if solver == "randomized":
-            u, s, vt = randomized_svd(
-                centered, k_request, n_iter=self.iterated_power,
-                random_state=self.random_state,
-            )
-        else:
-            u, s, vt = tsqr_svd(centered)
-        # sklearn >= 1.5 flips on V (deterministic regardless of row order /
-        # padding); match it so components_ agree elementwise.
-        u, vt = svd_flip(u, vt, u_based_decision=False)
+        _, s, vt = randomized_svd(
+            centered, k_request, n_iter=self.iterated_power,
+            random_state=self.random_state,
+        )
+        _, vt = svd_flip(None, vt, u_based_decision=False)
+        # s has k_request entries; the total variance needs all d, so it
+        # is the masked total variance
+        from ..core.sharded import masked_var
 
-        # Full spectrum statistics (s has k_request entries; total variance
-        # needs all d — with full solver s covers everything, with randomized
-        # we fall back to the masked total variance).
         explained = (s ** 2) / (n - 1)
-        if solver == "randomized":
-            from ..core.sharded import masked_var
-
-            total_var = jnp.sum(masked_var(X.data, X.mask, ddof=1))
-        else:
-            total_var = jnp.sum(explained)
-        ratio = explained / total_var
-
+        total_var = jnp.sum(masked_var(X.data, X.mask, ddof=1))
         if isinstance(n_components, float):
-            cum = jnp.cumsum(ratio)
-            k = min(int(jnp.searchsorted(cum, n_components, side="left")) + 1, len(s))
+            cum = jnp.cumsum(explained / total_var)
+            k = min(int(jnp.searchsorted(cum, n_components, side="left"))
+                    + 1, len(s))
         else:
             k = n_components
-
         self.n_components_ = k
         self.components_ = vt[:k]
         self.explained_variance_ = explained[:k]
-        self.explained_variance_ratio_ = ratio[:k]
+        self.explained_variance_ratio_ = explained[:k] / total_var
         self.singular_values_ = s[:k]
         self.mean_ = mean
         self.n_samples_ = n
@@ -122,7 +209,7 @@ class PCA(ComponentsOutMixin, TransformerMixin, TPUEstimator):
             )
         else:
             self.noise_variance_ = jnp.asarray(0.0, dtype=s.dtype)
-        return u, s, vt
+        return X
 
     def transform(self, X):
         x, _ = _masked_or_plain(X)
@@ -132,12 +219,13 @@ class PCA(ComponentsOutMixin, TransformerMixin, TPUEstimator):
         return _like_input(X, out)
 
     def fit_transform(self, X, y=None):
-        u, s, vt = self._fit(X)
-        out = u[:, : self.n_components_] * s[: self.n_components_]
-        if self.whiten:
-            import math
-
-            out = out * math.sqrt(self.n_samples_ - 1) / s[: self.n_components_]
+        """The fit, then one product ``(X - mean_) @ components_.T`` (no
+        ``Q`` of the table anywhere)."""
+        rows = self._fit(X)
+        scale = (1.0 / jnp.sqrt(self.explained_variance_) if self.whiten
+                 else jnp.ones((), rows.data.dtype))
+        out = _project(rows.data, rows.mask, self.mean_, self.components_,
+                       scale, k=self.n_components_)
         if isinstance(X, ShardedRows):
             return ShardedRows(data=out, mask=X.mask, n_samples=X.n_samples)
         return out[: self.n_samples_]

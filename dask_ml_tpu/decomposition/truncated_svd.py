@@ -10,9 +10,10 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from ..base import ComponentsOutMixin, TPUEstimator, TransformerMixin
-from ..core.sharded import ShardedRows, masked_mean, masked_var
-from ..linalg import randomized_svd, tsqr_svd
+from ..core.sharded import ShardedRows, masked_var
+from ..linalg import randomized_svd, tsqr_r
 from ..preprocessing.data import _ingest_float, _like_input, _masked_or_plain
+from .pca import _project
 from ..utils import svd_flip
 
 
@@ -39,22 +40,29 @@ class TruncatedSVD(ComponentsOutMixin, TransformerMixin, TPUEstimator):
             raise ValueError(
                 f"n_components must be in (0, n_features={d}); got {k}"
             )
-        # Zero the padded rows: unlike PCA there is no centering step to do
-        # it, and sharded inputs from upstream transforms (e.g. a scaler)
-        # carry nonzero pad rows.
-        data = X.data * X.mask[:, None]
         if self.algorithm in ("tsqr", "full"):
-            u, s, vt = tsqr_svd(data)
-            u, s, vt = u[:, :k], s[:k], vt[:k]
+            # R alone (no Q, no zeroed copy of the table: the mask is
+            # applied inside the passes), then one product for the scores
+            r, _, _ = tsqr_r(X)
+            _, s, vt = jnp.linalg.svd(r, full_matrices=False)
+            _, vt = svd_flip(None, vt, u_based_decision=False)
+            s, vt = s[:k], vt[:k]
+            transformed = _project(
+                X.data, X.mask, jnp.zeros((d,), X.data.dtype), vt,
+                jnp.ones((), X.data.dtype), k=k)
         elif self.algorithm == "randomized":
+            # Zero the padded rows: there is no centering step to do it,
+            # and sharded inputs from upstream transforms (e.g. a scaler)
+            # carry nonzero pad rows.
             u, s, vt = randomized_svd(
-                data, k, n_iter=self.n_iter, random_state=self.random_state
+                X.data * X.mask[:, None], k, n_iter=self.n_iter,
+                random_state=self.random_state,
             )
+            u, vt = svd_flip(u, vt, u_based_decision=False)
+            transformed = u * s
         else:
             raise ValueError(f"Unknown algorithm: {self.algorithm!r}")
-        u, vt = svd_flip(u, vt, u_based_decision=False)
 
-        transformed = u * s
         n = X.n_samples
         self.components_ = vt
         exp_var = masked_var(transformed, X.mask)

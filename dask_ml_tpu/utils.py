@@ -215,14 +215,16 @@ def handle_zeros_in_scale(scale):
 
 
 def svd_flip(u, v, u_based_decision: bool = True):
-    """Deterministic SVD sign convention (reference: ``utils.py :: svd_flip``)."""
+    """Deterministic SVD sign convention (reference: ``utils.py :: svd_flip``).
+    ``u`` may be None where only ``v`` is kept (a V-based decision)."""
     if u_based_decision:
         max_abs = jnp.argmax(jnp.abs(u), axis=0)
         signs = jnp.sign(u[max_abs, jnp.arange(u.shape[1])])
     else:
         max_abs = jnp.argmax(jnp.abs(v), axis=1)
         signs = jnp.sign(v[jnp.arange(v.shape[0]), max_abs])
-    u = u * signs[jnp.newaxis, :]
+    if u is not None:
+        u = u * signs[jnp.newaxis, :]
     v = v * signs[:, jnp.newaxis]
     return u, v
 

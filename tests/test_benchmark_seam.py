@@ -1,7 +1,8 @@
 """The seam the benchmark reads the program through (tier-1's guard).
 
 ``benchmarks/layer_metrics/*`` find the program by strings: span names
-(``glm.fit`` > ``glm.solve``, ``kmeans.fit`` > ``kmeans.init`` ...),
+(``glm.fit`` > ``glm.solve``, ``kmeans.fit`` > ``kmeans.init``,
+``pca.fit`` > ``pca.factor`` ...),
 attributes on those spans (``passes``, ``trials``, ``rounds`` ...) and,
 in the configurations, the XLA module names of the solve's programs
 (``jit__admm_run``, ``jit__lloyd_loop_fn`` ...).  A rename on the
@@ -54,16 +55,20 @@ PROGRAM_METRICS = [
 NAMED_PROGRAMS = sorted({
     (name, module)
     for name, cfg in CONFIGS.items()
-    for key in ("solve_modules", "init_modules")
+    for key in ("solve_modules", "init_modules", "factor_modules")
     for module in cfg.get(key, ())
 })
 
 
 def _table(cfg, rows):
-    """Two classes through a logistic model, or eight far blobs: the
-    kind of table the configuration's generator makes, small."""
+    """Two classes through a logistic model, eight far blobs, or a
+    Gaussian with a decaying spectrum off the origin: the kind of table
+    the configuration's generator makes, small."""
     rng = np.random.RandomState(0)
     d = int(cfg["features"])
+    if cfg["estimator"].endswith("PCA"):
+        X = rng.normal(size=(rows, d)) * np.geomspace(4.0, 0.25, d)
+        return (X + rng.uniform(-10.0, 10.0, size=d)).astype(np.float32), None
     if cfg["estimator"].endswith("KMeans"):
         centres = rng.uniform(-10.0, 10.0, size=(8, d))
         X = centres[rng.randint(8, size=rows)] + rng.normal(size=(rows, d))
@@ -110,6 +115,16 @@ def _modules_of_a_cold_fit(config):
     other fit of this process has (so each is lowered, and logged,
     here); fitted once a process."""
     rows = ROWS + 8 * (1 + sorted(CONFIGS).index(config))
+    # a program whose shapes do not follow the rows (the PCA's (d, d)
+    # spectrum) was compiled by an earlier fit of this process: every
+    # executable is dropped, so that this fit lowers each anew
+    import jax
+
+    from dask_ml_tpu.programs import cache
+
+    for program in list(cache._BY_NAME.values()):
+        program.clear()
+    jax.clear_caches()
     log = logging.getLogger("jax._src.interpreters.pxla")
     seen, level = _CompiledModules(), log.level
     log.addHandler(seen)

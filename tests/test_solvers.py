@@ -765,20 +765,27 @@ class TestLinearObjective:
 
 
 @pytest.fixture(scope="module")
-def v5e_chip():
-    """One chip of a DESCRIBED v5e (the TPU's compiler runs here without
-    the chip): a sharding to give ``jax.ShapeDtypeStruct``s.  Described
-    inside the fixture, never at import: one process at a time may load
-    the TPU's library."""
+def v5e_devices():
+    """The four chips of a DESCRIBED v5e host (the TPU's compiler runs
+    here without the chip).  Described inside the fixture, never at
+    import: one process at a time may load the TPU's library."""
     from jax.experimental import topologies
-    from jax.sharding import Mesh
 
     try:
         topo = topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler here, or its lock is held
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def v5e_chip(v5e_devices):
+    """One chip of the described host: a mesh to give
+    ``jax.ShapeDtypeStruct``s their sharding."""
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(v5e_devices[:1]).reshape(1, 1), ("data", "model"))
 
 
 @contextlib.contextmanager
@@ -923,3 +930,64 @@ class TestKMeansInitCompiledForTheChip:
             assert re.search(
                 r"= f32\[%d,%d\]\S* convolution\(.*operand_precision="
                 r"\{highest,highest\}" % (self.ROWS, width), bodies[fusion])
+
+
+class TestTsqrRCompiledForTheChip:
+    """ISSUE 32, at the benchmark's size (25,000,000 x 64 on one v5e; 100M
+    rows over four): what the chip's compiler makes of ``tsqr.r``, the
+    factorization ``PCA.fit`` keeps R of and never Q.  Compiled, never
+    run.  (In this file because one process at a time may load the TPU's
+    library: ``v5e_chip``.)"""
+
+    ROWS, D = 25_000_000, 64
+
+    def _compiled(self, mesh, rows, strategy="cholqr2"):
+        import importlib
+
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        tsqr = importlib.import_module("dask_ml_tpu.linalg.tsqr")
+
+        def S(shape, spec):
+            return jax.ShapeDtypeStruct(
+                shape, jnp.float32, sharding=NamedSharding(mesh, spec))
+
+        with _compile_cache_off():
+            return tsqr._tsqr_r_impl.lower(
+                S((rows, self.D), P("data", None)), S((rows,), P("data")),
+                S((self.D,), P()), mesh_holder=tsqr._MeshHolder(mesh),
+                find_mean=True, strategy=strategy).compile()
+
+    def test_no_array_of_the_tables_size_is_written(self, v5e_chip):
+        import re
+
+        compiled = self._compiled(v5e_chip, self.ROWS)
+        table = self.ROWS * self.D * 4
+        # ISSUE 32 asked for temporaries under a tenth of the table; the
+        # passes keep a step's slab and (32, 64, 64) sums: under a
+        # hundredth (451,584 B when written)
+        assert compiled.memory_analysis().temp_size_in_bytes < table / 100
+        hlo = compiled.as_text()
+        # one loop a pass, each under its named scope, and on one chip no
+        # collective at all
+        for scope in ("pca.mean", "pca.gram", "pca.repair"):
+            assert re.search(r"while\(.*op_name=\"jit\(_tsqr_r_fn\)/%s/while"
+                             % re.escape(scope), hlo), scope
+        assert not re.search(r"all-reduce|all-gather|all-to-all", hlo)
+
+    def test_four_chips_exchange_only_d_by_d(self, v5e_devices):
+        import re
+
+        from jax.sharding import Mesh
+
+        mesh = Mesh(np.array(v5e_devices[:4]).reshape(4, 1),
+                    ("data", "model"))
+        hlo = self._compiled(mesh, 4 * self.ROWS).as_text()
+        shapes = re.findall(
+            r"= \(?([a-z0-9]+\[[0-9,]*\])[^=\n]*? all-(?:reduce|gather)"
+            r"(?:-start)?\(", hlo)
+        assert shapes  # the sums do cross chips
+        for shape in shapes:
+            dims = [int(n) for n in re.findall(r"\d+", shape.split("[")[1])]
+            assert int(np.prod(dims or [1])) <= self.D * self.D, shape
