@@ -1,8 +1,10 @@
 """GLM estimators — twin of ``dask_ml/linear_model/glm.py``
 (``LogisticRegression``, ``LinearRegression``, ``PoissonRegression``, base
 ``_GLM``): an sklearn facade that maps ``C``/``penalty``/``solver`` onto the
-solver library (``lamduh = 1/C``, reference convention), adds the intercept
-column, and exposes ``coef_``/``intercept_``.
+solver library (``lamduh = 1/C``, reference convention), asks the solver for
+an intercept beside the weights (``intercept=fit_intercept``: the table is
+handed over as the caller gave it, no column of ones is appended), and
+exposes ``coef_``/``intercept_``.
 """
 
 from __future__ import annotations
@@ -28,7 +30,7 @@ from ..solvers import (
     proximal_grad,
 )
 from ..solvers.algorithms import COUNTED_SOLVERS, SOLVE_COUNTS
-from .utils import add_intercept, binary_indicator
+from .utils import binary_indicator
 
 _SOLVERS = {
     "admm": admm,
@@ -59,6 +61,14 @@ def _publish_counts(span, counts):
     reg.counter("solve.count").inc()
     for name, value in counts.items():
         reg.counter(f"solve.{name}").inc(value)
+
+
+def _appended_bytes(X, Xi):
+    """What ``glm.prepare`` reports as ``appended_bytes``: the size of a
+    table made for the solver in the place of the ingested ``X`` (a
+    column appended, a copy); 0 while the solver reads ``X`` itself
+    (weights change the mask alone)."""
+    return 0 if Xi.data is X.data else int(Xi.data.nbytes)
 
 
 class _GLM(TPUEstimator):
@@ -97,6 +107,7 @@ class _GLM(TPUEstimator):
             regularizer=get_regularizer(self.penalty),
             lamduh=1.0 / self.C,
             max_iter=self.max_iter,
+            intercept=bool(self.fit_intercept),
             **(self.solver_kwargs or {}),
         )
         if self.solver == "admm":
@@ -206,11 +217,10 @@ class _GLM(TPUEstimator):
         from ..solvers import lambda_sweep
 
         X = _ingest_float(self, X)
-        Xi = add_intercept(X) if self.fit_intercept else X
         kwargs = self._solver_call_kwargs()
         kwargs.pop("lamduh")
         betas, _ = lambda_sweep(
-            self.solver, Xi, y, [1.0 / float(c) for c in Cs],
+            self.solver, X, y, [1.0 / float(c) for c in Cs],
             family=self.family, **kwargs,
         )
         return betas
@@ -225,27 +235,31 @@ class _GLM(TPUEstimator):
             return self._fit(X, y, sample_weight, root)
 
     def _prepare(self, X, root, span, **root_attrs):
-        """Ingest X and append the intercept column; the fit's sizes go
-        on the root span."""
+        """Ingest X: ``(X, p)``, the table the solver reads and the
+        number of parameters per class (one more than X is wide with an
+        intercept, which the solver carries as a scalar: no column is
+        appended).  The fit's sizes go on the root span."""
         X = _ingest_float(self, X)
         self.n_features_in_ = X.data.shape[1]
-        Xi = add_intercept(X) if self.fit_intercept else X
         root.set(rows=X.n_samples, features=self.n_features_in_,
                  chips=len(X.data.sharding.device_set), **root_attrs)
-        span.set(padded_rows=Xi.data.shape[0])
-        return Xi
+        span.set(padded_rows=X.data.shape[0],
+                 intercept="scalar" if self.fit_intercept else "none")
+        return X, self.n_features_in_ + bool(self.fit_intercept)
 
     def _fit(self, X, y, sample_weight, root):
         with _obs.span("glm.prepare") as span:
-            Xi = self._prepare(X, root, span)
+            X0, p = self._prepare(X, root, span)
+            Xi = X0
             if sample_weight is not None:
                 from ..utils import reweight_rows
 
                 Xi = reweight_rows(Xi, sample_weight=sample_weight)
+            span.set(appended_bytes=_appended_bytes(X0, Xi))
             warm = None
             if self.warm_start:
                 warm = self._warm_ok(
-                    getattr(self, "betas_", None), (1, Xi.data.shape[1]),
+                    getattr(self, "betas_", None), (1, p),
                     was_multinomial=getattr(self, "_multinomial", False),
                 )
         with self._solve_span() as span:
@@ -321,12 +335,11 @@ class LogisticRegression(ClassifierMixin, _GLM):
                 f"{classes.tolist()}"
             )
         X = _ingest_float(self, X)
-        Xi = add_intercept(X) if self.fit_intercept else X
         y01 = binary_indicator(y, classes[1])
         kwargs = self._solver_call_kwargs()
         kwargs.pop("lamduh")
         betas, _ = lambda_sweep(
-            self.solver, Xi, y01, [1.0 / float(c) for c in Cs],
+            self.solver, X, y01, [1.0 / float(c) for c in Cs],
             family=self.family, **kwargs,
         )
         return betas, classes
@@ -406,7 +419,8 @@ class LogisticRegression(ClassifierMixin, _GLM):
         softmax = not binary and self.multi_class == "multinomial"
 
         with _obs.span("glm.prepare") as span:
-            Xi = self._prepare(X, root, span, classes=K)
+            X0, p = self._prepare(X, root, span, classes=K)
+            Xi = X0
             if sample_weight is not None or self.class_weight is not None:
                 # weights scale the mask: every masked reduction in the
                 # solvers becomes the sklearn weighted loss (the mask
@@ -434,8 +448,8 @@ class LogisticRegression(ClassifierMixin, _GLM):
                     )
                 else:
                     Xi = reweight_rows(Xi, sample_weight=sample_weight)
+            span.set(appended_bytes=_appended_bytes(X0, Xi))
             # the solve's target and its warm start (previous betas_)
-            p = Xi.data.shape[1]
             if binary:
                 # one-vs-rest target via the SHARED encoding helper
                 target = binary_indicator(

@@ -82,19 +82,30 @@ def _param_dtype(x):
     return jnp.promote_types(x.dtype, jnp.float32)
 
 
-def _pdim(x, family):
-    """Parameter-vector length: features × the family's parameters per
-    feature (1 for scalar-response families; K for multinomial softmax,
-    whose flat beta reshapes to (features, K) inside the loss)."""
-    return x.shape[1] * int(getattr(family, "params_per_feature", 1))
+def _pdim(x, family, intercept=False):
+    """Parameter-vector length: features (one more with an intercept,
+    which comes last) × the family's parameters per feature (1 for
+    scalar-response families; K for multinomial softmax, whose flat beta
+    reshapes to (features, K) inside the loss).  The length is how the
+    intercept reaches the jitted runners: ``Family.split`` tells a vector
+    one longer than the table is wide from one that is not."""
+    return (x.shape[1] + bool(intercept)) * int(
+        getattr(family, "params_per_feature", 1))
 
 
-def _init_beta(beta0, x, family):
+def _has_intercept(beta, x, family):
+    """Whether a runner's parameter vector carries an intercept: a static
+    fact of the trace, read from the vector's length as ``Family.split``
+    reads it."""
+    return beta.shape[0] != _pdim(x, family)
+
+
+def _init_beta(beta0, x, family, intercept=False):
     """Resolve a solver's initial parameter vector: zeros (cold start)
     or a caller-supplied warm start (``LogisticRegression(warm_start=
     True)`` passes the previous fit's coefficients).  Shape-checked: a
     wrong-length init is a caller bug, not something to run with."""
-    d = _pdim(x, family)
+    d = _pdim(x, family, intercept)
     dt = _param_dtype(x)
     if beta0 is None:
         return jnp.zeros(d, dtype=dt)
@@ -152,11 +163,14 @@ def _make_objective(family, reg, x, y, mask, lamduh):
                             lambda b: reg.penalty(b, lamduh))
 
 
-def _lbfgs_objective(objective, family, x, y, mask, smooth):
+def _lbfgs_objective(objective, family, x, y, mask, smooth,
+                     intercept=False):
     """The family's loss plus ``smooth`` (a function of the parameters
     alone), as a black-box closure or, for ``objective="linear"``, in the
     parts ``lbfgs_minimize`` can use to search on the cached linear
-    predictor.  ``objective`` is the counted runners' PRIVATE static
+    predictor (with ``intercept``, :func:`_has_intercept` of the vector
+    to be found, the intercept is the ``LinearObjective``'s ``offset``).
+    ``objective`` is the counted runners' PRIVATE static
     argument, set by the entry points alone: ``admm()`` and ``lbfgs()``
     ask for ``"linear"``, a caller that puts the runner under ``vmap``
     for ``"black_box"``.  Asked for ``"linear"``, two kinds of family get
@@ -172,9 +186,11 @@ def _lbfgs_objective(objective, family, x, y, mask, smooth):
             or getattr(family, "params_per_feature", 1) > 1):
         return lambda b: family.loss(b, x, y, mask) + smooth(b)
     return LinearObjective(
-        predict=lambda *betas: family.linear_predictors(x, *betas),
+        predict=lambda *betas: family.products(
+            x, *(family.split(b, x)[0] for b in betas)),
         pointwise=lambda eta: family.pointwise_loss(eta, y, mask),
-        smooth=smooth)
+        smooth=smooth,
+        offset=(lambda b: family.split(b, x)[1]) if intercept else None)
 
 
 def _converged(f_prev, f_new, tol):
@@ -193,7 +209,8 @@ def _converged(f_prev, f_new, tol):
 def _lbfgs_run(x, yv, mask, beta0, lamduh, max_iter, tol, *, family, reg,
                line_search="backtrack", objective="black_box"):
     obj = _lbfgs_objective(objective, family, x, yv, mask,
-                           lambda b: reg.penalty(b, lamduh))
+                           lambda b: reg.penalty(b, lamduh),
+                           _has_intercept(beta0, x, family))
     beta, st = lbfgs_minimize(
         obj, beta0, max_iter=max_iter, tol=tol, line_search=line_search
     )
@@ -204,7 +221,7 @@ def _lbfgs_run(x, yv, mask, beta0, lamduh, max_iter, tol, *, family, reg,
 def lbfgs(X, y, *, family: type[Family] = Logistic, regularizer=L2,
           lamduh: float = 0.0, max_iter: int = 100, tol: float = 1e-5,
           beta0=None, return_n_iter: bool = False, line_search: str = "auto",
-          return_counts: bool = False):
+          return_counts: bool = False, intercept: bool = False):
     """Full-gradient L-BFGS on the total (smooth) objective.
 
     Reference: ``dask_glm/algorithms.py :: lbfgs`` (scipy driver with
@@ -214,6 +231,16 @@ def lbfgs(X, y, *, family: type[Family] = Logistic, regularizer=L2,
 
     ``line_search="auto"`` resolves per platform (probe_grid on TPU,
     backtrack on CPU — :func:`line_search_strategy`).
+
+    ``intercept=True`` (every solver, ``packed_solve`` and
+    ``lambda_sweep`` take it) fits a constant term beside the weights:
+    the parameter vector is one longer than ``X`` is wide (per class for
+    a matrix of parameters), the intercept LAST, and the linear predictor
+    is ``X @ beta[:-1] + beta[-1]``.  It is the fit a column of ones
+    appended to ``X`` gives, penalty and ADMM's consensus covering the
+    intercept as they cover that column's weight, without the copy of
+    the table that appending costs.  With ``intercept=False`` (the
+    default) a caller who wants a constant term brings the column.
     """
     line_search = line_search_strategy(line_search)
     reg = get_regularizer(regularizer)
@@ -224,7 +251,7 @@ def lbfgs(X, y, *, family: type[Family] = Logistic, regularizer=L2,
         )
     x, yv, mask = _prep(X, y)
     DISPATCH_COUNTS["solves"] += 1
-    beta0 = _init_beta(beta0, x, family)
+    beta0 = _init_beta(beta0, x, family, intercept)
     beta, counts = _lbfgs_run(
         x, yv, mask, beta0, jnp.asarray(lamduh, _param_dtype(x)),
         jnp.int32(max_iter), jnp.asarray(tol, _param_dtype(x)),
@@ -273,7 +300,8 @@ def gradient_descent(X, y, *, family: type[Family] = Logistic,
                      regularizer=L2, lamduh: float = 0.0,
                      max_iter: int = 100, tol: float = 1e-7,
                      beta0=None, return_n_iter: bool = False,
-                     line_search: str = "backtrack"):
+                     line_search: str = "backtrack",
+                     intercept: bool = False):
     """Armijo-backtracking gradient descent (reference ``gradient_descent``)."""
     line_search = line_search_strategy(line_search)
     reg = get_regularizer(regularizer)
@@ -281,7 +309,7 @@ def gradient_descent(X, y, *, family: type[Family] = Logistic,
         raise ValueError("gradient_descent requires a smooth penalty; use proximal_grad")
     x, yv, mask = _prep(X, y)
     DISPATCH_COUNTS["solves"] += 1
-    beta0 = _init_beta(beta0, x, family)
+    beta0 = _init_beta(beta0, x, family, intercept)
     beta, n_it = _gd_run(
         x, yv, mask, beta0, jnp.asarray(lamduh, _param_dtype(x)),
         jnp.int32(max_iter), jnp.asarray(tol, _param_dtype(x)),
@@ -308,7 +336,11 @@ def _pg_run(x, yv, mask, beta0, lamduh, max_it, tol, *, family, reg):
             z = reg.prox(beta - t * g, t * lamduh)
             diff = z - beta
             ub = f + jnp.dot(g, diff) + jnp.sum(diff ** 2) / (2 * t)
-            return (f_smooth(z) > ub) & (j < 30)
+            # "not under the bound", so that a step whose loss is not a
+            # number backtracks as one whose loss is infinite does: with
+            # an intercept a pad row's predictor is the intercept, and
+            # where its loss overflows the mask makes 0 * inf of it
+            return ~(f_smooth(z) <= ub) & (j < 30)
 
         def body(carry):
             t, j = carry
@@ -340,13 +372,14 @@ def _pg_run(x, yv, mask, beta0, lamduh, max_it, tol, *, family, reg):
 
 def proximal_grad(X, y, *, family: type[Family] = Logistic, regularizer=L2,
                   lamduh: float = 0.0, max_iter: int = 100, tol: float = 1e-7,
-          beta0=None, return_n_iter: bool = False):
+                  beta0=None, return_n_iter: bool = False,
+                  intercept: bool = False):
     """Proximal gradient with backtracking on the smooth part (reference
     ``proximal_grad``): z = prox_{tλ}(β − t∇f(β))."""
     reg = get_regularizer(regularizer)
     x, yv, mask = _prep(X, y)
     DISPATCH_COUNTS["solves"] += 1
-    beta0 = _init_beta(beta0, x, family)
+    beta0 = _init_beta(beta0, x, family, intercept)
     beta, n_it = _pg_run(
         x, yv, mask, beta0, jnp.asarray(lamduh, _param_dtype(x)),
         jnp.int32(max_iter), jnp.asarray(tol, _param_dtype(x)),
@@ -365,13 +398,18 @@ def _newton_run(x, yv, mask, beta0, lamduh, max_it, tol, *, family, reg,
                 line_search="backtrack"):
     obj = _make_objective(family, reg, x, yv, mask, lamduh)
     vg = jax.value_and_grad(obj)
-    d = x.shape[1]
+    d = beta0.shape[0]
 
     def step(beta):
         f, g = vg(beta)
-        eta = x @ beta
-        w = family.hessian_weights(eta) * mask
-        H = (x * w[:, None]).T @ x  # (d, d) psum-reduced gemm
+        w = family.hessian_weights(family.linear_predictor(beta, x)) * mask
+        xw = x * w[:, None]
+        H = xw.T @ x  # psum-reduced gemm
+        if d != x.shape[1]:  # an intercept (newton: one number a feature)
+            # the border a column of ones would have given: X'w, sum(w)
+            xw1 = jnp.sum(xw, axis=0)
+            H = jnp.block([[H, xw1[:, None]],
+                           [xw1[None, :], jnp.sum(w)[None, None]]])
         if reg.smooth:
             H = H + lamduh * jnp.eye(d, dtype=_param_dtype(x))
         H = H + 1e-8 * jnp.eye(d, dtype=_param_dtype(x))
@@ -402,9 +440,11 @@ def _newton_run(x, yv, mask, beta0, lamduh, max_it, tol, *, family, reg,
 
 def newton(X, y, *, family: type[Family] = Logistic, regularizer=L2,
            lamduh: float = 0.0, max_iter: int = 50, tol: float = 1e-8,
-           beta0=None, return_n_iter: bool = False, line_search: str = "backtrack"):
-    """Damped Newton: distributed Hessian XᵀWX (one psum-reduced gemm),
-    replicated (d×d) solve (reference ``newton``)."""
+           beta0=None, return_n_iter: bool = False,
+           line_search: str = "backtrack", intercept: bool = False):
+    """Damped Newton: distributed Hessian XᵀWX (one psum-reduced gemm,
+    bordered by ``X'w`` and ``sum(w)`` for an intercept), replicated
+    (d×d) solve (reference ``newton``)."""
     line_search = line_search_strategy(line_search)
     reg = get_regularizer(regularizer)
     if lamduh and not reg.smooth:
@@ -417,7 +457,7 @@ def newton(X, y, *, family: type[Family] = Logistic, regularizer=L2,
         )
     x, yv, mask = _prep(X, y)
     DISPATCH_COUNTS["solves"] += 1
-    beta0 = _init_beta(beta0, x, family)
+    beta0 = _init_beta(beta0, x, family, intercept)
     beta, n_it = _newton_run(
         x, yv, mask, beta0, jnp.asarray(lamduh, _param_dtype(x)),
         jnp.int32(max_iter), jnp.asarray(tol, _param_dtype(x)),
@@ -448,14 +488,15 @@ def _admm_run(x, yv, mask, lamduh, rho, abstol, reltol, inner_tol, max_it,
 
     row_ax = _data_axes(mesh)
     n_shards = _data_axes_size(mesh)
-    d = _pdim(x, family)
+    d = z_init.shape[0]  # with the intercept, where the caller asked for one
 
     def one_shard(xb, yb, mb, z_rep, beta_b, u_b, rho_c):
         u0, b0 = u_b[0], beta_b[0]
 
         local_obj = _lbfgs_objective(
             objective, family, xb, yb, mb,
-            lambda b: 0.5 * rho_c * jnp.sum((b - z_rep + u0) ** 2))
+            lambda b: 0.5 * rho_c * jnp.sum((b - z_rep + u0) ** 2),
+            _has_intercept(b0, xb, family))
 
         with jax.named_scope("admm.local_solve"):
             b_new, st = lbfgs_minimize(
@@ -587,7 +628,7 @@ def admm(X, y, *, family: type[Family] = Logistic, regularizer=L2,
          inner_iter: int = 50, inner_tol: float = 1e-6, mesh=None,
          return_n_iter: bool = False, line_search: str = "backtrack",
          adaptive_rho: bool = True, beta0=None,
-         return_counts: bool = False):
+         return_counts: bool = False, intercept: bool = False):
     """Consensus ADMM (Boyd et al. §8): per-shard local subproblems solved by
     the jit-safe L-BFGS inside ``shard_map``, consensus z through the
     regularizer's prox, scaled dual updates.
@@ -626,7 +667,7 @@ def admm(X, y, *, family: type[Family] = Logistic, regularizer=L2,
         jnp.asarray(lamduh, dt), jnp.asarray(rho, dt),
         jnp.asarray(abstol, dt), jnp.asarray(reltol, dt),
         jnp.asarray(inner_tol, dt), jnp.int32(max_iter),
-        _init_beta(beta0, x, family),
+        _init_beta(beta0, x, family, intercept),
         family=family, reg=reg, mesh_holder=MeshHolder(mesh),
         inner_iter=inner_iter, line_search=line_search,
         adaptive_rho=adaptive_rho, objective="linear",
@@ -721,7 +762,8 @@ def packed_solve(solver: str, X, Y, *, family: type[Family] = Logistic,
                  tol: float = 1e-5, rho: float = 1.0, abstol: float = 1e-4,
                  reltol: float = 1e-2, inner_iter: int = 50,
                  inner_tol: float = 1e-6, mesh=None,
-                 line_search: str | None = None, Beta0=None):
+                 line_search: str | None = None, Beta0=None,
+                 intercept: bool = False):
     """All K independent solves as ONE vmapped XLA program over the
     leading axis of ``Y`` — the one-vs-rest fit issues a single dispatch
     instead of K sequential ones (the solvers' whole-solve ``while_loop``
@@ -789,13 +831,14 @@ def packed_solve(solver: str, X, Y, *, family: type[Family] = Logistic,
     # betas_); zeros when cold.  Per-row resolution goes through
     # _init_beta so the batched path shares its validation exactly.
     if Beta0 is None:
-        B0 = jnp.zeros((K, _pdim(x, family)), dtype=dt)
+        B0 = jnp.zeros((K, _pdim(x, family, intercept)), dtype=dt)
     else:
         if len(Beta0) != K:
             raise ValueError(
                 f"Beta0 must have {K} rows (one per lane); got {len(Beta0)}"
             )
-        B0 = jnp.stack([_init_beta(b, x, family) for b in Beta0])
+        B0 = jnp.stack(
+            [_init_beta(b, x, family, intercept) for b in Beta0])
 
     def _sequential(one_fn, *extra_rows):
         # K whole-solve dispatches (the auto fallback where vmap packing
@@ -868,7 +911,7 @@ def lambda_sweep(solver: str, X, y, lams, *, family: type[Family] = Logistic,
                  regularizer=L2, max_iter: int = 100, tol: float = 1e-5,
                  rho: float = 1.0, abstol: float = 1e-4, reltol: float = 1e-2,
                  inner_iter: int = 50, inner_tol: float = 1e-6, mesh=None,
-                 line_search: str = "backtrack"):
+                 line_search: str = "backtrack", intercept: bool = False):
     """All K solves of the SAME (X, y) at different regularization
     strengths as ONE vmapped program — the grid-search twin of
     ``packed_solve`` (there the lanes differ in y, here in ``lamduh``,
@@ -903,7 +946,7 @@ def lambda_sweep(solver: str, X, y, lams, *, family: type[Family] = Logistic,
                 x, yd, mask, lam, jnp.asarray(rho, dt),
                 jnp.asarray(abstol, dt), jnp.asarray(reltol, dt),
                 jnp.asarray(inner_tol, dt), jnp.int32(max_iter),
-                jnp.zeros(_pdim(x, family), dtype=dt),
+                jnp.zeros(_pdim(x, family, intercept), dtype=dt),
                 family=family, reg=reg, mesh_holder=mh,
                 inner_iter=inner_iter, line_search=line_search,
                 objective=objective,
@@ -928,7 +971,7 @@ def lambda_sweep(solver: str, X, y, lams, *, family: type[Family] = Logistic,
         raise ValueError("newton does not support matrix-parameter families")
     DISPATCH_COUNTS["solves"] += 1
     run = runners[solver]
-    B0 = jnp.zeros((K, _pdim(x, family)), dtype=dt)
+    B0 = jnp.zeros((K, _pdim(x, family, intercept)), dtype=dt)
     extra_kw = (
         {} if solver == "proximal_grad" else {"line_search": line_search}
     )

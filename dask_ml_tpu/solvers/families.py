@@ -6,7 +6,9 @@ TPU-first twist: families only define the masked scalar loss; gradients are
 Newton solver asks for per-sample hessian weights only.
 
 The loss has two parts, and ``loss`` is their composition: the linear
-predictor ``X @ beta`` (the only part that reads the design matrix) and the
+predictor ``X @ weights + intercept`` (the only part that reads the design
+matrix; the intercept is the parameter vector's last entry when it is one
+longer than ``X`` is wide, ``Family.split``) and the
 masked pointwise loss of it, ``pointwise_loss(eta, y, mask)``, which is
 what a family defines.  A line search that has ``eta = X @ x`` and
 ``u = X @ p`` needs only the second part to evaluate any step along ``p``
@@ -29,31 +31,55 @@ class Family:
     params_per_feature = 1
 
     @classmethod
-    def linear_predictor(cls, beta, X):  # eta = X @ beta; (n, K) when K > 1
-        if cls.params_per_feature > 1:
-            beta = beta.reshape(X.shape[1], cls.params_per_feature)
-        return X @ beta
+    def split(cls, beta, X):
+        """``(weights, intercept)`` of a flat parameter vector, told apart
+        by its length against ``X``'s width ``d``: ``d * K`` numbers are
+        weights alone (``intercept`` is None; a caller who wants a
+        constant term then has a column of ones in ``X``), ``(d + 1) * K``
+        carry the intercept LAST, where an appended column of ones would
+        have put it.  Weights are ``(d,)``, or ``(d, K)`` when K > 1; the
+        intercept a scalar, or ``(K,)``."""
+        k, d = cls.params_per_feature, X.shape[1]
+        if beta.shape[0] not in (d * k, (d + 1) * k):
+            raise ValueError(
+                f"{beta.shape[0]} parameters for a table {d} wide: this "
+                f"family needs {d * k}, or {(d + 1) * k} with an intercept")
+        b = beta.reshape(-1, k) if k > 1 else beta
+        return (b, None) if beta.shape[0] == d * k else (b[:-1], b[-1])
+
+    @classmethod
+    def linear_predictor(cls, beta, X):
+        """``eta = X @ weights (+ intercept)``; (n, K) when K > 1.  The
+        intercept rides beside the weights as a scalar added to the
+        product: no column of ones is read, or made."""
+        return cls.linear_predictors(X, beta)[0]
 
     @classmethod
     def linear_predictors(cls, X, *betas):
         """``linear_predictor`` of each of a few parameter vectors in ONE
-        read of ``X``, as a tuple: separate products would each read ``X``.
-        Each result is computed as ``linear_predictor`` computes it.  A
-        matrix of parameters (K > 1) is a matrix product already, so
-        several are one product with their columns side by side.  A vector
-        (K == 1) is a multiply-and-sum in the accumulation dtype, which is
-        what XLA makes of a matrix-vector product, so several are one
-        reduction with several results: a dot with their columns side by
-        side would go to the matrix unit at the backend's default
-        precision instead."""
-        if len(betas) == 1:
-            return (cls.linear_predictor(betas[0], X),)
-        k = cls.params_per_feature
-        if k > 1:
-            eta = X @ jnp.concatenate(
-                [b.reshape(X.shape[1], k) for b in betas], axis=1)
-            return tuple(jnp.split(eta, len(betas), axis=1))
-        terms = tuple(X * b for b in betas)  # (n, d)
+        read of ``X`` (:meth:`products`), as a tuple."""
+        weights, intercepts = zip(*(cls.split(b, X) for b in betas))
+        return tuple(
+            eta if b0 is None else eta + b0
+            for eta, b0 in zip(cls.products(X, *weights), intercepts))
+
+    @classmethod
+    def products(cls, X, *weights):
+        """``X @ w`` for each of a few weight arrays (as :meth:`split`
+        shapes them) in ONE read of ``X``, as a tuple: separate products
+        would each read ``X``.  A matrix of weights (K > 1) is a matrix
+        product already, so several are one product with their columns
+        side by side.  A vector (K == 1) is a multiply-and-sum in the
+        accumulation dtype, which is what XLA makes of a matrix-vector
+        product, so several are one reduction with several results: a dot
+        with their columns side by side would go to the matrix unit at
+        the backend's default precision instead."""
+        if len(weights) == 1:
+            return (X @ weights[0],)
+        if cls.params_per_feature > 1:
+            return tuple(jnp.split(
+                X @ jnp.concatenate(weights, axis=1), len(weights), axis=1))
+        terms = tuple(X * w for w in weights)  # (n, d)
         return lax.reduce(
             terms, tuple(jnp.zeros((), t.dtype) for t in terms),
             lambda acc, new: tuple(a + b for a, b in zip(acc, new)), (1,))

@@ -49,24 +49,35 @@ _C2 = 0.9
 
 
 class LinearObjective(NamedTuple):
-    """``f(b) = pointwise(predict(b)[0]) + smooth(b)``: an objective
-    whose only read of the data is a LINEAR map of the parameters.
+    """``f(b) = pointwise(image(b)) + smooth(b)``: an objective whose only
+    read of the data is a LINEAR map of the parameters.
 
     ``predict`` takes a few parameter vectors and returns the tuple of
-    their images in ONE read of the data (``Family.linear_predictors``);
-    ``pointwise`` maps one image to a scalar (``Family.pointwise_loss``
-    with the targets and the mask closed over); ``smooth`` is the part
-    that sees the parameters alone (a penalty; ADMM's proximity term).
-    Calling the objective composes the three, so it also serves wherever
-    a black box is wanted.
+    their images in ONE read of the data (``Family.products`` of their
+    weights); ``pointwise`` maps one image to a scalar
+    (``Family.pointwise_loss`` with the targets and the mask closed
+    over); ``smooth`` is the part that sees the parameters alone (a
+    penalty; ADMM's proximity term).  ``offset``, where there is one, is
+    the part of the map that reads no data: a linear function of the
+    parameters whose value (a GLM's intercept: a scalar, or one number a
+    class) is added to every row of the image, ``image(b) =
+    predict(b)[0] + offset(b)``.  It is kept apart so that it can be
+    added where an image is consumed; folded into ``predict`` it would
+    cost each search two more passes over vectors of the image's length.  Calling the objective composes
+    the parts, so it also serves wherever a black box is wanted.
     """
 
     predict: Callable
     pointwise: Callable
     smooth: Callable
+    offset: Callable | None = None
+
+    def image(self, b):
+        eta, = self.predict(b)
+        return eta if self.offset is None else eta + self.offset(b)
 
     def __call__(self, b):
-        return self.pointwise(self.predict(b)[0]) + self.smooth(b)
+        return self.pointwise(self.image(b)) + self.smooth(b)
 
 
 class LBFGSState(NamedTuple):
@@ -136,26 +147,48 @@ def _black_box_phi(value_and_grad, x, p):
 def _cached_phi(obj: LinearObjective, x, p):
     """``phi(t) -> (f, slope, ())`` along ``x + t p`` for a
     :class:`LinearObjective`, from ONE product: the images ``eta`` of
-    ``x`` and ``u`` of ``p`` (the image of ``x + t p`` is ``eta + t u``).
+    ``x`` and ``u`` of ``p`` (the image of ``x + t p`` is ``eta + t u``,
+    plus the offset's ``c + t q`` where the objective has one: a scalar
+    made inside each trial, never a pass over ``eta`` or ``u``).
     Also returns ``gradient_at(t)``, the objective's gradient at
     ``x + t p`` by one transposed product of the pointwise derivative at
-    ``eta + t u``.  The images live for one search: none is carried
+    that image.  The images live for one search: none is carried
     between iterations, so none drifts from its product."""
     eta, u = obj.predict(x, p)
+    if obj.offset is None:
+        def offset_at(t):
+            return None
+    else:
+        c, q = obj.offset(x), obj.offset(p)
 
-    def image(t):
-        return eta + t * u
+        def offset_at(t):
+            return c + t * q
 
-    def value(t):
-        return obj.pointwise(image(t)) + obj.smooth(x + t * p)
+    def image(t, s):
+        return eta + t * u if s is None else eta + t * u + s
+
+    def value(t, s=None):
+        return obj.pointwise(image(t, s)) + obj.smooth(x + t * p)
 
     def phi(t):
-        f, slope = jax.jvp(value, (t,), (jnp.ones_like(t),))
+        if obj.offset is None:
+            f, slope = jax.jvp(value, (t,), (jnp.ones_like(t),))
+        else:
+            # the derivatives in the step and in the offset apart, by one
+            # reverse pass.  As ONE tangent, ``u + q`` is a vector of the
+            # image's length, which a batched search (probe_grid) writes
+            # out and reads back (125 MB more of temporaries at 31M
+            # rows); as two forward passes the batched grid carries a
+            # third result and ran 15% longer on the chip (PERF.md
+            # section 6, PR 33)
+            f, (in_step, in_offset) = jax.value_and_grad(
+                value, argnums=(0, 1))(t, offset_at(t))
+            slope = in_step + jnp.sum(q * in_offset)
         return f, slope, ()
 
     def gradient_at(t):
-        r = jax.grad(obj.pointwise)(image(t))
-        (g,) = jax.linear_transpose(lambda b: obj.predict(b)[0], x)(r)
+        r = jax.grad(obj.pointwise)(image(t, offset_at(t)))
+        (g,) = jax.linear_transpose(obj.image, x)(r)
         return g + jax.grad(obj.smooth)(x + t * p)
 
     return phi, gradient_at

@@ -806,11 +806,16 @@ def _compile_cache_off():
 
 
 class TestCompiledForTheChip:
-    """ISSUE 29, at the benchmark's size (31,250,000 x 29 on one v5e):
-    what the chip's compiler makes of the whole-solve program.  Compiled,
-    never run: no time or result comes from here."""
+    """ISSUEs 29 and 33, at the benchmark's size (31,250,000 rows on one
+    v5e): what the chip's compiler makes of the whole-solve program, in
+    the two forms a caller can ask for: ``"scalar"``, the 28-column
+    table with the intercept beside 28 weights (what every GLM fit runs
+    since ISSUE 33), and ``"column"``, a 29-column table that brings its
+    own column of ones (``intercept=False``: the program as it was).
+    Compiled, never run: no time or result comes from here."""
 
-    ROWS, D = 31_250_000, 29
+    ROWS, PARAMS = 31_250_000, 29
+    FORMS = {"scalar": 28, "column": 29}  # the table's width
 
     @pytest.fixture()
     def lower(self, v5e_chip):
@@ -824,43 +829,94 @@ class TestCompiledForTheChip:
             return jax.ShapeDtypeStruct(
                 shape, dtype, sharding=NamedSharding(v5e_chip, spec))
 
-        scalars = [S((), P())] * 5
-        args = (S((self.ROWS, self.D), P("data", None)),
-                S((self.ROWS,), P("data")), S((self.ROWS,), P("data")),
-                *scalars, S((), P(), jnp.int32), S((self.D,), P()))
-        with _compile_cache_off():
-            yield lambda **kw: _admm_run.lower(
+        def lower(form, **kw):
+            scalars = [S((), P())] * 5
+            args = (S((self.ROWS, self.FORMS[form]), P("data", None)),
+                    S((self.ROWS,), P("data")), S((self.ROWS,), P("data")),
+                    *scalars, S((), P(), jnp.int32),
+                    S((self.PARAMS,), P()))
+            return _admm_run.lower(
                 *args, family=Logistic, reg=L2,
                 mesh_holder=MeshHolder(v5e_chip), inner_iter=30,
                 **kw).compile()
 
-    @pytest.mark.parametrize("line_search", ["backtrack", "probe_grid"])
-    def test_cached_predictor_holds_four_row_vectors_and_one_pair_product(
-            self, lower, line_search):
+        with _compile_cache_off():
+            yield lower
+
+    def _row_vector_passes(self, hlo):
+        """The fusions of ``hlo`` that read ONE array of a row's length
+        and nothing larger, and write one: an elementwise pass over a
+        row vector (adding a scalar to ``eta`` would be one)."""
         import re
 
-        compiled = lower(line_search=line_search, objective="linear")
+        row = r"f32\[%d\]" % self.ROWS
+        bodies = dict(re.findall(
+            r"^(?:ENTRY )?(%[\w.-]+) [^\n]*\{\n(.*?)^\}", hlo, re.M | re.S))
+        fused = set(re.findall(r"calls=(%[\w.-]+)", hlo))
+        found = []
+        for name, body in bodies.items():
+            if name in fused:
+                continue
+            shape = dict(re.findall(
+                r"^\s*(?:ROOT )?(%[\w.-]+) = (\(.*?\)|\S+) ", body, re.M))
+            for out, operands in re.findall(
+                    r"^\s*(?:ROOT )?%%[\w.-]+ = (%s\S*) fusion\((.*?)\), kind="
+                    % row, body, re.M):
+                read = [shape.get(o.strip(), "") for o in re.sub(
+                    r"/\*.*?\*/", "", operands).split(",")]
+                if sum(str(self.ROWS) in r for r in read) == 1 and any(
+                        re.match(row, r) for r in read):
+                    found.append((name, out, operands))
+        return found
+
+    @pytest.mark.parametrize("form", list(FORMS))
+    @pytest.mark.parametrize("line_search", ["backtrack", "probe_grid"])
+    def test_cached_predictor_holds_four_row_vectors_and_one_pair_product(
+            self, lower, line_search, form):
+        import re
+
+        compiled = lower(form, line_search=line_search, objective="linear")
+        memory = compiled.memory_analysis()
         vector = self.ROWS * 4
+        # the table (28 and 29 features both lie on 32 sublanes: 4.0 GB),
+        # the targets and the mask: what the caller holds, and all a fit
+        # needs beside the temporaries below (4,250,013,184 B, both forms)
+        assert memory.argument_size_in_bytes < 4.26e9
         # a CEILING, and over the one ISSUE 29 set (the black box's two
         # vectors and 80 MB): eta, u, the residual and the hoisted
         # -y * mask, which the black box holds too.  The residual does
         # not take eta's place, as it does in the black box, because eta
         # and u are the two results of ONE fusion (PERF.md section 7).
         # Under probe_grid the 34 candidates are one fused reduction over
-        # the four, and a rows x 34 array (4.25 GB) is never made
-        temp = compiled.memory_analysis().temp_size_in_bytes
-        assert temp < 4.1 * vector
+        # the four, and a rows x 34 array (4.25 GB) is never made.  The
+        # intercept adds no fifth: 502,286,848 / 502,320,128 B against
+        # the column form's 501,996,544 / 502,255,616
+        assert memory.temp_size_in_bytes < 4.02 * vector
         # the pair X @ [x | p]: ONE fusion with two results of a row's
         # length, fed by the table
+        hlo = compiled.as_text()
         pair = re.findall(
             r"= \(f32\[%d\]\S*, f32\[%d\]\S*\) fusion\(" % (
-                self.ROWS, self.ROWS), compiled.as_text())
+                self.ROWS, self.ROWS), hlo)
         assert len(pair) == 1
+        # the intercept is added inside the fusions that consume eta and
+        # u (the trials, the residual): no pass over a row vector of its
+        # own.  (Both forms have the first value_and_grad's product,
+        # which reads the TABLE and writes eta, and -y * mask, which
+        # reads two vectors.)
+        assert self._row_vector_passes(hlo) == []
+        if form == "scalar":
+            # and no table but the caller's: nothing 29 wide, nothing
+            # concatenated or padded at the table's size
+            assert "f32[%d,29]" % self.ROWS not in hlo
+            assert not re.search(
+                r"f32\[%d,\d+\]\S* (concatenate|pad)\(" % self.ROWS, hlo)
 
-    def test_black_box_program_is_the_parents(self, lower):
+    @pytest.mark.parametrize("form", list(FORMS))
+    def test_black_box_program_is_the_parents(self, lower, form):
         # what packed_solve's and lambda_sweep's lanes run, here without
         # the vmap: two row vectors of temporaries, as before ISSUE 29
-        compiled = lower(line_search="backtrack", objective="black_box")
+        compiled = lower(form, line_search="backtrack", objective="black_box")
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert 2 * self.ROWS * 4 < temp < 2.1 * self.ROWS * 4  # 252,606,464
 
