@@ -111,9 +111,9 @@ def shard_rows(
         pad = (-n) % n_shards
         if pad:
             x = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
-        mask_dev = (jnp.arange(n + pad) < n).astype(jnp.float32)
         data = jax.device_put(x, row_sharding(mesh, x.ndim))
-        mask = jax.device_put(mask_dev, row_sharding(mesh, 1))
+        mask = _row_mask(
+            n, padded=n + pad, sharding=row_sharding(mesh, 1))
         return ShardedRows(data=data, mask=mask, n_samples=n)
     x = np.asarray(x)
     if dtype is not None:
@@ -274,3 +274,14 @@ def masked_unique(data, mask, span=None) -> np.ndarray:
     # pad rows take a real value (the scan's first), so that they add
     # no value of their own to the sort
     return np.asarray(jnp.unique(jnp.where(mask > 0, data, found[0])))
+
+
+@partial(jax.jit, static_argnames=("padded", "sharding"))
+def _row_mask(n, *, padded, sharding):
+    """The mask of ``n`` real rows among ``padded``, born with the row
+    sharding: every device makes its own rows' part.  Made eagerly it
+    was an ``int32`` counter, a ``bool`` and a ``float32`` vector of ALL
+    the rows on the default device before the scatter: 2.25 GB beside
+    that chip's share of a 250M-row table (PERF.md section 6, PR 34)."""
+    return jax.lax.with_sharding_constraint(
+        (jnp.arange(padded) < n).astype(jnp.float32), sharding)

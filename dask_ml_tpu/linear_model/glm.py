@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..base import ClassifierMixin, RegressorMixin, TPUEstimator
+from ..core.mesh import data_axes_size
 from ..core.sharded import ShardedRows, masked_unique
 from ..preprocessing.data import _ingest_float
 from .. import obs as _obs
@@ -29,7 +30,7 @@ from ..solvers import (
     newton,
     proximal_grad,
 )
-from ..solvers.algorithms import COUNTED_SOLVERS, SOLVE_COUNTS
+from ..solvers.algorithms import COUNTED_SOLVERS, unpack_counts
 from .utils import binary_indicator
 
 _SOLVERS = {
@@ -43,20 +44,22 @@ _SOLVERS = {
 
 def _fetch_counts(runs):
     """The fit's one device-to-host transfer of its iteration counts:
-    ``(n_iter_, counts)``.  ``runs`` holds one entry per solver run, a
-    scalar iteration count or, from a counted solver, the
-    ``SOLVE_COUNTS`` vector; ``counts`` is what goes on the ``glm.solve``
-    span: all three counts of a single counted run, else the rounds."""
+    ``(n_iter_, counts, ratios)``.  ``runs`` holds one entry per solver
+    run, a scalar iteration count or, from a counted solver, the
+    ``SOLVE_COUNTS`` vector; ``counts`` and ``ratios`` are what goes on
+    the ``glm.solve`` span: all of a single counted run's vector
+    (``solvers.algorithms.unpack_counts``), else the rounds."""
     got = np.asarray(runs, dtype=np.int32)
     if got.ndim == 2:
-        return got[:, 0], dict(zip(SOLVE_COUNTS, got[0].tolist()))
-    return got, {"rounds": int(got.max())}
+        return (got[:, 0], *unpack_counts(got[0]))
+    return got, {"rounds": int(got.max())}, {}
 
 
-def _publish_counts(span, counts):
+def _publish_counts(span, counts, ratios):
     """A finished solve's counts: onto its span, and into the always-on
-    registry (``solve.count`` solves, and their summed counts)."""
-    span.set(**counts)
+    registry (``solve.count`` solves, and their summed counts).  Where
+    ADMM's consensus stopped (``ratios``) goes on the span alone."""
+    span.set(**counts, **ratios)
     reg = _obs.registry()
     reg.counter("solve.count").inc()
     for name, value in counts.items():
@@ -133,8 +136,10 @@ class _GLM(TPUEstimator):
     def _solve_span(self):
         """``glm.solve``: from the solver call to ``n_iter_`` on the host,
         so the wait for the device is inside it."""
-        return _obs.span("glm.solve", line_search=(
-            self.solver_kwargs or {}).get("line_search", "default"))
+        return _obs.span(
+            "glm.solve", shards=data_axes_size(),
+            line_search=(self.solver_kwargs or {}).get(
+                "line_search", "default"))
 
     def _run_solver(self, X, y, family, beta0, kwargs):
         """One whole-solve dispatch: ``(beta, n_it)``, both still on the
@@ -242,7 +247,8 @@ class _GLM(TPUEstimator):
         X = _ingest_float(self, X)
         self.n_features_in_ = X.data.shape[1]
         root.set(rows=X.n_samples, features=self.n_features_in_,
-                 chips=len(X.data.sharding.device_set), **root_attrs)
+                 chips=len(X.data.sharding.device_set),
+                 n_shards=data_axes_size(), **root_attrs)
         span.set(padded_rows=X.data.shape[0],
                  intercept="scalar" if self.fit_intercept else "none")
         return X, self.n_features_in_ + bool(self.fit_intercept)
@@ -268,8 +274,8 @@ class _GLM(TPUEstimator):
             # sklearn contract: iteration count(s) of the solver run(s);
             # converted only now, after the solve is dispatched (the wait
             # for the device is here, inside the span)
-            self.n_iter_, counts = _fetch_counts([n_it])
-            _publish_counts(span, counts)
+            self.n_iter_, *counts = _fetch_counts([n_it])
+            _publish_counts(span, *counts)
         if self.fit_intercept:
             self.coef_ = beta[:-1]
             self.intercept_ = float(beta[-1])
@@ -536,8 +542,8 @@ class LogisticRegression(ClassifierMixin, _GLM):
             # sklearn contract: one count per OvR solve — device scalars
             # are converted only here, after every class's solve has
             # dispatched (the wait for the device is here, in the span)
-            self.n_iter_, counts = _fetch_counts(n_iter_runs)
-            _publish_counts(span, counts)
+            self.n_iter_, *counts = _fetch_counts(n_iter_runs)
+            _publish_counts(span, *counts)
         if self.fit_intercept:
             self.coef_ = (
                 self.betas_[0, :-1] if len(self.classes_) == 2

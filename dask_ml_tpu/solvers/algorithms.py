@@ -128,14 +128,36 @@ def reset_dispatch_counts():
 
 #: what the counted runners (``_admm_run``, ``_lbfgs_run``) return in the
 #: place of a scalar iteration count, as one small int32 vector so that
-#: the host fetches it in the one transfer ``n_iter_`` already costs: the
-#: solver's own iterations (ADMM rounds; ``n_iter_``), the L-BFGS
-#: iterations inside them, ``LBFGSState.n_evals`` (the local solves'
-#: operations that stream the design matrix) and ``LBFGSState.n_trials``
-#: (the line search's trials on the cached linear predictor, which do not)
-SOLVE_COUNTS = ("rounds", "inner_iters", "passes", "trials")
+#: the host fetches it in the one transfer ``n_iter_`` already costs.
+#: The first four places are every counted solver's: the solver's own
+#: iterations (ADMM rounds; ``n_iter_``), the L-BFGS iterations inside
+#: them, ``LBFGSState.n_evals`` (the local solves' operations that stream
+#: the design matrix) and ``LBFGSState.n_trials`` (the line search's
+#: trials on the cached linear predictor, which do not).  The rest are
+#: ADMM's alone (``_lbfgs_run``'s vector ends after four): summed over
+#: the rounds, the evaluations and the trials the slowest shard's local
+#: solve made more than the fastest's (what the fastest chip sat out at
+#: the round's all-reduce; 0 on one shard), and the rounds that moved
+#: ``rho``
+SOLVE_COUNTS = ("rounds", "inner_iters", "passes", "trials",
+                "skew_passes", "skew_trials", "rho_moves")
+#: where ADMM's consensus stopped, behind its counts in the same vector
+#: as float32 BIT PATTERNS (so they cost no second transfer): the last
+#: round's residuals over their tolerances (under 1: that part of the
+#: stopping rule was met) and the final ``rho`` over the initial one
+SOLVE_RATIOS = ("primal_ratio", "dual_ratio", "rho_ratio")
 #: the solvers that take ``return_counts=True``
 COUNTED_SOLVERS = ("admm", "lbfgs")
+
+
+def unpack_counts(row):
+    """``(counts, ratios)`` of one counted run's vector, once it is on
+    the host: dicts by :data:`SOLVE_COUNTS` and :data:`SOLVE_RATIOS`,
+    each as long as the solver filled it."""
+    row = np.asarray(row, dtype=np.int32)
+    n = len(SOLVE_COUNTS)
+    return (dict(zip(SOLVE_COUNTS, row[:n].tolist())),
+            dict(zip(SOLVE_RATIOS, row[n:].view(np.float32).tolist())))
 
 
 def _iterations(n_it):
@@ -512,9 +534,13 @@ def _admm_run(x, yv, mask, lamduh, rho, abstol, reltol, inner_tol, max_it,
             primal_sq = lax.psum(jnp.sum((b_new - z_new) ** 2), row_ax)
             beta_norm_sq = lax.psum(jnp.sum(b_new ** 2), row_ax)
             u_norm_sq = lax.psum(jnp.sum(u_new ** 2), row_ax)
-            # the round lasts as long as its slowest shard's solve
-            work = lax.pmax(
-                jnp.stack([st.k, st.n_evals, st.n_trials]), row_ax)
+            # the round lasts as long as its slowest shard's solve; the
+            # negatives bring the fastest shard's counts in the same
+            # all-reduce, and the difference is what that shard sat out
+            both = lax.pmax(
+                jnp.stack([st.k, st.n_evals, st.n_trials,
+                           -st.n_evals, -st.n_trials]), row_ax)
+            work = jnp.concatenate([both[:3], both[1:3] + both[3:]])
         return (b_new[None], u_new[None], z_new, primal_sq, beta_norm_sq,
                 u_norm_sq, work)
 
@@ -604,7 +630,8 @@ def _admm_run(x, yv, mask, lamduh, rho, abstol, reltol, inner_tol, max_it,
             u_l = u_l * (rho_c / rho_new)
             rho_c = rho_new
         return (i + 1, beta_l, u_l, z, rho_c, primal, dual, eps_pri,
-                eps_dual, rho_moved, work + round_work)
+                eps_dual, rho_moved, work + jnp.concatenate(
+                    [round_work, rho_moved[None].astype(jnp.int32)]))
 
     inf = jnp.asarray(jnp.inf, _param_dtype(x))
     zero = jnp.asarray(0.0, _param_dtype(x))
@@ -617,9 +644,13 @@ def _admm_run(x, yv, mask, lamduh, rho, abstol, reltol, inner_tol, max_it,
     z0 = z_init.astype(_param_dtype(x))
     init = (jnp.int32(0), beta_l0, u_l0, z0,
             jnp.asarray(rho, _param_dtype(x)), inf, inf, zero, zero,
-            jnp.asarray(False), jnp.zeros(3, jnp.int32))
+            jnp.asarray(False), jnp.zeros(len(SOLVE_COUNTS) - 1, jnp.int32))
     final = lax.while_loop(cond, body, init)
-    return final[3], jnp.concatenate([final[0][None], final[-1]])
+    rounds, _, _, z, rho_c, primal, dual, eps_pri, eps_dual, _, work = final
+    ratios = jnp.stack([primal / eps_pri, dual / eps_dual, rho_c / rho])
+    return z, jnp.concatenate([
+        rounds[None], work,
+        lax.bitcast_convert_type(ratios.astype(jnp.float32), jnp.int32)])
 
 
 def admm(X, y, *, family: type[Family] = Logistic, regularizer=L2,
@@ -653,8 +684,10 @@ def admm(X, y, *, family: type[Family] = Logistic, regularizer=L2,
     ``admm-higgs`` cells run one each).
 
     ``return_counts=True`` returns ``(beta, counts)``, the device vector
-    :data:`SOLVE_COUNTS` lays out (per round the slowest shard's inner
-    iterations and evaluations, summed over the rounds).
+    :data:`SOLVE_COUNTS` and :data:`SOLVE_RATIOS` lay out and
+    :func:`unpack_counts` reads on the host (per round the slowest
+    shard's inner iterations and evaluations, and what the fastest made
+    fewer, summed over the rounds; then where the consensus stopped).
     """
     line_search = line_search_strategy(line_search)
     reg = get_regularizer(regularizer)

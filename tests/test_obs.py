@@ -810,7 +810,7 @@ class TestProfilerSessionArmsSpans:
         assert root["attrs"] == {
             "estimator": "LogisticRegression", "solver": "admm",
             "rows": X.shape[0], "features": X.shape[1], "chips": 8,
-            "classes": 2}
+            "n_shards": 8, "classes": 2}
         assert kids[0]["attrs"] == {"classes": 2}
         assert kids[1]["attrs"]["padded_rows"] >= X.shape[0]
 

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 pytest.importorskip("hypothesis")
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import assume, example, given, settings, strategies as st  # noqa: E402
 
 
 @st.composite
@@ -730,6 +730,7 @@ def test_hyperband_executes_its_own_metadata(R, eta, seed):
 
 @settings(max_examples=10, deadline=None)
 @given(st.integers(0, 2**31 - 1), st.integers(1, 4), st.integers(2, 6))
+@example(seed=169, n_blocks=1, k=5)  # 9 rows, 10 columns: assumed away
 def test_truncated_svd_streamed_matches_dense(seed, n_blocks, k):
     """fit_streamed (multi-pass randomized range finder over a sparse
     block stream) must agree with the dense TSQR fit on singular values
@@ -741,6 +742,11 @@ def test_truncated_svd_streamed_matches_dense(seed, n_blocks, k):
     rng_l = np.random.RandomState(seed % (2**31 - 1))
     d = k + rng_l.randint(2, 6)
     n = n_blocks * rng_l.randint(8, 20)
+    # the dense arm is TSQR, which needs rows >= columns: (9, 10) at
+    # seed=169, n_blocks=1, k=5 raised there, on the runs in which the
+    # draws reached it (twice in two days: which examples a derandomized
+    # run draws moves with the modules its worker has imported)
+    assume(n >= d)
     X = rng_l.normal(size=(n, d)).astype(np.float32)
     X[rng_l.rand(n, d) < 0.5] = 0.0  # sparse-ish
     bounds = np.linspace(0, n, n_blocks + 1, dtype=int)

@@ -367,10 +367,12 @@ class TestSolveCounts:
         X, y, _ = logistic_data
         kw = dict(family=Logistic, lamduh=1.0, line_search=line_search)
         beta, counts = solvers.admm(X, y, return_counts=True, **kw)
-        rounds, inner, passes, trials = (int(c) for c in counts)
-        assert solvers.algorithms.SOLVE_COUNTS == (
+        rounds, inner, passes, trials = (int(c) for c in counts[:4])
+        # the places every counted solver fills; ADMM's own come after
+        # (tests/test_admm_consensus.py)
+        assert solvers.algorithms.SOLVE_COUNTS[:4] == (
             "rounds", "inner_iters", "passes", "trials")
-        assert counts.dtype == jnp.int32 and counts.shape == (4,)
+        assert counts.dtype == jnp.int32 and counts.shape == (10,)
         # every round reads X once at its start (a value_and_grad) and
         # twice an inner iteration (the product, the gradient); every
         # iteration tries at least its unit step
@@ -919,6 +921,77 @@ class TestCompiledForTheChip:
         compiled = lower(form, line_search="backtrack", objective="black_box")
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert 2 * self.ROWS * 4 < temp < 2.1 * self.ROWS * 4  # 252,606,464
+
+
+class TestConsensusCompiledForFourChips:
+    """ISSUE 34, at the benchmark's size (250,000,000 x 28 over the four
+    chips of a described v5e host, 62,500,000 rows a chip: the cell
+    ``admm-higgs-250m.fit-4chip``): what the chip's compiler makes of the
+    whole-solve program once the consensus has four members.  The cell's
+    memory claim rests on temporaries ``peak_hbm_gib`` cannot see, and
+    its guarantee on nothing of a row's size crossing chips.  Compiled,
+    never run.  (In this file because one process at a time may load the
+    TPU's library: ``v5e_devices``.)"""
+
+    CHIPS, ROWS, D = 4, 250_000_000, 28
+
+    @pytest.mark.parametrize("line_search", ["backtrack", "probe_grid"])
+    def test_a_chips_share_fits_and_only_small_all_reduces_cross(
+            self, v5e_devices, line_search):
+        import re
+
+        import jax
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        from dask_ml_tpu.core.mesh import MeshHolder
+        from dask_ml_tpu.solvers.algorithms import SOLVE_COUNTS, _admm_run
+
+        mesh = Mesh(np.array(v5e_devices[:self.CHIPS]).reshape(
+            self.CHIPS, 1), ("data", "model"))
+
+        def S(shape, spec, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(
+                shape, dtype, sharding=NamedSharding(mesh, spec))
+
+        row = S((self.ROWS,), P("data"))
+        args = (S((self.ROWS, self.D), P("data", None)), row, row,
+                *[S((), P())] * 5, S((), P(), jnp.int32),
+                S((self.D + 1,), P()))
+        with _compile_cache_off():
+            compiled = _admm_run.lower(
+                *args, family=Logistic, reg=L2,
+                mesh_holder=MeshHolder(mesh), inner_iter=30,
+                line_search=line_search, objective="linear").compile()
+        memory = compiled.memory_analysis()
+        share = self.ROWS // self.CHIPS
+        # a chip's arguments: its rows of the table on 32 sublanes
+        # (8.0 GB), targets and mask (0.25 GB each): 8,500,013,184 B
+        assert 8.0e9 < memory.argument_size_in_bytes < 8.52e9
+        # and its temporaries: eta, u, the residual and -y * mask, four
+        # vectors of ITS rows as on one chip, and half a vector more
+        # (1,127,375,872 / 1,127,473,152 B): partitioned over four chips
+        # the program carries a bf16[62500000] ``-y`` through the outer
+        # loop that no instruction reads (PERF.md section 6, PR 34).
+        # Table and temporaries leave 6.7 GB of the chip free
+        assert 3.9 * share * 4 < memory.temp_size_in_bytes < 4.55 * share * 4
+        hlo = compiled.as_text()
+        assert not re.search(r"all-gather|all-to-all|collective-permute",
+                             hlo)
+        # every collective is an all-reduce, and the largest operand of
+        # any is the 29 parameters or the five counts of the round's
+        # work: nothing of a row's size ever crosses chips
+        crossing = re.findall(
+            r"= (\(?[a-z0-9]+\[[0-9,]*\][^=\n]*?) all-reduce(?:-start)?\(",
+            hlo)
+        assert crossing
+        for shapes in crossing:
+            for shape in re.findall(r"[a-z0-9]+\[([0-9,]*)\]", shapes):
+                dims = [int(n) for n in shape.split(",") if n]
+                assert int(np.prod(dims or [1])) <= self.D + 1, shapes
+        # the slowest and the fastest shard's counts ride ONE all-reduce
+        # (a max over s32[5]), where the slowest's alone rode before
+        assert len(re.findall(r"s32\[5\]\S* all-reduce(?:-start)?\(", hlo)) == 1
+        assert len(SOLVE_COUNTS) == 7
 
 
 class TestKMeansInitCompiledForTheChip:
