@@ -133,14 +133,23 @@ def reset_dispatch_counts():
 #: iterations (ADMM rounds; ``n_iter_``), the L-BFGS iterations inside
 #: them, ``LBFGSState.n_evals`` (the local solves' operations that stream
 #: the design matrix) and ``LBFGSState.n_trials`` (the line search's
-#: trials on the cached linear predictor, which do not).  The rest are
-#: ADMM's alone (``_lbfgs_run``'s vector ends after four): summed over
-#: the rounds, the evaluations and the trials the slowest shard's local
-#: solve made more than the fastest's (what the fastest chip sat out at
-#: the round's all-reduce; 0 on one shard), and the rounds that moved
-#: ``rho``
+#: trials on the cached linear predictor, which do not: each a reduction
+#: over vectors of a row's length, for a value of ``phi``, its slope or,
+#: once a history-less search, the curvature at the start of the line).
+#: The rest are ADMM's alone (``_lbfgs_run``'s vector ends after four):
+#: summed over the rounds, the evaluations and the trials the slowest
+#: shard's local solve made more than the fastest's (what the fastest
+#: chip sat out at the round's all-reduce; 0 on one shard), the rounds
+#: that moved ``rho``, and ``LBFGSState.n_guided``: those of ``trials``
+#: that the searches with no history took, which start from the
+#: curvature's guess and not from the unit step (a round's first, under
+#: ``backtrack``; the largest over the shards; 0 under ``probe_grid`` and
+#: for a black box).  Three trials a search (the curvature, the first
+#: look, and the slope at the unit step or the look above that Armijo
+#: refuses) is a guess that stood on the answer; more is the walk from
+#: the guess to the answer
 SOLVE_COUNTS = ("rounds", "inner_iters", "passes", "trials",
-                "skew_passes", "skew_trials", "rho_moves")
+                "skew_passes", "skew_trials", "rho_moves", "guided_trials")
 #: where ADMM's consensus stopped, behind its counts in the same vector
 #: as float32 BIT PATTERNS (so they cost no second transfer): the last
 #: round's residuals over their tolerances (under 1: that part of the
@@ -537,10 +546,12 @@ def _admm_run(x, yv, mask, lamduh, rho, abstol, reltol, inner_tol, max_it,
             # the round lasts as long as its slowest shard's solve; the
             # negatives bring the fastest shard's counts in the same
             # all-reduce, and the difference is what that shard sat out
+            # (and the guided searches' trials ride along)
             both = lax.pmax(
                 jnp.stack([st.k, st.n_evals, st.n_trials,
-                           -st.n_evals, -st.n_trials]), row_ax)
-            work = jnp.concatenate([both[:3], both[1:3] + both[3:]])
+                           -st.n_evals, -st.n_trials, st.n_guided]), row_ax)
+            work = jnp.concatenate(
+                [both[:3], both[1:3] + both[3:5], both[5:]])
         return (b_new[None], u_new[None], z_new, primal_sq, beta_norm_sq,
                 u_norm_sq, work)
 
@@ -631,7 +642,10 @@ def _admm_run(x, yv, mask, lamduh, rho, abstol, reltol, inner_tol, max_it,
             rho_c = rho_new
         return (i + 1, beta_l, u_l, z, rho_c, primal, dual, eps_pri,
                 eps_dual, rho_moved, work + jnp.concatenate(
-                    [round_work, rho_moved[None].astype(jnp.int32)]))
+                    # SOLVE_COUNTS' order: rho_moves stands before the
+                    # guided searches' trials, the round's last count
+                    [round_work[:5], rho_moved[None].astype(jnp.int32),
+                     round_work[5:]]))
 
     inf = jnp.asarray(jnp.inf, _param_dtype(x))
     zero = jnp.asarray(0.0, _param_dtype(x))

@@ -17,6 +17,14 @@ piece of code over a scalar function ``phi(t)`` of the step:
   one-vs-rest, model cohorts): classic backtrack-then-expand while_loops.
   Under vmap lanes run in lockstep (masked) at the max lane's probe
   count; a ``lax.cond`` grid would execute both branches in every lane.
+  It accepts the largest step ``2^-k <= 1`` that passes Armijo, doubled
+  while the curvature test fails and Armijo holds above, and takes no
+  trial whose outcome it already knows (:func:`_backtrack_wolfe`): no
+  curvature test after a halving, the slope before the value at 2t,
+  and, where an iteration has no history and the objective is a
+  :class:`LinearObjective`, a first look at the power of two the
+  curvature along the line points to (:func:`_start_exponent`) in the
+  place of some twenty halvings from 1.
 * ``probe_grid`` (opt-in for sequential solves): probe the unit step,
   else evaluate EVERY candidate step 2^k in one vmapped call of ``phi``.
 
@@ -39,6 +47,8 @@ and 6 (the ``admm-higgs`` cells run the two strategies).
 from __future__ import annotations
 
 from typing import Callable, NamedTuple
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -96,10 +106,17 @@ class LBFGSState(NamedTuple):
     # LinearObjective: the first value_and_grad, then the product and the
     # transposed product of each iteration (1 + 2 k), never a trial
     n_evals: jax.Array
-    # a LinearObjective's trials: values of phi taken from the cached
-    # images, a batched grid counting once; 0 for a black box, whose
-    # trials are in n_evals
+    # a LinearObjective's trials: reductions over the cached images that
+    # the searches took (a value of phi, its slope, or the curvature at
+    # the start of the line; a batched grid counting once); 0 for a black
+    # box, whose trials are in n_evals
     n_trials: jax.Array
+    # those of n_trials taken in searches that started from the
+    # curvature's guess (iterations with no history, under backtrack):
+    # the curvature's reduction, the first look, every halving or
+    # doubling, the curvature test where the walk up reached 1.  How far
+    # the guess stood from the answer
+    n_guided: jax.Array
 
 
 def _two_loop(g, S, Y, rho, n_updates, m):
@@ -152,8 +169,11 @@ def _cached_phi(obj: LinearObjective, x, p):
     made inside each trial, never a pass over ``eta`` or ``u``).
     Also returns ``gradient_at(t)``, the objective's gradient at
     ``x + t p`` by one transposed product of the pointwise derivative at
-    that image.  The images live for one search: none is carried
-    between iterations, so none drifts from its product."""
+    that image, and ``curvature()``, ``phi''(0)``: the derivative of the
+    slope at the start of the line, one more reduction over the cached
+    images (what a trial reads, less the targets where the pointwise
+    loss is linear in them).  The images live for one search: none is
+    carried between iterations, so none drifts from its product."""
     eta, u = obj.predict(x, p)
     if obj.offset is None:
         def offset_at(t):
@@ -191,72 +211,151 @@ def _cached_phi(obj: LinearObjective, x, p):
         (g,) = jax.linear_transpose(obj.image, x)(r)
         return g + jax.grad(obj.smooth)(x + t * p)
 
-    return phi, gradient_at
+    def curvature():
+        zero = jnp.zeros((), eta.dtype)
+        return jax.jvp(lambda t: phi(t)[1], (zero,), (jnp.ones_like(zero),))[1]
+
+    return phi, gradient_at, curvature
 
 
-def _backtrack_wolfe(phi, f0, dg, c1, c2, max_backtracks):
-    """Sequential weak-Wolfe search: Armijo backtracking, then step
-    expansion while the curvature condition phi'(t) ≥ c2·phi'(0) fails but
-    Armijo still holds at 2t.  Guarantees useful s·y on accepted steps so
-    the L-BFGS history builds even in curved nonconvex valleys.
+def _powers_of_two(n, dtype):
+    """``[1, 1/2, ..., 2^-n]``, made on the host so that every entry is
+    the power of two to the last bit (``exp2`` on the device is not:
+    it read 3.8146970e-06 for 2^-18, and a step that is off in its last
+    digits moves every later step of the solve)."""
+    return jnp.asarray(np.ldexp(1.0, -np.arange(n + 1)), dtype)
+
+
+def _start_exponent(curvature, dg, c1, max_backtracks):
+    """``k`` of the step ``2^-k`` a history-less search starts from: the
+    largest ``2^-k <= 1`` that passes Armijo on the parabola through
+    ``phi(0)`` with slope ``dg`` and second derivative ``curvature``
+    (``t <= 2 (1 - c1) (-dg) / curvature``), no smaller than the floor
+    ``2^-max_backtracks``.  A curvature that is not a positive number
+    gives 0, the unit step: the search as it is without a guess."""
+    bound = 2.0 * (1.0 - c1) * (-dg) / curvature
+    # how many of 1, 1/2, ... stand over the bound (none where it is no
+    # number): the first that does not is the start
+    k = jnp.sum(_powers_of_two(max_backtracks, bound.dtype) > bound)
+    usable = jnp.isfinite(curvature) & (curvature > 0)
+    return jnp.where(usable, jnp.minimum(k, max_backtracks), 0)
+
+
+def _backtrack_wolfe(phi, f0, dg, c1, c2, max_backtracks, start=None):
+    """Sequential weak-Wolfe search: the largest step ``2^-k <= 1`` that
+    passes Armijo, then doublings while the curvature condition
+    phi'(t) ≥ c2·phi'(0) fails but Armijo still holds at 2t.  Guarantees
+    useful s·y on accepted steps so the L-BFGS history builds even in
+    curved nonconvex valleys.
+
+    It takes no trial whose outcome it knows.  The first look is at
+    ``2^-start`` (``start``: a traced exponent from
+    :func:`_start_exponent`; None, STATIC, is the unit step and leaves
+    the walk up out of the program).  Where Armijo fails there the step
+    halves until it holds, down to the floor ``2^-max_backtracks``;
+    where it holds under 1 the step doubles while Armijo holds at 2t, up
+    to 1.  Along a convex ``phi`` the steps that pass Armijo are an
+    interval from 0, so either walk ends on the step a search from 1
+    ends on.  After a halving, or a doubling that was refused, 2t has
+    failed Armijo in this very search: no expansion can follow, so the
+    curvature is not tested.  Where nothing is known above ``t`` (the
+    unit step accepted at once, or a walk up that reached it) the slope
+    at ``t`` comes first, the value at 2t only where the curvature test
+    fails, and a step the expansion moves to keeps the value just taken.
 
     The strategy for VMAPPED contexts (packed one-vs-rest, model
     cohorts): a ``lax.cond`` grid under vmap executes both branches in
     every lane, so probe_grid would pay the full grid per lane per
     iteration; these while_loops run lanes in lockstep (masked) at the
-    max lane's probe count, which measures far cheaper for packed solves.
+    max lane's probe count, which measures far cheaper for packed solves
+    (there the expansion's ``cond`` is a select and every test takes the
+    value with the slope, as every test did before).
     """
     value = lambda t: phi(t)[0]  # noqa: E731
     slope = lambda t: phi(t)[1]  # noqa: E731
+    armijo = lambda t, f: f <= f0 + c1 * t * dg  # noqa: E731
+
+    first = 0 if start is None else start
+    t0 = _powers_of_two(max_backtracks, f0.dtype)[first]
+    halvings = max_backtracks - first  # what is left down to the floor
 
     def bt_cond(carry):
         t, f_new, j = carry
-        armijo = f_new <= f0 + c1 * t * dg
-        return jnp.logical_not(armijo) & (j < max_backtracks)
+        return jnp.logical_not(armijo(t, f_new)) & (j < halvings)
 
     def bt_body(carry):
         t, _, j = carry
         t = 0.5 * t
         return t, value(t), j + 1
 
-    t0 = jnp.asarray(1.0, dtype=f0.dtype)
-    t, f_new, j = lax.while_loop(bt_cond, bt_body, (t0, value(t0), 0))
-    n_calls = 1 + j  # the unit step, then one value a backtrack
-    failed = (j >= max_backtracks) & (f_new > f0 + c1 * t * dg)
+    f_first = value(t0)
+    t, f_new, j = lax.while_loop(bt_cond, bt_body, (t0, f_first, 0))
+    n_calls = 1 + j  # the first look, then one value a backtrack
+    # (not "Armijo fails": a value that is no number at the floor is
+    # handed back as it always was)
+    failed = (j >= halvings) & (f_new > f0 + c1 * t * dg)
     t = jnp.where(failed, 0.0, t)
     f_new = jnp.where(failed, f0, f_new)
+    fails_above = j > 0  # 2t is the step the last halving left
+
+    if start is not None:
+
+        climbs = armijo(t0, f_first)  # so no halving was taken
+
+        def up_cond(carry):
+            t, _, _, refused = carry
+            return climbs & jnp.logical_not(refused) & (t < 1)
+
+        def up_body(carry):
+            t, f_t, j, _ = carry
+            t2 = 2.0 * t
+            f_t2 = value(t2)
+            holds = armijo(t2, f_t2)
+            return (jnp.where(holds, t2, t), jnp.where(holds, f_t2, f_t),
+                    j + 1, jnp.logical_not(holds))
+
+        t, f_new, j_up, refused = lax.while_loop(
+            up_cond, up_body, (t, f_new, 0, jnp.asarray(False)))
+        n_calls = n_calls + j_up  # one value a look above
+        fails_above = fails_above | refused
 
     if c2 is not None:  # static: Armijo-only callers skip the expansion
 
-        def ex_cond(carry):
-            t, f_t, j = carry
+        def ex_body(carry):
+            t, f_t, j, n, _ = carry
             curv_ok = slope(t) >= c2 * dg
             t2 = 2.0 * t
-            armijo2 = value(t2) <= f0 + c1 * t2 * dg
-            return jnp.logical_not(curv_ok) & armijo2 & (j < 8) & (t > 0)
 
-        def ex_body(carry):
-            t, _, j = carry
-            t = 2.0 * t
-            return t, value(t), j + 1
+            def look_above():
+                f_t2 = value(t2)
+                return f_t2, armijo(t2, f_t2)
 
-        t, f_new, j_ex = lax.while_loop(ex_cond, ex_body, (t, f_new, 0))
-        # every test of the condition (one more than the expansions
-        # taken) takes the slope at t and the value at 2t; every
-        # expansion takes the value once more
-        n_calls = n_calls + 2 * (j_ex + 1) + j_ex
+            f_t2, armijo2 = lax.cond(
+                curv_ok, lambda: (f_t, jnp.asarray(False)), look_above)
+            j = j + armijo2
+            # the slope at t, and the value at 2t where the curvature
+            # test failed; the step moved to brings that value along
+            return (jnp.where(armijo2, t2, t), jnp.where(armijo2, f_t2, f_t),
+                    j, n + jnp.where(curv_ok, 1, 2), armijo2 & (j < 8))
+
+        t, f_new, _, n_expand, _ = lax.while_loop(
+            lambda carry: carry[-1], ex_body,
+            (t, f_new, 0, 0, jnp.logical_not(fails_above) & (t > 0)))
+        n_calls = n_calls + n_expand
     return t, f_new, None, failed, n_calls
 
 
-def _search(strategy, phi, f0, dg, c1, c2, max_backtracks):
+def _search(strategy, phi, f0, dg, c1, c2, max_backtracks, start=None):
     """The search's decisions over ``phi(t) -> (value, slope, aux)``,
-    whichever way ``phi`` is made; ``dg`` is the slope at 0.  Returns
+    whichever way ``phi`` is made; ``dg`` is the slope at 0, ``start``
+    the exponent ``backtrack`` takes its first look at
+    (:func:`_start_exponent`; ``probe_grid`` takes none).  Returns
     ``(t, f_new, aux_or_None, failed, n_calls)``: ``probe_grid`` hands
     back the ``aux`` of the accepted step (a black box's gradient there),
     ``backtrack`` None; ``n_calls`` counts the calls of ``phi``, a
     batched grid counting once."""
     if strategy == "backtrack":
-        return _backtrack_wolfe(phi, f0, dg, c1, c2, max_backtracks)
+        return _backtrack_wolfe(phi, f0, dg, c1, c2, max_backtracks, start)
     if strategy == "probe_grid":
         return _grid_line_search(phi, f0, dg, c1, c2, max_backtracks)
     raise ValueError(
@@ -397,6 +496,7 @@ def lbfgs_minimize(
         converged=jnp.max(jnp.abs(g0)) <= tol,
         n_evals=jnp.asarray(1),
         n_trials=jnp.asarray(0),
+        n_guided=jnp.asarray(0),
     )
 
     def cond(st: LBFGSState):
@@ -412,10 +512,26 @@ def lbfgs_minimize(
             p = jnp.where(descent, p, -st.g)
         with jax.named_scope("lbfgs.line_search"):
             if linear:  # static per kind of objective
-                phi, gradient_at = _cached_phi(fun, st.x, p)
+                phi, gradient_at, curvature = _cached_phi(fun, st.x, p)
+                dg = jnp.dot(st.g, p)
+                start, guided = None, jnp.asarray(False)
+                if line_search == "backtrack":
+                    # no history: p = -g of a loss SUMMED over the rows,
+                    # and the step that fits is some 4 / rows, twenty
+                    # halvings under 1.  Here the curvature along the
+                    # line costs one trial and says where to look first;
+                    # a black box's would cost two reads of the data
+                    guided = st.n_updates == 0
+                    start = lax.cond(
+                        guided,
+                        lambda: _start_exponent(
+                            curvature(), dg, c1, max_backtracks),
+                        lambda: jnp.asarray(0))
                 t, f_new, _, failed, n_trials = _search(
-                    line_search, phi, st.f, jnp.dot(st.g, p), c1, _C2,
-                    max_backtracks)
+                    line_search, phi, st.f, dg, c1, _C2, max_backtracks,
+                    start)
+                n_trials = n_trials + guided  # the curvature's reduction
+                n_guided = jnp.where(guided, n_trials, 0)
                 x_new = st.x + t * p
                 g_new = gradient_at(t)
                 n_evals = 2  # the product and the transposed product
@@ -424,7 +540,7 @@ def lbfgs_minimize(
                     line_search, value_and_grad, st.x, st.f, st.g, p, c1,
                     max_backtracks,
                 )
-                n_trials = 0
+                n_trials = n_guided = 0
                 x_new = st.x + t * p
                 if g_ls is None:  # static per strategy: backtrack re-evaluates
                     f_new, g_new = value_and_grad(x_new)
@@ -457,6 +573,7 @@ def lbfgs_minimize(
                 k=st.k + 1, n_updates=n_updates, converged=converged,
                 n_evals=st.n_evals + n_evals,
                 n_trials=st.n_trials + n_trials,
+                n_guided=st.n_guided + n_guided,
             )
 
     final = lax.while_loop(cond, body, init)

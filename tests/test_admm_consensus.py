@@ -157,6 +157,13 @@ def test_skew_is_the_slowest_shards_count_less_the_fastest(line_search):
         assert four[count] == max(each)
         assert four[count] - four[skew] == min(each)
     assert four["rounds"] == 1 and four["rho_moves"] in (0, 1)
+    # the round's first search has no history: under backtrack it starts
+    # from the curvature's guess, and the most trials any shard took in
+    # it ride out in the same vector (ISSUE 35); probe_grid guesses none
+    guided = [a["guided_trials"] for a in alone]
+    assert four["guided_trials"] == max(guided)
+    assert (min(guided) >= 3 if line_search == "backtrack"
+            else max(guided) == 0)
     # one round from a cold start has met nothing yet
     assert set(ratios) == set(SOLVE_RATIOS)
     assert all(np.isfinite(v) and v > 0 for v in ratios.values())
@@ -200,7 +207,8 @@ def test_glm_solve_span_and_registry_carry_the_consensus():
     assert set(SOLVE_COUNTS) | set(SOLVE_RATIOS) <= set(solve)
     assert solve["skew_passes"] > 0 and solve["skew_trials"] > 0
     after = diagnostics.run_report()["metrics"]["counters"]
-    for name in ("skew_passes", "skew_trials", "rho_moves"):
+    assert 3 * solve["rounds"] <= solve["guided_trials"] <= solve["trials"]
+    for name in ("skew_passes", "skew_trials", "rho_moves", "guided_trials"):
         assert after["solve." + name] - before.get(
             "solve." + name, 0) == solve[name]
     assert not any(r in k for k in after for r in SOLVE_RATIOS)

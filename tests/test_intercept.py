@@ -223,7 +223,12 @@ class TestSolverParity:
         rng = np.random.default_rng(2)
         x, p = (jnp.asarray(0.3 * rng.normal(size=6 * k), jnp.float32)
                 for _ in range(2))
-        phi, gradient_at = _cached_phi(linear, x, p)
+        phi, gradient_at, curvature = _cached_phi(linear, x, p)
+        # phi''(0), where a history-less search looks first (ISSUE 35),
+        # with the offset's share: p' H p of the black box
+        hvp = jax.jvp(lambda b: black_box(b)[1], (x,), (p,))[1]
+        np.testing.assert_allclose(
+            float(curvature()), float(jnp.dot(p, hvp)), rtol=2e-4)
         for t in (0.0, 0.125, 1.0, 2.0):
             t = jnp.float32(t)
             f, slope, aux = phi(t)

@@ -334,8 +334,9 @@ class TestSolveCounts:
     @pytest.mark.parametrize("diag", [[0.6, 0.8, 1.0], [0.5, 0.75, 1.0, 0.9]])
     @pytest.mark.parametrize("line_search,per_iter", [
         # the unit step's objective, the curvature test's gradient at t
-        # and objective at 2t, then value_and_grad at the accepted point
-        ("backtrack", 4),
+        # (it holds: no objective at 2t, ISSUE 35), then value_and_grad
+        # at the accepted point
+        ("backtrack", 3),
         # the unit probe evaluates value and gradient at once
         ("probe_grid", 1),
     ])
@@ -356,7 +357,7 @@ class TestSolveCounts:
         A = jnp.diag(jnp.asarray([1.0, 100.0], jnp.float32))
         f = lambda x: 0.5 * x @ A @ x  # noqa: E731
         _, bt = lbfgs_minimize(f, jnp.ones(2), tol=1e-6)
-        assert int(bt.n_evals) > 1 + 4 * int(bt.k)
+        assert int(bt.n_evals) > 1 + 3 * int(bt.k)
         _, grid = lbfgs_minimize(f, jnp.ones(2), tol=1e-6,
                                  line_search="probe_grid")
         k = int(grid.k)
@@ -372,12 +373,19 @@ class TestSolveCounts:
         # (tests/test_admm_consensus.py)
         assert solvers.algorithms.SOLVE_COUNTS[:4] == (
             "rounds", "inner_iters", "passes", "trials")
-        assert counts.dtype == jnp.int32 and counts.shape == (10,)
+        assert counts.dtype == jnp.int32 and counts.shape == (11,)
         # every round reads X once at its start (a value_and_grad) and
         # twice an inner iteration (the product, the gradient); every
         # iteration tries at least its unit step
         assert rounds >= 1 and passes == rounds + 2 * inner
         assert trials >= inner
+        # the searches with no history (a round's first) start from the
+        # curvature's guess under backtrack: its reduction, a look, and
+        # a look above or the slope; probe_grid guesses nothing
+        guided = int(counts[solvers.algorithms.SOLVE_COUNTS.index(
+            "guided_trials")])
+        assert (3 * rounds <= guided <= trials
+                if line_search == "backtrack" else guided == 0)
         beta2, n_it = solvers.admm(X, y, return_n_iter=True, **kw)
         assert int(n_it) == rounds  # the scalar contract is unchanged
         np.testing.assert_array_equal(np.asarray(beta), np.asarray(beta2))
@@ -393,9 +401,10 @@ class TestSolveCounts:
         _, n_it = solvers.lbfgs(X, y, lamduh=1.0, return_n_iter=True,
                                 line_search="backtrack")
         assert rounds == inner == int(n_it) and passes == 1 + 2 * inner
-        # backtrack: the unit step's value, then the curvature test's
-        # slope at t and value at 2t, at least
-        assert trials >= 3 * inner
+        # backtrack: the first look's value, then a halving's value or
+        # the slope where no halving was needed, at least; and once, with
+        # no history yet, the curvature that said where to look first
+        assert trials >= 2 * inner + 1
 
     @pytest.mark.parametrize("solver", ["admm", "lbfgs"])
     @pytest.mark.parametrize("strategy", ["packed", "sequential"])
@@ -577,7 +586,7 @@ class TestLinearObjective:
         x = jnp.asarray(0.3 * rng.normal(size=d), jnp.float32)
         p = jnp.asarray(0.3 * rng.normal(size=d), jnp.float32)
         vg = jax.value_and_grad(black_box)
-        phi, gradient_at = _cached_phi(linear, x, p)
+        phi, gradient_at, curvature = _cached_phi(linear, x, p)
         reference = _black_box_phi(vg, x, p)
         for t in (0.0, 0.125, 1.0, 2.0):
             t = jnp.float32(t)
@@ -592,6 +601,10 @@ class TestLinearObjective:
             np.testing.assert_allclose(
                 np.asarray(gradient_at(t)), np.asarray(g_ref),
                 rtol=2e-4, atol=2e-4)
+        # the curvature at the start of the line: p' H p of the black box
+        hvp = jax.jvp(jax.grad(black_box), (x,), (p,))[1]
+        np.testing.assert_allclose(
+            float(curvature()), float(jnp.dot(p, hvp)), rtol=2e-4)
         # called as a function, the structured objective is the black box
         np.testing.assert_allclose(
             float(linear(x)), float(black_box(x)), rtol=2e-6)
@@ -621,7 +634,38 @@ class TestLinearObjective:
         # the trials are the black box's evaluations inside the searches:
         # all but the first and, under backtrack, the one after each search
         extra = k if line_search == "backtrack" else 0
-        assert int(lin.n_trials) == int(bb.n_evals) - 1 - extra
+        unguided = guided = int(lin.n_guided)
+        if line_search == "backtrack":
+            # ... but for the one search with no history, the first: the
+            # black box walks down from 1, the cached predictor starts
+            # from the curvature's guess.  A search from 1 on the same
+            # cached line takes what the black box took
+            assert guided >= 3
+            _, unguided = self._first_search(linear, x0, start=None)
+        assert int(lin.n_trials) - guided + unguided == (
+            int(bb.n_evals) - 1 - extra)
+
+    @staticmethod
+    def _first_search(linear, x0, start="guess"):
+        """``(accepted step, trials)`` of the search a solve of
+        ``linear`` from ``x0`` makes first, along ``-g``: from the
+        exponent ``start``, from the curvature's guess, or (None) from
+        the unit step as every search went before ISSUE 35."""
+        import jax
+
+        from dask_ml_tpu.solvers.lbfgs_core import (
+            _C2, _cached_phi, _search, _start_exponent)
+
+        f0, g = jax.value_and_grad(linear)(x0)
+        phi, _, curvature = _cached_phi(linear, x0, -g)
+        dg = -jnp.dot(g, g)
+        if start == "guess":
+            start = _start_exponent(curvature(), dg, 1e-4, 30)
+        t, _, _, failed, n = _search(
+            "backtrack", phi, f0, dg, 1e-4, _C2, 30,
+            None if start is None else jnp.asarray(start))
+        assert not bool(failed)
+        return float(t), int(n)
 
     @staticmethod
     def _quadratic(diag):
@@ -635,29 +679,33 @@ class TestLinearObjective:
             pointwise=lambda eta: 0.5 * jnp.sum(eta ** 2),
             smooth=lambda b: jnp.float32(0.0))
 
-    @pytest.mark.parametrize("line_search,per_iter", [
+    @pytest.mark.parametrize("line_search,per_iter,once", [
         # the unit step's value, then the curvature test's slope at t
-        # and value at 2t
-        ("backtrack", 3),
+        # (it holds: no value at 2t); once, with no history, the
+        # curvature that said "start at 1"
+        ("backtrack", 2, 1),
         # the unit probe gives value and slope at once
-        ("probe_grid", 1),
+        ("probe_grid", 1, 0),
     ])
     def test_counts_by_hand_when_every_unit_step_is_accepted(
-            self, line_search, per_iter):
+            self, line_search, per_iter, once):
         x, st = lbfgs_minimize(self._quadratic([0.6, 0.8, 1.0]),
                                jnp.ones(3), tol=1e-6,
                                line_search=line_search)
         k = int(st.k)
         assert k >= 4 and bool(st.converged)
         assert int(st.n_evals) == 1 + 2 * k
-        assert int(st.n_trials) == per_iter * k
+        assert int(st.n_trials) == per_iter * k + once
+        # the guided search: curvature, the unit step's value, its slope
+        assert int(st.n_guided) == 3 * once
 
     @pytest.mark.parametrize("line_search,trials", [
         # from (1, 1) along -g = -(1, 100): Armijo fails at t = 1, 1/2,
-        # ..., 1/32 and holds at 1/64 (seven values), where the slope is
-        # positive, so the one curvature test (slope at t, value at 2t)
-        # ends the search
-        ("backtrack", 9),
+        # ..., 1/32 and holds at 1/64: seven values and a curvature test
+        # that could move nothing before ISSUE 35 (9 trials).  The line
+        # is a parabola, so its curvature puts the first look ON 1/64;
+        # one look above, at 1/32, and the search is over
+        ("backtrack", 3),
         # the unit probe fails, then the grid: one batched call
         ("probe_grid", 2),
     ])
@@ -668,6 +716,7 @@ class TestLinearObjective:
                                line_search=line_search)
         assert int(st.k) == 1
         assert int(st.n_evals) == 3 and int(st.n_trials) == trials
+        assert np.asarray(st.x).tolist() == [1 - 1 / 64, 1 - 100 / 64]
 
     @pytest.mark.parametrize("solver", ["admm", "lbfgs"])
     def test_a_family_with_loss_alone_solves_on_the_black_box(
@@ -713,15 +762,21 @@ class TestLinearObjective:
         a = solve["attrs"]
         assert set(solvers.algorithms.SOLVE_COUNTS) <= set(a)
         assert a["passes"] == a["rounds"] + 2 * a["inner_iters"]
-        assert a["trials"] >= 3 * a["inner_iters"]
+        # a value and a slope or a halving a search; the curvature too
+        # in the search that opens a round
+        assert a["trials"] >= 2 * a["inner_iters"] + a["rounds"]
+        assert 3 * a["rounds"] <= a["guided_trials"] <= a["trials"]
 
     @pytest.mark.parametrize("solver", ["admm", "lbfgs"])
     def test_vmapped_callers_keep_the_black_box_program(
             self, logistic_data, monkeypatch, mesh, solver):
         """``packed_solve`` and ``lambda_sweep`` put the runners under
         ``vmap`` and ask for the black-box objective: their programs
-        hold the 14 products with X they held before ISSUE 29, and no
-        reduction with two results (the pair product)."""
+        hold the black box's products with X and no reduction with two
+        results (the pair product).  14 products before ISSUE 35, when
+        the expansion's condition took the slope at t and the value at
+        2t and its body took that value again; 10 now that one loop body
+        takes each once (both counts as ``make_jaxpr`` shows them)."""
         import jax
 
         from dask_ml_tpu.solvers import packed_solve
@@ -755,15 +810,201 @@ class TestLinearObjective:
         kw = dict(family=Logistic, lamduh=1.0)
         monkeypatch.setenv("DASK_ML_TPU_PACK", "packed")
         assert census(lambda: packed_solve(
-            solver, sX, jnp.asarray(Y), **kw), shape) == (14, 0)
+            solver, sX, jnp.asarray(Y), **kw), shape) == (10, 0)
         assert census(lambda: lambda_sweep(
-            solver, sX, Y[0], [0.1, 1.0], family=Logistic), shape) == (14, 0)
+            solver, sX, Y[0], [0.1, 1.0], family=Logistic), shape) == (10, 0)
         # one solve a dispatch: the first value_and_grad (two products),
         # then the pair and one transposed product an iteration
         monkeypatch.setenv("DASK_ML_TPU_PACK", "sequential")
         dots, pairs = census(lambda: packed_solve(
             solver, sX, jnp.asarray(Y), **kw), shape)
         assert (dots, pairs) == (2 * 3, 2 * 1)
+
+
+def _parabola(c, log):
+    """``phi(t) = -t + c t^2 / 2`` (value 0 and slope -1 at the start,
+    Armijo up to ``2 (1 - c1) / c``) whose value and slope are two host
+    callbacks that write themselves into ``log``: under ``jit`` what a
+    search does not use is never called, so the log is the search's
+    trials, in order."""
+    import jax
+
+    def recorded(kind, fn):
+        def call(t):
+            log.append((kind, float(t)))
+            return np.float32(fn(float(t)))
+
+        return lambda t: jax.pure_callback(
+            call, jax.ShapeDtypeStruct((), jnp.float32), t)
+
+    value = recorded("value", lambda t: -t + 0.5 * c * t * t)
+    slope = recorded("slope", lambda t: -1.0 + c * t)
+    return lambda t: (value(t), slope(t), ())
+
+
+def _recorded_search(c, start, c2="wolfe"):
+    """``(t, f_new, failed, n_calls, log)`` of one jitted ``backtrack``
+    search of :func:`_parabola` from the exponent ``start`` (None: the
+    unit step, with no walk up in the program)."""
+    import jax
+
+    from dask_ml_tpu.solvers.lbfgs_core import _C2, _search
+
+    log = []
+
+    def search(start):
+        return _search("backtrack", _parabola(c, log), jnp.float32(0.0),
+                       jnp.float32(-1.0), 1e-4,
+                       _C2 if c2 == "wolfe" else c2, 30, start)
+
+    t, f_new, _, failed, n = jax.jit(search)(
+        None if start is None else jnp.asarray(start))
+    return float(t), float(f_new), bool(failed), int(n), log
+
+
+def _values(*exponents):
+    return [("value", 2.0 ** e) for e in exponents]
+
+
+class TestGuidedSearch:
+    """ISSUE 35: the backtracking search takes no trial whose outcome it
+    knows.  A search with no history starts from the curvature along the
+    line and not from 1; after a halving, or a doubling that Armijo
+    refused, nothing is asked about 2t; where nothing is known above t
+    the slope comes first and the value at 2t only if the curvature
+    test fails.  The accepted step is the one a search from 1 accepts."""
+
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_the_start_is_an_exact_power_of_two_under_the_parabolas_bound(
+            self, rng, family):
+        import jax
+
+        from dask_ml_tpu.solvers.lbfgs_core import (
+            _cached_phi, _powers_of_two, _start_exponent)
+
+        d, linear, _ = TestLinearObjective()._objectives(family, rng)
+        x0 = jnp.zeros(d, jnp.float32)
+        g = jax.grad(linear)(x0)
+        _, _, curvature = _cached_phi(linear, x0, -g)
+        c, dg = float(curvature()), -float(jnp.dot(g, g))
+        assert c > 0
+        k = int(jax.jit(_start_exponent, static_argnums=(2, 3))(
+            jnp.float32(c), jnp.float32(dg), 1e-4, 30))
+        t0 = np.float32(_powers_of_two(30, jnp.float32)[k])
+        # the power of two to the last bit: a mantissa of one half
+        assert np.frexp(t0) == (0.5, 1 - k)
+        bound = min(1.0, 2 * (1 - 1e-4) * -dg / c)
+        assert t0 <= bound and (2 * t0 > bound or t0 == 1 or k == 30)
+
+    @pytest.mark.parametrize("curvature,dg,k", [
+        # no positive number: the unit step, the search as it was
+        (np.nan, -1.0, 0), (np.inf, -1.0, 0), (-np.inf, -1.0, 0),
+        (-1.0, -1.0, 0), (0.0, -1.0, 0), (1.0, np.nan, 0),
+        # a bound of 2 or of 1.9998 / 1.9998: nothing over 1
+        (1.0, -1.0, 0), (1.9998, -1.0, 0),
+        # just under a power of two goes to the next one down
+        (3.0, -1.0, 1), (4.0, -1.0, 2), (100.0, -1.0, 6),
+        # never under the floor 2^-max_backtracks
+        (2.0 ** 31, -1.0, 30), (1e30, -1.0, 30), (1.0, -1e-30, 30),
+    ])
+    def test_start_exponent_by_hand(self, curvature, dg, k):
+        from dask_ml_tpu.solvers.lbfgs_core import _start_exponent
+
+        assert int(_start_exponent(
+            jnp.float32(curvature), jnp.float32(dg), 1e-4, 30)) == k
+
+    @pytest.mark.parametrize("start,trials", [
+        # a search from 1: six halvings, and no curvature test after them
+        (None, 7), (0, 7),
+        # a start above the answer walks down ...
+        (3, 4),
+        # ... the parabola's own guess stands on it: one look above ...
+        ("guess", 2), (6, 2),
+        # ... and a start below walks up to it, then is refused at 1/32
+        (9, 5), (30, 26),
+    ])
+    def test_every_start_ends_on_the_step_a_search_from_one_accepts(
+            self, start, trials):
+        # _quadratic([1, 100]) from (1, 1) along -g: Armijo holds up to
+        # 0.019998, so 1/64 is the largest power of two that passes
+        t, n = TestLinearObjective._first_search(
+            TestLinearObjective._quadratic([1.0, 100.0]), jnp.ones(2),
+            start=start)
+        assert t == 1 / 64 and n == trials
+
+    @pytest.mark.parametrize("start", ["guess", "above", "below"])
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_guided_and_unguided_searches_accept_equal_steps(
+            self, rng, family, start):
+        d, linear, _ = TestLinearObjective()._objectives(family, rng)
+        x0 = jnp.zeros(d, jnp.float32)
+        first = TestLinearObjective._first_search
+        from_one, _ = first(linear, x0, start=None)
+        k = int(round(-np.log2(from_one)))
+        assert from_one == 2.0 ** -k  # equal, not close
+        if start != "guess":
+            start = min(k + 3, 30) if start == "below" else max(k - 3, 0)
+        assert first(linear, x0, start=start)[0] == from_one
+
+    @pytest.mark.parametrize("start", [None, 0, 12, 30])
+    def test_the_floor_and_failed_are_as_before(self, start):
+        # Armijo holds nowhere (a line that rises under a slope said to
+        # fall: c = 1e12 puts the bound under the floor 2^-30)
+        t, f_new, failed, n, log = _recorded_search(1e12, start)
+        first = start or 0
+        assert failed and t == 0.0 and f_new == 0.0
+        assert n == 31 - first
+        assert log == _values(*range(-first, -31, -1))
+
+    @pytest.mark.parametrize("c,start,c2,trials,accepted", [
+        # after a halving the search takes no slope and no value(2t):
+        # 2t is the step whose Armijo test just failed
+        (100.0, None, "wolfe", _values(0, -1, -2, -3, -4, -5, -6), 1 / 64),
+        (100.0, 3, "wolfe", _values(-3, -4, -5, -6), 1 / 64),
+        # nor after a walk up that Armijo stopped
+        (100.0, 6, "wolfe", _values(-6, -5), 1 / 64),
+        (100.0, 9, "wolfe", _values(-9, -8, -7, -6, -5), 1 / 64),
+        # the unit step accepted at the first look: the slope, which
+        # holds, and no value at 2
+        (0.5, None, "wolfe", _values(0) + [("slope", 1.0)], 1.0),
+        # a walk up that reached 1 knows nothing above it either
+        (0.5, 3, "wolfe", _values(-3, -2, -1, 0) + [("slope", 1.0)], 1.0),
+        # an expansion keeps the value it moved to: each of 2, 4, 8, 16
+        # is taken once, then its slope (which holds at 16)
+        (0.01, None, "wolfe",
+         [(kind, 2.0 ** e) for e in range(5) for kind in ("value", "slope")],
+         16.0),
+        (0.01, 2, "wolfe",
+         _values(-2, -1) + [(kind, 2.0 ** e) for e in range(5)
+                            for kind in ("value", "slope")], 16.0),
+        # eight expansions at most, and no test after the eighth
+        (1e-6, None, "wolfe",
+         [(kind, 2.0 ** e) for e in range(8) for kind in ("value", "slope")]
+         + _values(8), 256.0),
+        # Armijo alone (gradient_descent, newton): never a slope
+        (0.5, None, None, _values(0), 1.0),
+        (100.0, None, None, _values(0, -1, -2, -3, -4, -5, -6), 1 / 64),
+    ])
+    def test_the_trials_a_search_takes_in_order(
+            self, c, start, c2, trials, accepted):
+        t, f_new, failed, n, log = _recorded_search(c, start, c2)
+        assert not failed and t == accepted
+        assert log == trials and n == len(trials)
+        # the value handed back is the one taken at the accepted step
+        assert f_new == np.float32(-t + 0.5 * c * t * t)
+
+    def test_only_the_search_with_no_history_is_guided(self):
+        # on the quadratic the first search guesses (curvature, 1/64,
+        # 1/32) and every later one starts at 1 with no curvature taken
+        _, st = lbfgs_minimize(
+            TestLinearObjective._quadratic([1.0, 100.0]), jnp.ones(2),
+            tol=1e-6, max_iter=6)
+        assert int(st.k) >= 3 and int(st.n_updates) == int(st.k)
+        assert int(st.n_guided) == 3 < int(st.n_trials)
+        _, grid = lbfgs_minimize(
+            TestLinearObjective._quadratic([1.0, 100.0]), jnp.ones(2),
+            tol=1e-6, max_iter=6, line_search="probe_grid")
+        assert int(grid.n_guided) == 0
 
 
 @pytest.fixture(scope="module")
@@ -978,7 +1219,7 @@ class TestConsensusCompiledForFourChips:
         assert not re.search(r"all-gather|all-to-all|collective-permute",
                              hlo)
         # every collective is an all-reduce, and the largest operand of
-        # any is the 29 parameters or the five counts of the round's
+        # any is the 29 parameters or the six counts of the round's
         # work: nothing of a row's size ever crosses chips
         crossing = re.findall(
             r"= (\(?[a-z0-9]+\[[0-9,]*\][^=\n]*?) all-reduce(?:-start)?\(",
@@ -989,9 +1230,13 @@ class TestConsensusCompiledForFourChips:
                 dims = [int(n) for n in shape.split(",") if n]
                 assert int(np.prod(dims or [1])) <= self.D + 1, shapes
         # the slowest and the fastest shard's counts ride ONE all-reduce
-        # (a max over s32[5]), where the slowest's alone rode before
-        assert len(re.findall(r"s32\[5\]\S* all-reduce(?:-start)?\(", hlo)) == 1
-        assert len(SOLVE_COUNTS) == 7
+        # (a max over s32[6]: the guided searches' trials came to it in
+        # ISSUE 35), where the slowest's alone rode before; with the
+        # parameters' sum and the residuals' three scalars that is three
+        # all-reduces a round, as before
+        assert len(re.findall(r"s32\[6\]\S* all-reduce(?:-start)?\(", hlo)) == 1
+        assert len(crossing) == 3
+        assert len(SOLVE_COUNTS) == 8
 
 
 class TestKMeansInitCompiledForTheChip:
