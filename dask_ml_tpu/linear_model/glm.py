@@ -30,7 +30,7 @@ from ..solvers import (
     newton,
     proximal_grad,
 )
-from ..solvers.algorithms import COUNTED_SOLVERS, unpack_counts
+from ..solvers.algorithms import COUNTED_SOLVERS, EXITS, unpack_counts
 from .utils import binary_indicator
 
 _SOLVERS = {
@@ -55,11 +55,11 @@ def _fetch_counts(runs):
     return got, {"rounds": int(got.max())}, {}
 
 
-def _publish_counts(span, counts, ratios):
+def _publish_counts(span, counts, ratios, est):
     """A finished solve's counts: onto its span, and into the always-on
     registry (``solve.count`` solves, and their summed counts).  Where
-    ADMM's consensus stopped (``ratios``) goes on the span alone."""
-    span.set(**counts, **ratios)
+    and why it stopped (``_stop_attrs``) goes on the span alone."""
+    span.set(**counts, **_stop_attrs(est, counts, ratios))
     reg = _obs.registry()
     reg.counter("solve.count").inc()
     for name, value in counts.items():
@@ -275,7 +275,7 @@ class _GLM(TPUEstimator):
             # converted only now, after the solve is dispatched (the wait
             # for the device is here, inside the span)
             self.n_iter_, *counts = _fetch_counts([n_it])
-            _publish_counts(span, *counts)
+            _publish_counts(span, *counts, self)
         if self.fit_intercept:
             self.coef_ = beta[:-1]
             self.intercept_ = float(beta[-1])
@@ -543,7 +543,7 @@ class LogisticRegression(ClassifierMixin, _GLM):
             # are converted only here, after every class's solve has
             # dispatched (the wait for the device is here, in the span)
             self.n_iter_, *counts = _fetch_counts(n_iter_runs)
-            _publish_counts(span, *counts)
+            _publish_counts(span, *counts, self)
         if self.fit_intercept:
             self.coef_ = (
                 self.betas_[0, :-1] if len(self.classes_) == 2
@@ -688,3 +688,23 @@ class PoissonRegression(RegressorMixin, _GLM):
 
     def score(self, X, y, sample_weight=None):
         return -self.get_deviance(X, y, sample_weight=sample_weight)
+
+
+def _stop_attrs(est, counts, ratios):
+    """What ``glm.solve`` says of a counted solve's stop beside its
+    counts: the solver's ``ratios`` (where ADMM's consensus and its last
+    round's local solves stood against their tests; one of the latter
+    that is no number, because ``inner_tol`` is 0 or a solve took no
+    iteration, is left off) and ``stopped``, why the solver's own loop
+    ended: ADMM's by Boyd's rule (``"boyd"``) or at ``max_iter`` rounds
+    (``"budget"``), ``lbfgs``'s by the name of its exit
+    (``lbfgs_core.EXITS``).  Known on the host: no device work."""
+    attrs = {name: value for name, value in ratios.items()
+             if name not in ("grad_ratio", "dec_ratio")
+             or np.isfinite(value)}
+    if "exit_gtol" in counts:  # a counted solve's whole vector
+        attrs["stopped"] = (
+            ("budget" if counts["rounds"] >= est.max_iter else "boyd")
+            if est.solver == "admm" else
+            next(name for name in EXITS if counts["exit_" + name]))
+    return attrs

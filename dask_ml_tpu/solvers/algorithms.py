@@ -38,7 +38,8 @@ from ..core.compat import shard_map_unchecked
 from ..core.mesh import MeshHolder, get_mesh
 from ..core.sharded import ShardedRows, shard_rows
 from .families import Family, Logistic
-from .lbfgs_core import LinearObjective, lbfgs_minimize, run_line_search
+from .lbfgs_core import (
+    EXITS, LinearObjective, lbfgs_minimize, run_line_search, stall_threshold)
 from .regularizers import L2, Regularizer, get_regularizer
 
 logger = logging.getLogger(__name__)
@@ -136,7 +137,15 @@ def reset_dispatch_counts():
 #: trials on the cached linear predictor, which do not: each a reduction
 #: over vectors of a row's length, for a value of ``phi``, its slope or,
 #: once a history-less search, the curvature at the start of the line).
-#: The rest are ADMM's alone (``_lbfgs_run``'s vector ends after four):
+#: The next four say why the L-BFGS solves ended, one count for each of
+#: ``lbfgs_core.EXITS`` (``LBFGSState.reason``): the gradient certified
+#: (``max|g| <= tol``), the objective stalled (its relative decrease at
+#: or under 10 eps), the search failed (no step passed Armijo), the
+#: budget spent (``max_iter`` iterations and none of those).  ``lbfgs``
+#: is one solve, so one of the four is 1; ADMM makes one local solve a
+#: shard a round, so they sum to ``rounds x shards``.  A solve that
+#: ends by ``failed`` or ``budget`` is one to distrust.
+#: The rest are ADMM's alone (``_lbfgs_run``'s vector ends after eight):
 #: summed over the rounds, the evaluations and the trials the slowest
 #: shard's local solve made more than the fastest's (what the fastest
 #: chip sat out at the round's all-reduce; 0 on one shard), the rounds
@@ -149,12 +158,21 @@ def reset_dispatch_counts():
 #: refuses) is a guess that stood on the answer; more is the walk from
 #: the guess to the answer
 SOLVE_COUNTS = ("rounds", "inner_iters", "passes", "trials",
+                *("exit_" + name for name in EXITS),
                 "skew_passes", "skew_trials", "rho_moves", "guided_trials")
 #: where ADMM's consensus stopped, behind its counts in the same vector
 #: as float32 BIT PATTERNS (so they cost no second transfer): the last
 #: round's residuals over their tolerances (under 1: that part of the
-#: stopping rule was met) and the final ``rho`` over the initial one
-SOLVE_RATIOS = ("primal_ratio", "dual_ratio", "rho_ratio")
+#: stopping rule was met) and the final ``rho`` over the initial one;
+#: then where the last round's LOCAL solves stopped, the largest over
+#: the shards: ``max|g|`` at the solve's last point over ``inner_tol``
+#: (under 1: the gradient test was met; infinite where ``inner_tol`` is
+#: 0) and the last iteration's relative decrease of the local objective
+#: over ``lbfgs_core.stall_threshold`` (under 1: the float32 loss could
+#: no longer tell two steps apart; infinite where a solve took no
+#: iteration)
+SOLVE_RATIOS = ("primal_ratio", "dual_ratio", "rho_ratio",
+                "grad_ratio", "dec_ratio")
 #: the solvers that take ``return_counts=True``
 COUNTED_SOLVERS = ("admm", "lbfgs")
 
@@ -245,8 +263,9 @@ def _lbfgs_run(x, yv, mask, beta0, lamduh, max_iter, tol, *, family, reg,
     beta, st = lbfgs_minimize(
         obj, beta0, max_iter=max_iter, tol=tol, line_search=line_search
     )
-    return beta, jnp.stack(
-        [st.k, st.k, st.n_evals, st.n_trials]).astype(jnp.int32)
+    return beta, jnp.concatenate([
+        jnp.stack([st.k, st.k, st.n_evals, st.n_trials]),
+        _exit_counts(st)]).astype(jnp.int32)
 
 
 def lbfgs(X, y, *, family: type[Family] = Logistic, regularizer=L2,
@@ -546,14 +565,27 @@ def _admm_run(x, yv, mask, lamduh, rho, abstol, reltol, inner_tol, max_it,
             # the round lasts as long as its slowest shard's solve; the
             # negatives bring the fastest shard's counts in the same
             # all-reduce, and the difference is what that shard sat out
-            # (and the guided searches' trials ride along)
-            both = lax.pmax(
+            # (and the guided searches' trials ride along, and how far
+            # this round's solve stood from its two convergence tests:
+            # a non-negative float32's bit pattern orders as its int32
+            # does, and ``abs`` clears the sign a zero, a NaN or a
+            # rounding of an accepted step's decrease may carry)
+            stood = jnp.abs(jnp.stack([
+                st.g_max / inner_tol,
+                st.rel_dec / stall_threshold(st.rel_dec.dtype),
+            ]).astype(jnp.float32))
+            both = lax.pmax(jnp.concatenate([
                 jnp.stack([st.k, st.n_evals, st.n_trials,
-                           -st.n_evals, -st.n_trials, st.n_guided]), row_ax)
+                           -st.n_evals, -st.n_trials, st.n_guided]),
+                lax.bitcast_convert_type(stood, jnp.int32)]), row_ax)
+            # why each shard's solve ended: the one all-reduce a round
+            # this costs over the three there were (a sum, so it cannot
+            # ride the pmax)
+            exits = lax.psum(_exit_counts(st), row_ax)
             work = jnp.concatenate(
-                [both[:3], both[1:3] + both[3:5], both[5:]])
+                [both[:3], exits, both[1:3] + both[3:5], both[5:6]])
         return (b_new[None], u_new[None], z_new, primal_sq, beta_norm_sq,
-                u_norm_sq, work)
+                u_norm_sq, work, both[6:])
 
     step = shard_map_unchecked(
         one_shard,
@@ -575,6 +607,7 @@ def _admm_run(x, yv, mask, lamduh, rho, abstol, reltol, inner_tol, max_it,
             P(),
             P(),
             P(),
+            P(),
         ),
     )
 
@@ -584,15 +617,15 @@ def _admm_run(x, yv, mask, lamduh, rho, abstol, reltol, inner_tol, max_it,
 
     def cond(state):
         (i, _, _, _, _, primal, dual, eps_pri, eps_dual,
-         rho_moved, _) = state
+         rho_moved, _, _) = state
         return (i < max_it) & (
             (primal >= eps_pri) | (dual >= eps_dual) | rho_moved
         )
 
     def body(state):
-        i, beta_l, u_l, z, rho_c, *_, work = state
+        i, beta_l, u_l, z, rho_c, *_, work, _ = state
         z_old = z
-        beta_l, u_l, z, primal_sq, beta_sq, u_sq, round_work = step(
+        beta_l, u_l, z, primal_sq, beta_sq, u_sq, round_work, stood = step(
             x, yv, mask, z, beta_l, u_l, rho_c
         )
         primal = jnp.sqrt(primal_sq)
@@ -644,8 +677,8 @@ def _admm_run(x, yv, mask, lamduh, rho, abstol, reltol, inner_tol, max_it,
                 eps_dual, rho_moved, work + jnp.concatenate(
                     # SOLVE_COUNTS' order: rho_moves stands before the
                     # guided searches' trials, the round's last count
-                    [round_work[:5], rho_moved[None].astype(jnp.int32),
-                     round_work[5:]]))
+                    [round_work[:-1], rho_moved[None].astype(jnp.int32),
+                     round_work[-1:]]), stood)
 
     inf = jnp.asarray(jnp.inf, _param_dtype(x))
     zero = jnp.asarray(0.0, _param_dtype(x))
@@ -658,13 +691,18 @@ def _admm_run(x, yv, mask, lamduh, rho, abstol, reltol, inner_tol, max_it,
     z0 = z_init.astype(_param_dtype(x))
     init = (jnp.int32(0), beta_l0, u_l0, z0,
             jnp.asarray(rho, _param_dtype(x)), inf, inf, zero, zero,
-            jnp.asarray(False), jnp.zeros(len(SOLVE_COUNTS) - 1, jnp.int32))
+            jnp.asarray(False), jnp.zeros(len(SOLVE_COUNTS) - 1, jnp.int32),
+            # no round, no local solve: neither ratio is a number
+            lax.bitcast_convert_type(
+                jnp.full(2, jnp.inf, jnp.float32), jnp.int32))
     final = lax.while_loop(cond, body, init)
-    rounds, _, _, z, rho_c, primal, dual, eps_pri, eps_dual, _, work = final
+    (rounds, _, _, z, rho_c, primal, dual, eps_pri, eps_dual, _, work,
+     stood) = final
     ratios = jnp.stack([primal / eps_pri, dual / eps_dual, rho_c / rho])
     return z, jnp.concatenate([
         rounds[None], work,
-        lax.bitcast_convert_type(ratios.astype(jnp.float32), jnp.int32)])
+        lax.bitcast_convert_type(ratios.astype(jnp.float32), jnp.int32),
+        stood])
 
 
 def admm(X, y, *, family: type[Family] = Logistic, regularizer=L2,
@@ -701,7 +739,9 @@ def admm(X, y, *, family: type[Family] = Logistic, regularizer=L2,
     :data:`SOLVE_COUNTS` and :data:`SOLVE_RATIOS` lay out and
     :func:`unpack_counts` reads on the host (per round the slowest
     shard's inner iterations and evaluations, and what the fastest made
-    fewer, summed over the rounds; then where the consensus stopped).
+    fewer, summed over the rounds; how many shard-rounds' local solves
+    ended by each of ``lbfgs_core.EXITS``; then where the consensus
+    stopped, and where the last round's local solves did).
     """
     line_search = line_search_strategy(line_search)
     reg = get_regularizer(regularizer)
@@ -1033,3 +1073,9 @@ def lambda_sweep(solver: str, X, y, lams, *, family: type[Family] = Logistic,
         return beta, _iterations(n_it)
 
     return jax.vmap(one)(lam_v, B0)
+
+
+def _exit_counts(st):
+    """One finished L-BFGS solve as ``int32[4]`` counts by
+    ``lbfgs_core.EXITS``: 1 at its ``LBFGSState.reason``."""
+    return (jnp.arange(len(EXITS)) == st.reason).astype(jnp.int32)

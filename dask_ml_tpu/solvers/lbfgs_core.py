@@ -56,6 +56,10 @@ from jax import lax
 
 #: curvature constant of the weak-Wolfe test
 _C2 = 0.9
+#: why :func:`lbfgs_minimize` ended, as ``LBFGSState.reason`` holds it:
+#: an index into :data:`EXITS`
+EXIT_GTOL, EXIT_STALLED, EXIT_FAILED, EXIT_BUDGET = range(4)
+EXITS = ("gtol", "stalled", "failed", "budget")
 
 
 class LinearObjective(NamedTuple):
@@ -117,6 +121,14 @@ class LBFGSState(NamedTuple):
     # doubling, the curvature test where the walk up reached 1.  How far
     # the guess stood from the answer
     n_guided: jax.Array
+    # why the loop ended, an index into EXITS (lbfgs_minimize has the
+    # rule), and how far the last point stood from the two convergence
+    # tests: max|g| there (compare with ``tol``), and the last
+    # iteration's relative decrease of the objective (compare with
+    # :func:`stall_threshold`; inf where no iteration was taken)
+    reason: jax.Array
+    g_max: jax.Array
+    rel_dec: jax.Array
 
 
 def _two_loop(g, S, Y, rho, n_updates, m):
@@ -458,7 +470,7 @@ def lbfgs_minimize(
     one product before the search, one transposed product for the
     gradient at the accepted step) and every trial of the search works
     on the cached images.  Direction, history update, the Wolfe tests
-    and the three exits are the same for both.  A caller about to
+    and the four exits are the same for both.  A caller about to
     ``vmap`` this function passes a plain callable: under vmap the
     cached images are lanes x their length.
 
@@ -473,6 +485,18 @@ def lbfgs_minimize(
     line-search-failure exit still fires — a lane that cannot take any
     step has no further work worth timing), which gives a caller a
     fixed iteration count.
+
+    Why the loop ended is ``LBFGSState.reason``, one of :data:`EXITS` by
+    its index, beside the loop's flag ``converged``.  Where several
+    tests hold at once the first of these names the exit: ``failed`` (no
+    step passed Armijo, so ``t = 0``: the point did not move, and the
+    objective's decrease, 0, would read as a stall too), then ``gtol``
+    (‖g‖_∞ ≤ tol; also a start that already passes it, ``k`` 0), then
+    ``stalled`` (``tol > 0`` and the relative decrease ≤
+    :func:`stall_threshold`); ``budget`` is the loop that ended at
+    ``max_iter`` with none of them.  ``g_max`` and ``rel_dec`` are the
+    two quantities those tests read at the last point.  Under ``vmap``
+    each lane has its own.
     ``line_search``: ``backtrack`` (default; REQUIRED under ``vmap``) or
     ``probe_grid`` (batched grid); what each costs is in PERF.md
     section 5.
@@ -483,6 +507,8 @@ def lbfgs_minimize(
     d = x0.shape[0]
     f0, g0 = value_and_grad(x0)
     dtype = f0.dtype
+    g0_max = jnp.max(jnp.abs(g0))
+    certified0 = g0_max <= tol
 
     init = LBFGSState(
         x=x0,
@@ -493,10 +519,13 @@ def lbfgs_minimize(
         rho=jnp.zeros((m,), dtype=dtype),
         k=jnp.asarray(0),
         n_updates=jnp.asarray(0),
-        converged=jnp.max(jnp.abs(g0)) <= tol,
+        converged=certified0,
         n_evals=jnp.asarray(1),
         n_trials=jnp.asarray(0),
         n_guided=jnp.asarray(0),
+        reason=jnp.select([certified0], [EXIT_GTOL], EXIT_BUDGET),
+        g_max=g0_max,
+        rel_dec=jnp.asarray(jnp.inf, dtype),
     )
 
     def cond(st: LBFGSState):
@@ -564,17 +593,29 @@ def lbfgs_minimize(
             rel_dec = (st.f - f_new) / jnp.maximum(
                 jnp.maximum(jnp.abs(st.f), jnp.abs(f_new)), 1.0
             )
-            stalled = (tol > 0) & (
-                rel_dec <= 10.0 * jnp.finfo(dtype).eps
-            )
-            converged = (jnp.max(jnp.abs(g_new)) <= tol) | failed | stalled
+            stalled = (tol > 0) & (rel_dec <= stall_threshold(dtype))
+            g_max = jnp.max(jnp.abs(g_new))
+            certified = g_max <= tol
+            converged = certified | failed | stalled
+            # the first that holds names the exit
+            reason = jnp.select(
+                [failed, certified, stalled],
+                [EXIT_FAILED, EXIT_GTOL, EXIT_STALLED], EXIT_BUDGET)
             return LBFGSState(
                 x=x_new, f=f_new, g=g_new, S=S, Y=Y, rho=rho,
                 k=st.k + 1, n_updates=n_updates, converged=converged,
                 n_evals=st.n_evals + n_evals,
                 n_trials=st.n_trials + n_trials,
                 n_guided=st.n_guided + n_guided,
+                reason=reason, g_max=g_max, rel_dec=rel_dec,
             )
 
     final = lax.while_loop(cond, body, init)
     return final.x, final
+
+
+def stall_threshold(dtype):
+    """The relative decrease of the objective at or under which
+    :func:`lbfgs_minimize` takes an iteration for no decrease (the
+    ``stalled`` exit): 10 eps of the objective's dtype."""
+    return 10.0 * jnp.finfo(dtype).eps

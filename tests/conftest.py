@@ -118,6 +118,21 @@ def _tracing_left_as_found():
     yield from tracing_guard()
 
 
+@pytest.fixture(autouse=True)
+def _default_mesh_left_as_found():
+    """``benchmarks/run.py :: run_cell`` sets the process-default mesh to
+    its cell's chips and leaves it so (a benchmark's process runs one
+    cell and ends).  A test that drives it (``tests/test_pca_tsqr.py``
+    on one device) would hand every later test of its worker that mesh:
+    fits on one shard where eight were meant, and arrays of two meshes
+    in one program.  Put back, whatever the test did."""
+    from dask_ml_tpu.core import mesh as _mesh
+
+    before = getattr(_mesh._state, "default_mesh", None)
+    yield
+    _mesh.set_mesh(before)
+
+
 class PeakInside:
     """``with gauge:`` around a planted sleep; ``gauge.peak`` is the most
     threads inside at once: that work overlapped, read without a clock."""

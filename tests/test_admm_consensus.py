@@ -191,7 +191,7 @@ def test_where_the_consensus_stopped_rides_in_the_counts_vector():
     got, ratios = unpack_counts(fixed)
     assert got["rho_moves"] == 0 and ratios["rho_ratio"] == 1.0
     got, ratios = unpack_counts(plain)
-    assert tuple(got) == SOLVE_COUNTS[:4] and ratios == {}
+    assert tuple(got) == SOLVE_COUNTS[:8] and ratios == {}
 
 
 def test_glm_solve_span_and_registry_carry_the_consensus():

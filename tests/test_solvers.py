@@ -373,7 +373,7 @@ class TestSolveCounts:
         # (tests/test_admm_consensus.py)
         assert solvers.algorithms.SOLVE_COUNTS[:4] == (
             "rounds", "inner_iters", "passes", "trials")
-        assert counts.dtype == jnp.int32 and counts.shape == (11,)
+        assert counts.dtype == jnp.int32 and counts.shape == (17,)
         # every round reads X once at its start (a value_and_grad) and
         # twice an inner iteration (the product, the gradient); every
         # iteration tries at least its unit step
@@ -397,7 +397,9 @@ class TestSolveCounts:
         X, y, _ = logistic_data
         _, counts = solvers.lbfgs(X, y, lamduh=1.0, return_counts=True,
                                   line_search="backtrack")
-        rounds, inner, passes, trials = (int(c) for c in counts)
+        rounds, inner, passes, trials = (int(c) for c in counts[:4])
+        # then how the one solve ended (tests/test_solve_exits.py)
+        assert counts.shape == (8,) and int(counts[4:].sum()) == 1
         _, n_it = solvers.lbfgs(X, y, lamduh=1.0, return_n_iter=True,
                                 line_search="backtrack")
         assert rounds == inner == int(n_it) and passes == 1 + 2 * inner
@@ -1230,13 +1232,16 @@ class TestConsensusCompiledForFourChips:
                 dims = [int(n) for n in shape.split(",") if n]
                 assert int(np.prod(dims or [1])) <= self.D + 1, shapes
         # the slowest and the fastest shard's counts ride ONE all-reduce
-        # (a max over s32[6]: the guided searches' trials came to it in
-        # ISSUE 35), where the slowest's alone rode before; with the
-        # parameters' sum and the residuals' three scalars that is three
-        # all-reduces a round, as before
-        assert len(re.findall(r"s32\[6\]\S* all-reduce(?:-start)?\(", hlo)) == 1
-        assert len(crossing) == 3
-        assert len(SOLVE_COUNTS) == 8
+        # (a max over s32[8]: the guided searches' trials came to it in
+        # ISSUE 35, the last round's two ratios as bit patterns in ISSUE
+        # 36), where the slowest's alone rode before; with the
+        # parameters' sum and the residuals' three scalars that was three
+        # all-reduces a round.  ISSUE 36 adds the fourth and says so: a
+        # sum over s32[4], how many shards' solves ended by each exit
+        assert len(re.findall(r"s32\[8\]\S* all-reduce(?:-start)?\(", hlo)) == 1
+        assert len(re.findall(r"s32\[4\]\S* all-reduce(?:-start)?\(", hlo)) == 1
+        assert len(crossing) == 4
+        assert len(SOLVE_COUNTS) == 12
 
 
 class TestKMeansInitCompiledForTheChip:
