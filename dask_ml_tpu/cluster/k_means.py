@@ -244,16 +244,16 @@ _lloyd_loop = _programs.cached_program(
 )
 
 
-def _assign_fn(x, mask, centers):
-    d2 = _sq_dists(x, centers)
+def _assign_fn(x, mask, centers, x_norm=None):
+    # given the rows' |x|^2 (fit holds k-means||'s) the table is read once
+    d2 = (_sq_dists(x, centers) if x_norm is None else _new_d2(
+        x, x_norm, centers, jnp.ones((centers.shape[0],), bool)))
     labels = jnp.argmin(d2, axis=1)
     min_d2 = jnp.min(d2, axis=1)  # same element as d2[argmin], fused lowering
     return labels, jnp.sum(min_d2 * mask)
 
 
-# no donation: the outputs ((n,) int labels + a scalar) are smaller
-# than every input and x/centers stay live in the caller — the
-# gemm-output-smaller class design.md §8 records
+# no donation (the gemm-output-smaller class of design.md §8):
 # graftlint: disable=donation-miss -- outputs (labels + scalar) smaller than every input; x/centers stay live in fit/predict
 _assign = _programs.cached_program(_assign_fn, name="kmeans.assign")
 
@@ -543,9 +543,11 @@ def _sample_candidates(X, n_clusters, key, oversampling_factor,
     """The device part of k-means|| and its one pull: the candidate
     buffer ``(slots, d)``, which slots hold a row, every slot's weight,
     the rounds run, the distance columns they computed and ``cap``, all
-    on the host.  ``behind()`` is called once the rounds are dispatched
-    and before the pull: what it queues runs on the device while the host
-    works on the candidates."""
+    on the host, and the rows' ``|x|^2``, left on the device for the
+    caller to carry (every init-only row vector dies with this frame).
+    ``behind()`` is called once the rounds are dispatched and before the
+    pull: what it queues runs on the device while the host works on the
+    candidates."""
     from ..ops.scatter import scatter_strategy
 
     x, mask = X.data, X.mask
@@ -577,17 +579,17 @@ def _sample_candidates(X, n_clusters, key, oversampling_factor,
         if behind is not None:
             behind()
         cand, keep, weights, (rounds, slots) = jax.device_get(out)
-        return cand, keep, weights, rounds, slots, cap
+        return cand, keep, weights, rounds, slots, cap, x_norm
 
 
 def _init_scalable(X, n_clusters, key, oversampling_factor, init_max_iter,
                    behind=None):
-    """``init_scalable`` and its counts (``rounds`` run, valid
+    """``init_scalable``, its counts (``rounds`` run, valid
     ``candidates``, ``cap``, and ``slots``: the distance columns the
     rounds computed, ``rounds * cap`` if every fold were ``cap`` wide),
-    which ``KMeans.fit`` puts on its span; ``behind``: see
-    ``_sample_candidates``."""
-    cand, keep, weights, rounds, slots, cap = _sample_candidates(
+    which ``KMeans.fit`` puts on its span, and the rows' ``|x|^2`` the
+    rounds ran on; ``behind``: see ``_sample_candidates``."""
+    cand, keep, weights, rounds, slots, cap, x_norm = _sample_candidates(
         X, n_clusters, key, oversampling_factor, init_max_iter, behind)
     x, n = X.data, X.n_samples
     cand = np.asarray(cand, dtype=np.float64)[keep]
@@ -614,7 +616,7 @@ def _init_scalable(X, n_clusters, key, oversampling_factor, init_max_iter,
                      max_iter=10, random_state=0)
     with _one_host_thread():
         local.fit(cand, sample_weight=np.maximum(weights, 1e-12))
-    return jnp.asarray(local.cluster_centers_, dtype=x.dtype), counts
+    return jnp.asarray(local.cluster_centers_, dtype=x.dtype), counts, x_norm
 
 
 _HOST_POOLS = None
@@ -634,6 +636,72 @@ def _one_host_thread():
 
         _HOST_POOLS = threadpoolctl.ThreadpoolController()
     return _HOST_POOLS.limit(limits=1)
+
+
+#: rows whose mean anchors the one pass of ``_tol_fn``
+_TOL_SAMPLE = 1024
+
+
+def _sample_stride(rows: int, want: int) -> int:
+    """The stride that takes about ``want`` of ``rows`` rows: the largest
+    prime at most ``rows // want``, so that a table laid out with a period
+    is sampled across it (the benchmark's blobs repeat every 8 rows, and
+    ``25M // 1024`` = 24,414 would meet four of the eight); 1 where there
+    are not twice ``want`` rows."""
+    stride = max(rows // want, 1)
+    while any(stride % p == 0 for p in range(2, int(stride ** 0.5) + 1)):
+        stride -= 1
+    return stride
+
+
+def _tol_fn(x, mask, tol, *, mesh_holder):
+    """The Lloyd loop's stopping threshold, sklearn's ``tol * mean_j
+    var_j`` over the real rows, from ONE read of the table:
+    ``(threshold, anchor_share)``.
+
+    ``var = (S2 - S1^2 / n) / n`` of ``S1 = sum((x - a) m)`` and ``S2 =
+    sum((x - a)^2 m)`` is off by about ``eps * (1 + (mean - a)^2 / var)``,
+    so the anchor ``a`` is no row but the masked mean of about
+    ``_TOL_SAMPLE`` rows taken at a fixed stride (``_sample_stride``)
+    over the whole table (each shard over its own rows, one ``psum`` of
+    ``d + 1`` numbers): within sigma / 32 of the mean whatever the table's
+    order, period or offset.
+    ``anchor_share`` = ``max_j (S1_j^2 / n) / S2_j`` is the share of the
+    anchored second moment that is the anchor's own offset: near 0 for a
+    good anchor, and what says when the one pass would lose digits (a
+    column with no spread may read 1 and loses nothing).  No centred second
+    pass stands behind a ``lax.cond``: the ``conditional`` wants the table
+    copied to row-major tiles, 11.92 GB at 25M x 50 (PERF.md PR 37).
+    ``core.sharded.masked_var`` keeps its three passes for those who
+    publish a variance; this is a scale for a threshold."""
+    mesh = mesh_holder.mesh
+    row_ax = data_axes(mesh)
+    per_shard = max(_TOL_SAMPLE // data_axes_size(mesh), 1)
+
+    def local(x_l, m_l):
+        m = m_l.astype(x_l.dtype)
+        stride = _sample_stride(x_l.shape[0], per_shard)
+        rows, w = x_l[::stride], m[::stride]
+        sample = jax.lax.psum(jnp.concatenate(
+            [jnp.sum(rows * w[:, None], axis=0), jnp.sum(w)[None]]), row_ax)
+        anchor = sample[:-1] / safe_denominator(sample[-1])
+        xs = x_l - anchor
+        xm = xs * m[:, None]
+        return jax.lax.psum(
+            (jnp.sum(xm, axis=0), jnp.sum(xs * xm, axis=0), jnp.sum(m)),
+            row_ax)
+
+    s1, s2, n = _shard_map(
+        local, mesh, in_specs=(P(row_ax, None), P(row_ax)),
+        out_specs=(P(), P(), P()))(x, mask)
+    offset = s1 * s1 / n
+    share = jnp.max(offset / safe_denominator(s2))  # s2 == 0: s1 == 0 too
+    return (tol * jnp.mean((s2 - offset) / n)).astype(x.dtype), share
+
+
+# graftlint: disable=donation-miss -- outputs are two scalars; the table and its mask stay live in fit
+_tol = _programs.cached_program(
+    _tol_fn, name="kmeans.tol", static_argnames=("mesh_holder",))
 
 
 class KMeans(TransformerMixin, TPUEstimator):
@@ -668,10 +736,13 @@ class KMeans(TransformerMixin, TPUEstimator):
         self.init_max_iter = init_max_iter
         self.fit_checkpoint = fit_checkpoint
 
-    def _init_centers(self, X: ShardedRows, key, span=None, behind=None):
+    def _init_centers(self, X: ShardedRows, key, span=None, behind=None,
+                      norms=None):
         """The starting centres; k-means||'s counts go on ``span`` (the
-        fit's ``kmeans.init``) and into the always-on registry, and it
-        calls ``behind()`` where device work can hide host work."""
+        fit's ``kmeans.init``) and into the always-on registry, it calls
+        ``behind()`` where device work can hide host work, and appends
+        the rows' ``|x|^2`` to the list ``norms`` where it computed them
+        (k-means|| alone), for the fit's last assignment."""
         init = self.init
         if isinstance(init, (np.ndarray, jnp.ndarray)):
             # a COPY, never a view of the user's array: the Lloyd loop
@@ -687,10 +758,12 @@ class KMeans(TransformerMixin, TPUEstimator):
             return centers
         if init == "k-means||":
             with _timer("k-means|| initialization", logger, logging.DEBUG):
-                centers, counts = _init_scalable(
+                centers, counts, x_norm = _init_scalable(
                     X, self.n_clusters, key, self.oversampling_factor,
                     self.init_max_iter, behind,
                 )
+            if norms is not None:
+                norms.append(x_norm)
             if span is not None:
                 span.set(**counts)
             reg = _obs.registry()
@@ -780,36 +853,40 @@ class KMeans(TransformerMixin, TPUEstimator):
             # stay valid for a retried resume
             centers = jnp.array(state["centers"], dtype=X.data.dtype)
         x, mask = X.data, X.mask
-        tol = []  # the stopping threshold, a device scalar, queued once
+        # the stopping threshold and its anchor's share, device scalars,
+        # queued once
+        tol = []
 
         def queue_tol():
             # sklearn-style tol scaling: mean of per-feature variances,
             # masked so pad rows don't inflate the threshold, and from
             # UNWEIGHTED variances: sklearn's _tolerance ignores
             # sample_weight, so weighting must not move it
-            from ..core.sharded import masked_var
+            tol.extend(_tol(x, valid_mask, float(self.tol),
+                            mesh_holder=MeshHolder(get_mesh())))
 
-            tol.append((self.tol * jnp.mean(masked_var(x, valid_mask)))
-                       .astype(x.dtype))
-
+        norms = []  # the rows' |x|^2, where the init computed them
         if snap is None:
             with _obs.span("kmeans.init") as span:
                 # the threshold needs no centres: queued behind k-means||'s
-                # rounds, its three reads of X run while the host clusters
-                # the candidates
-                centers = self._init_centers(X, key, span, behind=queue_tol)
+                # rounds, its read of X runs while the host clusters the
+                # candidates
+                centers = self._init_centers(X, key, span, behind=queue_tol,
+                                             norms=norms)
         with _obs.span("kmeans.lloyd") as span:
             if not tol:
                 queue_tol()
             centers, n_iter = self._lloyd(x, mask, tol[0], centers, ckpt, it0)
             # the assignment is queued behind the loop before anything is
             # waited for: the chip goes on while the host wakes up
-            labels, inertia = _assign(x, mask, centers)
+            labels, inertia = _assign(x, mask, centers, *norms)
             # every caller reads the centres on the host: their copy
             # starts now, not at the first ``np.asarray``
             centers.copy_to_host_async()
-            n_iter = int(n_iter)  # the wait for the loop
-            span.set(iters=n_iter)
+            # the wait for the loop, and the one fetch of both
+            n_iter, share = jax.device_get((n_iter, tol[1]))
+            n_iter = int(n_iter)
+            span.set(iters=n_iter, tol_anchor_share=float(share))
         reg = _obs.registry()
         reg.counter("kmeans.count").inc()
         reg.counter("kmeans.lloyd_iters").inc(n_iter)

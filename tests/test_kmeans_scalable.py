@@ -54,7 +54,7 @@ def _brute_weights(X, w, cand):
 
 
 def _sampled(Xs, k=5, key=0, **kw):
-    cand, keep, weights, rounds, _, cap = km._sample_candidates(
+    cand, keep, weights, rounds, _, cap, _ = km._sample_candidates(
         Xs, k, jax.random.PRNGKey(key), kw.pop("oversampling_factor", 2),
         kw.pop("init_max_iter", None))
     return np.asarray(cand, np.float64), np.asarray(keep), np.asarray(
@@ -232,6 +232,8 @@ def test_span_tree_and_counters():
     rounds, candidates, iters = _fit_counts(est)
     assert iters == est.n_iter_ >= 1
     assert tree["children"][0]["attrs"]["cap"] == 40
+    assert set(tree["children"][1]["attrs"]) == {"iters", "tol_anchor_share"}
+    assert 0 <= tree["children"][1]["attrs"]["tol_anchor_share"] < 0.01
     assert 1 < candidates <= 1 + rounds * 40
     after = counts()
     assert {k: after[k] - before[k] for k in after} == {
@@ -382,7 +384,7 @@ def test_a_round_that_draws_more_than_w2_takes_the_cap_branch(monkeypatch):
     weights."""
     Xs = shard_rows(_whole_numbers(2003, 4, seed=12))
     key = jax.random.PRNGKey(_OVERFLOW_KEY)
-    cand, keep, weights, rounds, slots, cap = km._sample_candidates(
+    cand, keep, weights, rounds, slots, cap, _ = km._sample_candidates(
         Xs, 2, key, 2, None)
     widths = km._fold_widths(4.0, cap)
     assert (cap, widths) == (16, (8, 16))
@@ -414,7 +416,7 @@ def test_slots_on_the_span_and_in_the_registry_are_the_widths_summed():
     KMeans(n_clusters=5, random_state=3).fit(Xs)
     attrs = next(c for c in _fit_tree()["children"]
                  if c["name"] == "kmeans.init")["attrs"]
-    _, keep, _, rounds, slots, cap = km._sample_candidates(
+    _, keep, _, rounds, slots, cap, _ = km._sample_candidates(
         Xs, 5, jax.random.PRNGKey(3), 2, None)  # the fit's own draws
     widths = km._fold_widths(10.0, cap)
     assert (cap, widths, attrs["cap"]) == (40, (16, 24, 40), 40)
@@ -487,3 +489,204 @@ def test_lloyd_sums_offsets_from_the_current_centre():
     est = KMeans(n_clusters=1, init=X[:1].copy(), max_iter=5).fit(X)
     exact = X.astype(np.float64).mean(axis=0)
     assert np.abs(np.asarray(est.cluster_centers_)[0] - exact).max() < 2e-4
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 37: the tolerance from one read of the table, the last assignment
+# on the norms k-means|| made
+# ---------------------------------------------------------------------------
+
+_CHIPS = [1, 2, 8]
+
+
+def _sample_stride(rows, chips):
+    """The stride ``_tol_fn`` samples a shard's rows at."""
+    return km._sample_stride(-(-rows // chips),
+                             max(km._TOL_SAMPLE // chips, 1))
+
+
+def _periodic(rows=8195, features=6, k=8, seed=2):
+    """Blobs laid out as the benchmark's are: row ``i`` belongs to blob
+    ``i % k``.  ``rows // 1024`` is 8 on every mesh of the file, the
+    period itself: a stride of 8 would sample one blob."""
+    r = np.random.default_rng(seed)
+    centres = r.uniform(-10, 10, size=(k, features))
+    return (centres[np.arange(rows) % k]
+            + r.normal(size=(rows, features))).astype(np.float32)
+
+
+def _tol_tables(chips):
+    """name -> (table, whether a good anchor is asked of it)."""
+    X = _blobs()  # 4003 rows: pad rows on 2 and 8 shards, some sampled
+    sd = X.std(axis=0)
+    outlier = X.copy()  # one sampled row stands 1e3 deviations away
+    outlier[3 * _sample_stride(len(X), chips)] += 1e3 * sd
+    return {
+        "blobs": (X, True),
+        "offset": ((X + 1e4 * sd).astype(np.float32), True),
+        "sorted": (X[np.argsort(X[:, 0])], True),
+        "periodic": (_periodic(), True),
+        "outlier": (outlier, False),
+        # fewer real rows than the sample asks for: every row is sampled,
+        # the pad rows among them
+        "few_rows": (X[:301], True),
+    }
+
+
+@pytest.mark.parametrize("chips", _CHIPS)
+@pytest.mark.parametrize(
+    "table", ["blobs", "offset", "sorted", "periodic", "outlier", "few_rows"])
+def test_the_tolerance_is_sklearns_from_one_pass(table, chips):
+    X, anchored = _tol_tables(chips)[table]
+    with use_mesh(device_mesh(chips)):
+        Xs = shard_rows(X)
+        assert Xs.data.shape[0] > len(X) or chips == 1  # pad rows
+        tol, share = jax.device_get(km._tol(
+            Xs.data, Xs.mask, 1e-4, mesh_holder=MeshHolder(get_mesh())))
+    want = 1e-4 * np.var(X.astype(np.float64), axis=0).mean()
+    assert tol.dtype == np.float32
+    assert abs(tol / want - 1) < 1e-5
+    if anchored:
+        assert 0 <= share < 0.01
+    else:  # the sample's mean moved by a deviation of the other rows
+        assert 0 <= share < 1
+
+
+@pytest.mark.parametrize("rows, want, stride", [
+    (25_000_000, 1024, 24_413),  # the benchmark's cell: 24,414 is even
+    (8195, 1024, 7), (1025, 128, 7), (4003, 1024, 3), (501, 128, 3),
+    (2048, 1024, 2), (2047, 1024, 1), (300, 1024, 1), (38, 128, 1),
+])
+def test_the_sample_stride_is_prime(rows, want, stride):
+    assert km._sample_stride(rows, want) == stride
+
+
+def test_a_far_anchor_reads_a_large_share():
+    """What ``tol_anchor_share`` is for: where the sample's mean stands
+    far from the table's (half the sampled rows, and no other, 300
+    deviations off: the sample's mean 150, the table's 50) it says so."""
+    X = _blobs()
+    X[: len(X) // 2: _sample_stride(len(X), 1)] += 300 * X.std(axis=0)
+    with use_mesh(device_mesh(1)):
+        Xs = shard_rows(X)
+        tol, share = km._tol(Xs.data, Xs.mask, 1e-4,
+                             mesh_holder=MeshHolder(get_mesh()))
+    assert 0.25 < float(share) < 1
+    want = 1e-4 * np.var(X.astype(np.float64), axis=0).mean()
+    assert abs(float(tol) / want - 1) < 1e-5  # eps * (1 + 0.8) all the same
+
+
+@pytest.mark.parametrize("chips", _CHIPS)
+def test_sample_weight_does_not_move_the_tolerance(chips, monkeypatch):
+    queued, real = [], km._tol
+
+    def recording(x, mask, tol, **static):
+        queued.append(real(x, mask, tol, **static))
+        return queued[-1]
+
+    X = _blobs(rows=2003, seed=3)
+    w = np.random.default_rng(4).uniform(0.0, 5.0, size=len(X))
+    w[::7] = 0.0
+    monkeypatch.setattr(km, "_tol", recording)
+    with use_mesh(device_mesh(chips)):
+        KMeans(n_clusters=5, random_state=0).fit(X)
+        KMeans(n_clusters=5, random_state=0).fit(X, sample_weight=w)
+    (plain, _), (weighted, _) = queued
+    want = 1e-4 * np.var(X.astype(np.float64), axis=0).mean()
+    assert float(plain) == float(weighted)
+    assert abs(float(plain) / want - 1) < 1e-5
+
+
+@pytest.mark.parametrize("chips", _CHIPS)
+def test_assign_on_carried_norms_is_assign(chips):
+    """Labels bit for bit, the inertia within an ulp: the same expression
+    on the same operands, less the recomputed ``|x|^2``."""
+    X = _blobs(rows=4003, seed=8)
+    centers = jnp.asarray(X[:: len(X) // 5][:5] + 0.25)
+    with use_mesh(device_mesh(chips)):
+        Xs = shard_rows(X)
+        labels, inertia = km._assign(Xs.data, Xs.mask, centers)
+        on_norms = km._assign(Xs.data, Xs.mask, centers,
+                              km._row_norms(Xs.data))
+    assert np.array_equal(np.asarray(labels), np.asarray(on_norms[0]))
+    assert len(np.unique(np.asarray(labels)[: len(X)])) == 5
+    assert abs(float(inertia) - float(on_norms[1])) <= np.spacing(
+        np.float32(inertia))
+
+
+def _gaps_from(data, est, start):
+    """The benchmark's numbers for ``est`` against plain Lloyd from
+    ``start`` (the fit's own: a local optimum is then the reference's
+    too)."""
+    centres, _ = REFERENCE.lloyd(data["X"], start)
+    inertia = REFERENCE.sweep(data["X"], centres)[2]
+    ref = {"centers": centres,
+           "spread": float(np.sqrt(inertia / data["X"].shape[0]))}
+    return REFERENCE.compare(
+        ref, data, harness.fetch_answer(np, est, CONFIG["fetch"]),
+        {"labels_": est.labels_})
+
+
+def _norms_given(monkeypatch):
+    """How many operands each ``kmeans.assign`` of the test was given."""
+    given = []
+    real = km._assign
+
+    def recording(*operands):
+        given.append(len(operands))
+        return real(*operands)
+
+    monkeypatch.setattr(km, "_assign", recording)
+    return given
+
+
+@pytest.mark.parametrize("init", ["array", "random", "resumed"])
+def test_a_fit_that_carries_no_norms_agrees_with_the_plain_reference(
+        init, monkeypatch, tmp_path):
+    from dask_ml_tpu.core.prng import as_key
+    from dask_ml_tpu.resilience import (FaultInjected, FitCheckpoint,
+                                        fault_plan)
+
+    data = _table(13, 16_000, 8)
+    Xs = shard_rows(data["X"])
+    given = _norms_given(monkeypatch)
+    if init == "array":
+        start = REFERENCE.far_start(data["X"], 8, head=16_000)
+        est = KMeans(n_clusters=8, init=np.asarray(start, np.float32))
+    elif init == "random":
+        # a blob split between two random rows settles slowly: down to
+        # the fixed point the reference walks to, not to the threshold
+        est = KMeans(n_clusters=8, init="random", random_state=5, tol=0.0)
+        start = np.asarray(est._init_centers(Xs, as_key(5)))
+    else:
+        path = str(tmp_path / "killed.pkl")
+
+        def make():
+            return KMeans(n_clusters=8, random_state=0, tol=0.0, max_iter=6,
+                          fit_checkpoint=FitCheckpoint(path, every_n_iters=1))
+
+        with fault_plan() as plan:
+            plan.inject("step", at_call=2)
+            with pytest.raises(FaultInjected):
+                make().fit(Xs)
+        est = make()
+        start = np.asarray(est.fit_checkpoint.load_if_matches(est)[1][
+            "centers"])
+    est.fit(Xs)
+    assert given == [3]  # the program predict and score run
+    got = _gaps_from(data, est, start)
+    for name, limit in CONFIG["limits"].items():
+        assert got[name] <= limit, (name, got[name])
+
+
+def test_a_kmeans_parallel_fit_assigns_on_the_inits_norms(monkeypatch):
+    given = _norms_given(monkeypatch)
+    X = _blobs(rows=1500, seed=6)
+    est = KMeans(n_clusters=5, random_state=0).fit(X)
+    assert given == [4]
+    Xs = shard_rows(X)
+    labels, inertia = km._assign(Xs.data, Xs.mask, est.cluster_centers_)
+    assert np.array_equal(np.asarray(est.labels_),
+                          np.asarray(labels)[: len(X)])
+    assert abs(est.inertia_ - float(inertia)) <= np.spacing(
+        np.float32(inertia))

@@ -1311,6 +1311,123 @@ class TestKMeansInitCompiledForTheChip:
                 r"\{highest,highest\}" % (self.ROWS, width), bodies[fusion])
 
 
+class TestKMeansTailCompiledForTheChip:
+    """ISSUE 37, at the benchmark's size (25,000,000 x 50 on one v5e): the
+    two programs of a k-means fit that are none of its solver's, the
+    stopping threshold and the last assignment, read the table once each.
+    Compiled, never run.  (In this file because one process at a time may
+    load the TPU's library: ``v5e_chip``.)"""
+
+    ROWS, D, K = 25_000_000, 50, 8
+
+    def _compiled(self, mesh, program, *operands, rows=ROWS, **static):
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        specs = {"table": ((rows, self.D), P("data", None)),
+                 "row": ((rows,), P("data")),
+                 "centers": ((self.K, self.D), P())}
+        args = [jax.ShapeDtypeStruct(
+            specs[o][0], jnp.float32,
+            sharding=NamedSharding(mesh, specs[o][1]))
+            if isinstance(o, str) else o for o in operands]
+        with _compile_cache_off():
+            return program.lower(*args, **static).compile()
+
+    def _fed_by_the_table(self, compiled):
+        """The entry computation's fusions that take the table, each as
+        ``(result shapes, what it calls, its line)``."""
+        import re
+
+        hlo = compiled.as_text()
+        (entry,) = re.findall(r"^ENTRY [^\n]*\{\n(.*?)^\}", hlo, re.M | re.S)
+        (table,) = re.findall(
+            r"(%%[\w.-]+) = f32\[%d,%d\]\S* parameter\(" % (self.ROWS, self.D),
+            entry)
+        return hlo, entry, [
+            line for line in entry.splitlines()
+            if re.search(r" fusion\([^)]*%s[,)]" % re.escape(table), line)]
+
+    def test_the_tolerance_reads_the_table_once(self, v5e_chip):
+        import re
+
+        from dask_ml_tpu.cluster import k_means as km
+        from dask_ml_tpu.core.mesh import MeshHolder
+
+        compiled = self._compiled(v5e_chip, km._tol, "table", "row", 1e-4,
+                                  mesh_holder=MeshHolder(v5e_chip))
+        # 129,024 B when written: the sampled rows and their sums
+        assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+        hlo, entry, fed = self._fed_by_the_table(compiled)
+        # behind a ``lax.cond`` a pass over the table asks for a copy of
+        # it in row-major tiles, 11.92 GB (PERF.md section 6, PR 37)
+        assert "conditional" not in hlo
+        sample = [line for line in fed if "gather" in line]
+        passes = [line for line in fed if "gather" not in line]
+        # the sample: about 1,024 rows at a fixed stride, not a read
+        (sample,) = sample
+        (rows,) = re.findall(r"= f32\[(\d+),%d\]" % self.D, sample)
+        assert 1024 <= int(rows) <= 1026
+        # ONE pass, with both column sums as its results
+        assert len(passes) == 1
+        assert re.search(
+            r"= \(f32\[%d\]\S*, f32\[%d\]\S*\) fusion\(" % (self.D, self.D),
+            passes[0])
+        # and nothing of the table's size is made anywhere
+        assert not re.search(r"= f32\[%d,%d\]\S* (?!parameter)" % (
+            self.ROWS, self.D), entry)
+
+    def test_four_chips_exchange_the_tolerances_sums_alone(self, v5e_devices):
+        """Each shard samples and sums its own rows: what crosses chips
+        is the sample's ``d + 1`` numbers and the pass's sums, all-reduced,
+        and nothing is gathered."""
+        import re
+
+        from jax.sharding import Mesh
+
+        from dask_ml_tpu.cluster import k_means as km
+        from dask_ml_tpu.core.mesh import MeshHolder
+
+        mesh = Mesh(np.array(v5e_devices[:4]).reshape(4, 1),
+                    ("data", "model"))
+        hlo = self._compiled(mesh, km._tol, "table", "row", 1e-4,
+                             rows=4 * self.ROWS,
+                             mesh_holder=MeshHolder(mesh)).as_text()
+        assert not re.search(r"all-gather|all-to-all|collective-permute",
+                             hlo)
+        reduced = re.findall(r"= ([^=\n]*?) all-reduce(?:-start)?\(", hlo)
+        assert len(reduced) == 2  # the sample's sums, then the pass's
+        for results in reduced:
+            for dims in re.findall(r"f32\[([0-9,]*)\]", results):
+                assert int(np.prod([int(n) for n in dims.split(",") if n]
+                                   or [1])) <= self.D + 1, results
+
+    def test_the_assignment_on_carried_norms_reads_the_table_once(
+            self, v5e_chip):
+        from dask_ml_tpu.cluster import k_means as km
+
+        given = self._compiled(
+            v5e_chip, km._assign, "table", "row", "centers", "row")
+        assert given.memory_analysis().temp_size_in_bytes < 1e6  # 0
+        (fusion,) = self._fed_by_the_table(given)[2]
+        assert "highest" in given.as_text()
+        assert "s32[%d]" % self.ROWS in fusion  # the distances' argmin
+
+    def test_the_three_operand_assignment_is_the_parents_program(
+            self, v5e_chip):
+        """What ``predict``, ``score`` and ``MiniBatchKMeans`` run: two
+        reads of the table and the norms as a temporary, as before."""
+        from dask_ml_tpu.cluster import k_means as km
+
+        plain = self._compiled(v5e_chip, km._assign, "table", "row",
+                               "centers")
+        # 100,108,800 B: |x|^2, written out between the two
+        assert plain.memory_analysis().temp_size_in_bytes >= 4 * self.ROWS
+        norms, distances = self._fed_by_the_table(plain)[2]
+        assert "= f32[%d]" % self.ROWS in norms
+        assert "s32[%d]" % self.ROWS in distances
+
+
 class TestTsqrRCompiledForTheChip:
     """ISSUE 32, at the benchmark's size (25,000,000 x 64 on one v5e; 100M
     rows over four): what the chip's compiler makes of ``tsqr.r``, the
