@@ -218,17 +218,19 @@ class _GLM(TPUEstimator):
         """``len(Cs)`` REGRESSION fits differing only in ``C`` as one
         vmapped program (``solvers.lambda_sweep``); the grid-search fast
         path calls this for identity-link families.  Eligibility (no
-        sample weights) is the caller's job.  Returns betas (K, p)."""
+        sample weights) is the caller's job.  Returns (betas (K, p),
+        counts (K, n)), both on the host: each lane's counts
+        (``lambda_sweep(return_counts=True)``: iterations first) come in
+        the one ``device_get`` that brings its coefficients."""
         from ..solvers import lambda_sweep
 
         X = _ingest_float(self, X)
         kwargs = self._solver_call_kwargs()
         kwargs.pop("lamduh")
-        betas, _ = lambda_sweep(
+        return jax.device_get(lambda_sweep(
             self.solver, X, y, [1.0 / float(c) for c in Cs],
-            family=self.family, **kwargs,
-        )
-        return betas
+            family=self.family, return_counts=True, **kwargs,
+        ))
 
     def fit(self, X, y=None, sample_weight=None):
         # the fit's spans (live under ``obs.enable()`` or a profiler
@@ -324,7 +326,10 @@ class LogisticRegression(ClassifierMixin, _GLM):
         grid-search fast path calls this; eligibility (binary labels,
         no sample/class weights, plain ovr) is the CALLER's job.
 
-        Returns (betas (K, p), classes (2,)).
+        Returns (betas (K, p), classes (2,), counts (K, n)), all on the
+        host: each lane's counts (``lambda_sweep(return_counts=True)``:
+        iterations first) come in the one ``device_get`` that brings its
+        coefficients.
         """
         from ..core.sharded import ShardedRows as _SR
         from ..core.sharded import as_sharded
@@ -344,11 +349,11 @@ class LogisticRegression(ClassifierMixin, _GLM):
         y01 = binary_indicator(y, classes[1])
         kwargs = self._solver_call_kwargs()
         kwargs.pop("lamduh")
-        betas, _ = lambda_sweep(
+        betas, counts = jax.device_get(lambda_sweep(
             self.solver, X, y01, [1.0 / float(c) for c in Cs],
-            family=self.family, **kwargs,
-        )
-        return betas, classes
+            family=self.family, return_counts=True, **kwargs,
+        ))
+        return betas, classes, counts
 
     def _fit(self, X, y, sample_weight, root):
         # warm start (an improvement over the reference: dask_glm ignores

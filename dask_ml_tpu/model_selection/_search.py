@@ -24,13 +24,16 @@ import threading
 
 from .._locks import make_lock
 from concurrent.futures import ThreadPoolExecutor, as_completed
+from contextlib import nullcontext
 
 import numpy as np
 
+from .. import obs as _obs
 from ..base import TPUEstimator, clone
 from ..core.sharded import ShardedRows, masked_unique, unshard
 from ..metrics.scorer import check_scoring
 from ..utils import check_random_state
+from ._split import KFold, _fold_slabs, _take_host_bytes
 from ._split import _take as _rows  # pandas/array/ShardedRows row subset
 
 
@@ -134,6 +137,34 @@ logger = logging.getLogger(__name__)
 
 def _host(a):
     return unshard(a) if isinstance(a, ShardedRows) else a
+
+
+def _publish_lanes(span, counts):
+    """A finished sweep's lanes onto ``search.sweep``, from each lane's
+    counts (``lambda_sweep(return_counts=True)``, on the host): the
+    iterations' extremes and ``lane_idle_iters``, the iterations the
+    finished lanes sat out while the slowest ran (one vmapped
+    ``while_loop`` turns until its last lane is done); where the runner
+    counts them (``SOLVE_COUNTS``), ``passes_max``, the most reads of the
+    train rows any lane's solve made, and ``trials_max``, the most of them
+    any lane's line searches made: the lanes' objective is a black box,
+    whose every trial is a pass, and a solve's other passes are one at
+    its start (ADMM: a round) and one an iteration.  Both are least
+    counts of the program's own: the lanes search in lock-step, so a turn
+    costs what its slowest lane takes.  The lanes go into the always-on
+    registry."""
+    from ..solvers.algorithms import SOLVE_COUNTS
+
+    counts = np.asarray(counts).astype(np.int64)
+    col = dict(zip(SOLVE_COUNTS, counts.reshape(len(counts), -1).T))
+    iters = col["rounds"]
+    span.set(iters_max=int(iters.max()), iters_min=int(iters.min()),
+             lane_idle_iters=int((iters.max() - iters).sum()))
+    if "passes" in col:
+        starts = iters if "rho_moves" in col else 1
+        span.set(passes_max=int(col["passes"].max()), trials_max=int(
+            (col["passes"] - col["inner_iters"] - starts).max()))
+    _obs.registry().counter("search.lanes").inc(int(iters.size))
 
 
 def _fold_classes_ok(ytr, yte) -> bool:
@@ -372,6 +403,31 @@ class _BaseSearchCV(TPUEstimator):
         return toks
 
     def fit(self, X, y=None, **fit_params):
+        # the search's spans (live under ``obs.enable()`` or a profiler
+        # session): ``search.fit`` is the root; ``search.split``, a
+        # ``search.fold`` for every fold made, the packed path's
+        # ``search.sweep`` and ``search.score`` a fold, and
+        # ``search.refit``, under which the winner's own tree hangs
+        with _obs.span("search.fit", search=type(self).__name__,
+                       estimator=type(self.estimator).__name__) as root:
+            _obs.registry().counter("search.fits").inc()
+            return self._fit(X, y, fit_params, root)
+
+    def _slab_splitter(self, y):
+        """This package's unshuffled ``KFold`` where the search's folds are
+        its contiguous slabs and every label lives on the device (so that
+        no stratification was asked of host labels): what ``cv=None`` and
+        ``cv=<int>`` mean there, or such a ``KFold`` handed in.  None for
+        every other splitter, whose folds are index arrays."""
+        if not (y is None or isinstance(y, ShardedRows)):
+            return None
+        if self.cv is None or isinstance(self.cv, int):
+            return KFold(n_splits=5 if self.cv is None else self.cv)
+        if type(self.cv) is KFold and not self.cv.shuffle:
+            return self.cv
+        return None
+
+    def _fit(self, X, y, fit_params, root):
         from ..core.sharded import as_sharded
         from ..utils import check_consistent_length
 
@@ -385,53 +441,69 @@ class _BaseSearchCV(TPUEstimator):
             check_consistent_length(X, y)
         X, y = as_sharded(X), as_sharded(y)
         device_path = isinstance(X, ShardedRows) and self._device_capable()
-        if device_path:
-            # sharded input stays ON DEVICE through the whole search:
-            # folds are sliced by the device-side gather in
-            # _split._take, models fit/score sharded folds, and
-            # only scalar scores come back to host.  The reference keeps
-            # blocks worker-resident the same way (``_search.py ::
-            # build_graph``).
-            Xh, yh = X, y
-            n = X.n_samples
-            explicit_cv = self.cv is not None and not isinstance(self.cv, int)
-            if y is not None and not isinstance(y, ShardedRows):
-                # y already lives on host: stratified defaults cost
-                # nothing — keep round-2 semantics for classifiers
-                y_split = np.asarray(y)
-            elif explicit_cv and y is not None:
-                # a user-chosen splitter may stratify on labels — that
-                # takes a host copy of y (1-D, the only O(n) fetch here)
-                y_split = np.asarray(_host(y))
+        slabs = False
+        with _obs.span("search.split") as split_span:
+            if device_path:
+                # sharded input stays ON DEVICE through the whole search:
+                # folds are cut on the device (slabs by _split._fold_slabs,
+                # index arrays by the gather in _split._take), models
+                # fit/score sharded folds, and only scalar scores come
+                # back to host.  The reference keeps blocks
+                # worker-resident the same way (``_search.py ::
+                # build_graph``).
+                Xh, yh = X, y
+                n = X.n_samples
+                cv = self._slab_splitter(y)
+                if cv is not None:
+                    # index-free KFold, like the reference's array path (a
+                    # lazy dask array cannot be stratified either): a fold
+                    # is its bounds, and nothing of the table's length is
+                    # made on the host.  This DIFFERS from the host path's
+                    # stratified default for classifiers: say so, and how
+                    # to get stratification.
+                    slabs = True
+                    edges = cv.bounds(n)
+                    splits = list(zip(edges[:-1].tolist(), edges[1:].tolist()))
+                    from sklearn.base import is_classifier
+
+                    if (y is not None and cv is not self.cv
+                            and is_classifier(self.estimator)):
+                        import warnings
+
+                        warnings.warn(
+                            "sharded input uses unshuffled KFold (no "
+                            "stratification) — class-sorted labels can "
+                            "yield single-class folds; pass an explicit "
+                            "splitter (e.g. StratifiedKFold) to stratify at "
+                            "the cost of one 1-D label fetch",
+                            UserWarning, stacklevel=3,
+                        )
+                else:
+                    if y is not None and not isinstance(y, ShardedRows):
+                        # y already lives on host: stratified defaults
+                        # cost nothing — keep round-2 semantics for
+                        # classifiers
+                        y_split = np.asarray(y)
+                    elif y is not None:
+                        # a user-chosen splitter may stratify on labels —
+                        # that takes a host copy of y (1-D, the only O(n)
+                        # fetch here)
+                        y_split = np.asarray(_host(y))
+                    else:
+                        y_split = None
+                    cv = self._resolve_cv(y_split)
+                    splits = list(cv.split(np.empty((n, 0)), y_split))
             else:
-                # index-only KFold by default, like the reference's array
-                # path (a lazy dask array cannot be stratified either).
-                # This DIFFERS from the host path's stratified default for
-                # classifiers — say so, and how to get stratification.
-                y_split = None
-                from sklearn.base import is_classifier
-
-                if y is not None and is_classifier(self.estimator):
-                    import warnings
-
-                    warnings.warn(
-                        "sharded input uses unshuffled KFold (no "
-                        "stratification) — class-sorted labels can yield "
-                        "single-class folds; pass an explicit splitter "
-                        "(e.g. StratifiedKFold) to stratify at the cost "
-                        "of one 1-D label fetch",
-                        UserWarning, stacklevel=2,
-                    )
-            cv = self._resolve_cv(y_split)
-            splits = list(cv.split(np.empty((n, 0)), y_split))
-        else:
-            Xh, yh = _host(X), _host(y) if y is not None else None
-            cv = self._resolve_cv(yh)
-            splits = list(cv.split(Xh, yh))
+                Xh, yh = _host(X), _host(y) if y is not None else None
+                cv = self._resolve_cv(yh)
+                splits = list(cv.split(Xh, yh))
+            split_span.set(splitter=type(cv).__name__, slabs=int(slabs),
+                           folds=len(splits))
         candidates = list(self._get_param_iterator())
         if not candidates:
             raise ValueError("No candidate parameters")
         scorers, multimetric = self._resolve_scorers()
+        root.set(candidates=len(candidates), folds=len(splits))
 
         # prefix-transform cache: (pipeline prefix token) -> fitted step +
         # transformed data, compute-once under the thread pool, entries
@@ -480,21 +552,40 @@ class _BaseSearchCV(TPUEstimator):
         # is exactly the ShardedRows one.
         _fold_cacheable = isinstance(Xh, ShardedRows)
 
-        def _fold_slices(fi):
-            tr, te = splits[fi]
-            return (
-                _rows(Xh, tr),
-                _rows(yh, tr) if yh is not None else None,
-                _rows(Xh, te),
-                _rows(yh, te) if yh is not None else None,
-            )
+        root_id = root.span_id
 
-        def fold_get(fi):
+        def _fold_slices(fi, span=None):
+            """Fold ``fi``'s (Xtr, ytr, Xte, yte), under the caller's
+            ``search.fold`` span or one of its own (a worker thread's has
+            no open parent: the root is named); what the fold took goes
+            on the span."""
+            with (_obs.span("search.fold", parent=root_id, fold=fi)
+                  if span is None else nullcontext(span)) as span:
+                _obs.registry().counter("search.folds").inc()
+                if slabs:
+                    lo, hi = splits[fi]
+                    span.set(rows_train=n - (hi - lo), rows_test=hi - lo,
+                             host_index_bytes=0)
+                    return _fold_slabs(Xh, yh, lo, hi)
+                tr, te = splits[fi]
+                span.set(rows_train=len(tr), rows_test=len(te),
+                         host_index_bytes=sum(
+                             np.asarray(i).nbytes for i in (tr, te)) + sum(
+                             _take_host_bytes(a, i)
+                             for i in (tr, te) for a in (Xh, yh)))
+                return (
+                    _rows(Xh, tr),
+                    _rows(yh, tr) if yh is not None else None,
+                    _rows(Xh, te),
+                    _rows(yh, te) if yh is not None else None,
+                )
+
+        def fold_get(fi, span=None):
             if not _fold_cacheable:
-                return _fold_slices(fi)
+                return _fold_slices(fi, span)
             with fold_lock:
                 if fi not in fold_cache:
-                    fold_cache[fi] = _fold_slices(fi)
+                    fold_cache[fi] = _fold_slices(fi, span)
                 return fold_cache[fi]
 
         def fold_release(fi):
@@ -507,6 +598,7 @@ class _BaseSearchCV(TPUEstimator):
             candidates, len(splits), fold_get, fold_release, scorers,
             fit_params, test_scores, train_scores,
         )
+        root.set(packed=int(packed_done))
         if not packed_done:
             # a mid-way packed fallback consumed some folds' refcounts;
             # restore the full budget for the per-task path
@@ -626,11 +718,12 @@ class _BaseSearchCV(TPUEstimator):
                 )
             self.best_params_ = candidates[self.best_index_]
         if self.refit:
-            best = clone(self.estimator).set_params(**self.best_params_)
-            if yh is not None:
-                best.fit(Xh, yh, **fit_params)
-            else:
-                best.fit(Xh, **fit_params)
+            with _obs.span("search.refit", candidate=self.best_index_):
+                best = clone(self.estimator).set_params(**self.best_params_)
+                if yh is not None:
+                    best.fit(Xh, yh, **fit_params)
+                else:
+                    best.fit(Xh, **fit_params)
             self.best_estimator_ = best
         return self
 
@@ -647,12 +740,17 @@ class _BaseSearchCV(TPUEstimator):
         Gated on ``pack_strategy() == "packed"`` (vmap packing measured
         SLOWER on CPU, r3 ``packed_speedup 0.684``); ineligible grids
         fall through to the per-task path.  Returns True when it filled
-        the score arrays.
+        the score arrays, and then leaves ``coefs_paths_`` (folds,
+        candidates, p), each fold's lanes as they came to the host, the
+        intercept last (what ``LogisticRegressionCV`` keeps under that
+        name); a search that took the per-task path has no such
+        attribute.
         """
         from ..linear_model import LinearRegression as _OLS
         from ..linear_model import LogisticRegression as _LR
         from ..solvers import grid_pack_strategy
 
+        self.__dict__.pop("coefs_paths_", None)  # an earlier fit's
         est = self.estimator
         is_clf = type(est) is _LR
         is_reg = type(est) is _OLS  # identity link: R² scores by gemm
@@ -670,47 +768,58 @@ class _BaseSearchCV(TPUEstimator):
         if set(scorers) != {"score"}:
             return False
         Cs = [p["C"] for p in candidates]
+        paths = []
         filled_test = np.empty((len(Cs), n_folds))
         filled_train = (
             np.empty_like(filled_test) if self.return_train_score else None
         )
         try:
             for fi in range(n_folds):
-                Xtr, ytr, Xte, yte = fold_get(fi)
                 try:
-                    if ytr is None or yte is None:
-                        return False
-                    sweep_est = clone(est)
-                    if is_clf:
-                        # eligibility BEFORE the K-lane fit (a doomed
-                        # fold must not execute the whole vmapped solve
-                        # only to discard it): the train fold must be
-                        # exactly binary, and every test label must be
-                        # among the train classes — the packed scorer
-                        # encodes labels against the TRAIN fold's 2
-                        # classes, so an unseen test label would encode
-                        # to 0 and count as a hit whenever eta<=0 (the
-                        # per-candidate path counts it as a miss).
-                        if not _fold_classes_ok(ytr, yte):
+                    # eligibility BEFORE the K-lane fit (a doomed fold
+                    # must not execute the whole vmapped solve only to
+                    # discard it): the train fold must be exactly binary,
+                    # and every test label must be among the train
+                    # classes — the packed scorer encodes labels against
+                    # the TRAIN fold's 2 classes, so an unseen test label
+                    # would encode to 0 and count as a hit whenever
+                    # eta<=0 (the per-candidate path counts it as a
+                    # miss).  The check's wait is the fold's: it is where
+                    # the host first needs what the fold's program made.
+                    with _obs.span("search.fold", fold=fi) as span:
+                        Xtr, ytr, Xte, yte = fold_get(fi, span)
+                        if ytr is None or yte is None or (
+                                is_clf and not _fold_classes_ok(ytr, yte)):
                             return False
-                        betas, classes = sweep_est._sweep_fit_binary(
-                            Xtr, ytr, Cs)
+                    sweep_est = clone(est)
+                    with _obs.span("search.sweep", fold=fi,
+                                   lanes=len(Cs)) as span:
+                        if is_clf:
+                            betas, classes, counts = (
+                                sweep_est._sweep_fit_binary(Xtr, ytr, Cs))
 
-                        def sc(Xf, yf):
-                            return _sweep_accuracy(
-                                Xf, yf, betas, classes, est.fit_intercept)
-                    else:
-                        betas = sweep_est._sweep_fit_values(Xtr, ytr, Cs)
+                            def sc(Xf, yf):
+                                return _sweep_accuracy(
+                                    Xf, yf, betas, classes,
+                                    est.fit_intercept)
+                        else:
+                            betas, counts = sweep_est._sweep_fit_values(
+                                Xtr, ytr, Cs)
 
-                        def sc(Xf, yf):
-                            return _sweep_r2(
-                                Xf, yf, betas, est.fit_intercept)
-                    filled_test[:, fi] = np.asarray(sc(Xte, yte))
-                    if filled_train is not None:
-                        filled_train[:, fi] = np.asarray(sc(Xtr, ytr))
+                            def sc(Xf, yf):
+                                return _sweep_r2(
+                                    Xf, yf, betas, est.fit_intercept)
+                        _publish_lanes(span, counts)
+                        paths.append(betas)
+                    with _obs.span("search.score", fold=fi):
+                        filled_test[:, fi] = np.asarray(sc(Xte, yte))
+                        if filled_train is not None:
+                            filled_train[:, fi] = np.asarray(sc(Xtr, ytr))
                 finally:
                     # one fold live at a time: this path consumes ALL
-                    # n_cand reservations of the fold it just finished
+                    # n_cand reservations of the fold it just finished,
+                    # and lets go of it before the next one is cut
+                    Xtr = ytr = Xte = yte = None
                     for _ in range(len(Cs)):
                         fold_release(fi)
         except Exception:
@@ -723,6 +832,7 @@ class _BaseSearchCV(TPUEstimator):
                 "per-candidate fits", exc_info=True,
             )
             return False
+        self.coefs_paths_ = np.stack(paths)
         test_scores["score"][:, :] = filled_test
         if train_scores is not None and filled_train is not None:
             train_scores["score"][:, :] = filled_train

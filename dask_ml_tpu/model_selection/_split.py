@@ -4,7 +4,10 @@
 The reference splits blockwise (per-chunk shuffles, contiguous slabs).
 Here splits are index-based on the host (indices are O(n) ints) and the
 selected rows are gathered device-side, so a split of a sharded array
-yields sharded arrays without materializing X on the host.
+yields sharded arrays without materializing X on the host.  The one
+splitter whose folds ARE contiguous slabs, this module's unshuffled
+``KFold``, needs no indices at all on sharded input: a fold is its bounds
+``(lo, hi)`` and ``_fold_slabs`` cuts it on the device.
 """
 
 from __future__ import annotations
@@ -16,7 +19,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..core.mesh import MeshHolder, get_mesh
+from ..core.mesh import MeshHolder, data_axes_size, get_mesh
 from ..core.sharded import ShardedRows, row_sharding
 from ..utils import check_random_state
 
@@ -46,8 +49,6 @@ def _take(a, idx):
         from ..core.sharded import pad_rows
 
         mesh = get_mesh()
-        from ..core.mesh import data_axes_size
-
         n_shards = data_axes_size(mesh)
         idx, k = pad_rows(np.asarray(idx, dtype=np.int32), n_shards)
         mask_np = np.zeros(idx.shape[0], dtype=np.float32)
@@ -62,6 +63,63 @@ def _take(a, idx):
         # and returns dataframes)
         return a.iloc[idx]
     return np.asarray(a)[idx]
+
+
+def _take_host_bytes(a, idx) -> int:
+    """Bytes of the index and mask arrays ``_take(a, idx)`` makes on the
+    host and sends to the device (``int32`` + ``float32`` a padded row);
+    0 for anything but sharded rows."""
+    if not isinstance(a, ShardedRows):
+        return 0
+    return 8 * (len(idx) + (-len(idx)) % data_axes_size(get_mesh()))
+
+
+@partial(jax.jit, static_argnames=("lo", "hi", "n", "mesh_holder"))
+def _fold_slabs_fn(arrays, *, lo, hi, n, mesh_holder):
+    """One fold of an unshuffled ``KFold`` as slabs: for each array of
+    ``arrays`` (rows ``[0, n)`` real) its train rows ``[0, lo) + [hi, n)``
+    and its held-out rows ``[lo, hi)``, and one mask for either side.
+    What ``_gather_rows`` returns for the sorted indices, bit for bit (pad
+    rows repeat row 0, as a gather by a zero-padded index does), with no
+    index: slices and a concatenation, row-sharded over the mesh."""
+    mesh = mesh_holder.mesh
+    shards = data_axes_size(mesh)
+
+    def side(x, pieces, k):
+        pad = (-k) % shards
+        if pad:
+            pieces = pieces + [jnp.broadcast_to(x[:1], (pad,) + x.shape[1:])]
+        out = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces)
+        return jax.lax.with_sharding_constraint(
+            out, row_sharding(mesh, x.ndim))
+
+    def mask(k):
+        rows = k + (-k) % shards
+        return jax.lax.with_sharding_constraint(
+            (jnp.arange(rows) < k).astype(jnp.float32),
+            row_sharding(mesh, 1))
+
+    k_test = hi - lo
+    train = [side(x, [x[:lo], x[hi:n]], n - k_test) for x in arrays]
+    test = [side(x, [x[lo:hi]], k_test) for x in arrays]
+    return train, mask(n - k_test), test, mask(k_test)
+
+
+def _fold_slabs(X, y, lo: int, hi: int):
+    """``(Xtr, ytr, Xte, yte)``: the fold of ``X`` (``ShardedRows``) and
+    ``y`` (``ShardedRows`` of its length, or None) whose held-out rows are
+    ``[lo, hi)``, cut on the device by ONE program.  Nothing of the
+    table's length is made on the host or crosses to the device; the
+    arrays of one side share one mask."""
+    n, k_test = X.n_samples, int(hi) - int(lo)
+    train, m_train, test, m_test = _fold_slabs_fn(
+        tuple(a.data for a in (X, y) if a is not None), lo=int(lo),
+        hi=int(hi), n=n, mesh_holder=MeshHolder(get_mesh()))
+    tr = [ShardedRows(data=d, mask=m_train, n_samples=n - k_test)
+          for d in train] + [None]
+    te = [ShardedRows(data=d, mask=m_test, n_samples=k_test)
+          for d in test] + [None]
+    return tr[0], tr[1], te[0], te[1]
 
 
 def _as_count(v, n):
@@ -122,16 +180,22 @@ class KFold:
         self.shuffle = shuffle
         self.random_state = random_state
 
-    def split(self, X, y=None, groups=None):
-        n = _n_samples(X)
+    def bounds(self, n: int):
+        """The folds' edges in ``[0, n]``, ``n_splits + 1`` of them: fold
+        ``i`` holds out positions ``[bounds[i], bounds[i + 1])`` (of the
+        rows themselves when unshuffled)."""
         if self.n_splits < 2:
             raise ValueError("n_splits must be >= 2")
         if self.n_splits > n:
             raise ValueError(f"n_splits={self.n_splits} > n_samples={n}")
+        return np.linspace(0, n, self.n_splits + 1, dtype=int)
+
+    def split(self, X, y=None, groups=None):
+        n = _n_samples(X)
+        bounds = self.bounds(n)
         idx = np.arange(n)
         if self.shuffle:
             check_random_state(self.random_state).shuffle(idx)
-        bounds = np.linspace(0, n, self.n_splits + 1, dtype=int)
         for lo, hi in zip(bounds[:-1], bounds[1:]):
             test = idx[lo:hi]
             train = np.concatenate([idx[:lo], idx[hi:]])

@@ -998,7 +998,8 @@ def lambda_sweep(solver: str, X, y, lams, *, family: type[Family] = Logistic,
                  regularizer=L2, max_iter: int = 100, tol: float = 1e-5,
                  rho: float = 1.0, abstol: float = 1e-4, reltol: float = 1e-2,
                  inner_iter: int = 50, inner_tol: float = 1e-6, mesh=None,
-                 line_search: str = "backtrack", intercept: bool = False):
+                 line_search: str = "backtrack", intercept: bool = False,
+                 return_counts: bool = False):
     """All K solves of the SAME (X, y) at different regularization
     strengths as ONE vmapped program — the grid-search twin of
     ``packed_solve`` (there the lanes differ in y, here in ``lamduh``,
@@ -1009,7 +1010,12 @@ def lambda_sweep(solver: str, X, y, lams, *, family: type[Family] = Logistic,
     opposite CPU signs) and keeps its per-candidate path where packing
     measured slower.
 
-    Returns (betas (K, pdim), n_iters (K,)).
+    Returns (betas (K, pdim), n_iters (K,)).  ``return_counts=True``
+    (as ``lbfgs`` and ``admm`` take it) returns each lane's whole count
+    in the iterations' place, (K, n): the counted runners' vector
+    :data:`SOLVE_COUNTS` lays out, a single column of iterations for the
+    others.  The lanes' objective is a black box, so a lane's ``trials``
+    are 0 and its searches' trials are among its ``passes``.
     """
     reg = get_regularizer(regularizer)
     if line_search != "backtrack":
@@ -1038,7 +1044,7 @@ def lambda_sweep(solver: str, X, y, lams, *, family: type[Family] = Logistic,
                 inner_iter=inner_iter, line_search=line_search,
                 objective=objective,
             )
-            return beta, counts[0]
+            return beta, counts if return_counts else counts[0]
 
         return jax.vmap(one_a)(lam_v)
     runners = {
@@ -1065,14 +1071,29 @@ def lambda_sweep(solver: str, X, y, lams, *, family: type[Family] = Logistic,
     if solver == "lbfgs":
         extra_kw["objective"] = objective
 
+    return _sweep_lanes(
+        x, yd, mask, lam_v, B0, jnp.int32(max_iter), jnp.asarray(tol, dt),
+        run=run, family=family, reg=reg, extra_kw=tuple(extra_kw.items()),
+        counts=return_counts)
+
+
+@partial(jax.jit, static_argnames=(
+    "run", "family", "reg", "extra_kw", "counts"))
+def _sweep_lanes(x, yv, mask, lams, B0, max_iter, tol, *, run, family, reg,
+                 extra_kw, counts=False):
+    """``lambda_sweep``'s K lanes of one whole-solve runner, vmapped over
+    ``lams`` and their starts: a program of its own name, so that a trace
+    tells the lanes (``jit__sweep_lanes``) from the single solve the
+    runner is elsewhere (``jit__lbfgs_run``: a search's refit)."""
+
     def one(lam, b0):
         beta, n_it = run(
-            x, yd, mask, b0, lam, jnp.int32(max_iter),
-            jnp.asarray(tol, dt), family=family, reg=reg, **extra_kw,
+            x, yv, mask, b0, lam, max_iter, tol, family=family, reg=reg,
+            **dict(extra_kw),
         )
-        return beta, _iterations(n_it)
+        return beta, jnp.atleast_1d(n_it) if counts else _iterations(n_it)
 
-    return jax.vmap(one)(lam_v, B0)
+    return jax.vmap(one)(lams, B0)
 
 
 def _exit_counts(st):

@@ -27,6 +27,7 @@ import logging
 import numbers
 import os
 import re
+from unittest import mock
 
 import numpy as np
 import pytest
@@ -55,7 +56,8 @@ PROGRAM_METRICS = [
 NAMED_PROGRAMS = sorted({
     (name, module)
     for name, cfg in CONFIGS.items()
-    for key in ("solve_modules", "init_modules", "factor_modules")
+    for key in ("solve_modules", "init_modules", "factor_modules",
+                "sweep_modules")
     for module in cfg.get(key, ())
 })
 
@@ -89,8 +91,14 @@ def _fit(cfg, rows=ROWS):
         '"$seed"', "0"))
     X, y = _table(cfg, rows)
     est = make(**args)
-    est.fit(shard_rows(X)) if y is None else est.fit(
-        shard_rows(X), shard_rows(y))
+    # a search's candidates pack into lanes on a TPU, where the cells run
+    # (the CPU's ``auto`` fits them one by one, and opens no
+    # ``search.sweep``): the search's configuration alone is told so
+    packs = ({"DASK_ML_TPU_GRID_PACK": "packed"} if "sweep_modules" in cfg
+             else {})
+    with mock.patch.dict(os.environ, packs):
+        est.fit(shard_rows(X)) if y is None else est.fit(
+            shard_rows(X), shard_rows(y))
     return est
 
 

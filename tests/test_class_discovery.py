@@ -189,7 +189,7 @@ def test_every_device_site_calls_the_helper(site):
     if site == "sweep":
         from dask_ml_tpu.linear_model import LogisticRegression
 
-        _, classes = LogisticRegression(
+        _, classes, _ = LogisticRegression(
             solver="lbfgs", max_iter=2)._sweep_fit_binary(
                 shard_rows(X), y, [0.1, 1.0])
         np.testing.assert_array_equal(classes, [-2, 4])

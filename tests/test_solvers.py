@@ -1487,3 +1487,96 @@ class TestTsqrRCompiledForTheChip:
         for shape in shapes:
             dims = [int(n) for n in re.findall(r"\d+", shape.split("[")[1])]
             assert int(np.prod(dims or [1])) <= self.D * self.D, shape
+
+
+class TestSearchCompiledForTheChip:
+    """ISSUE 39, at the benchmark's size (31,250,000 x 28 on one v5e,
+    ``cv=3``, eight values of ``C``): what the chip's compiler makes of a
+    search's programs: a fold cut as slabs, the fold's eight lanes, the
+    gemm that scores them.  Compiled, never run.  (In this file because
+    one process at a time may load the TPU's library: ``v5e_chip``.)"""
+
+    ROWS, D, LANES = 31_250_000, 28, 8
+    EDGES = (0, 10_416_666, 20_833_333, 31_250_000)
+    TABLE = 31_250_000 * 32 * 4  # 28 columns lie on 32 sublanes
+
+    @staticmethod
+    def _shapes(mesh):
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        def S(shape, dtype=jnp.float32):
+            spec = P("data", *[None] * (len(shape) - 1)) if shape and (
+                shape[0] > 1000) else P()
+            return jax.ShapeDtypeStruct(
+                shape, dtype, sharding=NamedSharding(mesh, spec))
+
+        return S
+
+    @pytest.mark.parametrize("fold", [0, 1, 2])
+    def test_a_fold_is_slices_and_at_most_one_slab_of_temporaries(
+            self, v5e_chip, fold):
+        import re
+
+        from dask_ml_tpu.core.mesh import MeshHolder
+        from dask_ml_tpu.model_selection import KFold
+        from dask_ml_tpu.model_selection._split import _fold_slabs_fn
+
+        assert tuple(KFold(3).bounds(self.ROWS)) == self.EDGES
+        S = self._shapes(v5e_chip)
+        lo, hi = self.EDGES[fold:fold + 2]
+        with _compile_cache_off():
+            compiled = _fold_slabs_fn.lower(
+                (S((self.ROWS, self.D)), S((self.ROWS,))), lo=lo, hi=hi,
+                n=self.ROWS, mesh_holder=MeshHolder(v5e_chip)).compile()
+        memory = compiled.memory_analysis()
+        # what the caller holds: the table and its labels (no index, no
+        # mask goes in), and what comes out: both sides of both, and a
+        # mask a side: a second table and three row vectors
+        assert memory.argument_size_in_bytes < self.TABLE + 1.01 * 4 * self.ROWS
+        assert memory.output_size_in_bytes < self.TABLE + 3.01 * 4 * self.ROWS
+        # an end fold's train side is ONE slice: nothing beside the
+        # output.  The middle fold's is two pieces, which the compiler
+        # cuts out before it joins them: the train slab once more
+        # (2,666,821,632 B when written), never the table
+        slab = (self.ROWS - (hi - lo)) * 32 * 4
+        assert memory.temp_size_in_bytes <= (1.001 * slab if fold == 1 else 0)
+        hlo = compiled.as_text()
+        assert not re.search(r" gather\(| sort\(", hlo)  # nothing by index
+
+    def test_eight_lanes_hold_a_fold_and_their_temporaries(self, v5e_chip):
+        from dask_ml_tpu.solvers.algorithms import _lbfgs_run, _sweep_lanes
+
+        S = self._shapes(v5e_chip)
+        rows = self.ROWS - self.EDGES[1]  # the first fold's train rows
+        with _compile_cache_off():
+            compiled = _sweep_lanes.lower(
+                S((rows, self.D)), S((rows,)), S((rows,)), S((self.LANES,)),
+                S((self.LANES, self.D + 1)), S((), jnp.int32), S(()),
+                run=_lbfgs_run, family=Logistic, reg=L2,
+                extra_kw=(("line_search", "backtrack"),
+                          ("objective", "black_box")),
+                counts=True).compile()  # as the search calls it
+        memory = compiled.memory_analysis()
+        vector = rows * 4
+        assert memory.argument_size_in_bytes < rows * 32 * 4 + 2.01 * vector
+        # each lane's eta, residual and trial point at a row's length:
+        # 17 row vectors when written (1,420,318,720 B), 3.4 GB under the
+        # chip's 15.75 with the table and the fold resident (8.5 GB)
+        assert memory.temp_size_in_bytes < 17.5 * vector
+        assert "jit__sweep_lanes" in compiled.as_text()[:200]
+
+    def test_the_scoring_gemm_makes_no_rows_by_lanes_array(self, v5e_chip):
+        from dask_ml_tpu.model_selection._search import _sweep_kernels
+
+        S = self._shapes(v5e_chip)
+        rows = self.EDGES[1]  # a held-out slab
+        acc, _ = _sweep_kernels()
+        with _compile_cache_off():
+            compiled = acc.lower(
+                S((rows, self.D)), S((rows,)), S((rows,)),
+                S((self.LANES, self.D + 1)), fit_intercept=True).compile()
+        # eta for eight lanes would be rows x 8 (1.3 GB on eight
+        # sublanes): the compare, the mask and the sum fuse into the
+        # product's consumer
+        assert compiled.memory_analysis().temp_size_in_bytes < rows * 4
