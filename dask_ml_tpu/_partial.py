@@ -14,6 +14,18 @@ strictly serial seed behavior).  ``x`` may also be an ITERATOR of blocks
 (``io.stream_csv_blocks``, ``io.stream_binary_blocks``, or any generator
 yielding ``X`` or ``(X, y)``) for out-of-core streams that never exist
 as one array.
+
+A table held on the device takes no stream: where ``x`` and ``y`` are
+both ``ShardedRows`` and the model is this package's ``SGDClassifier``
+or ``SGDRegressor`` without per-row weights, :func:`fit` makes the pass
+ONE program over the resident blocks (``linear_model/_sgd_pass.py``).
+What that pass guarantees: every real row trains the model once, in its
+block; the blocks come in the order the stream would visit them (the
+same ``shuffle_blocks`` / ``random_state`` rule); one SGD step a block,
+the state carried across calls and never reinitialised by one; nothing
+of the table's size crosses to the host (the host sends the blocks'
+order); the answer is the host stream's over the same blocks to float32
+rounding.
 """
 
 from __future__ import annotations
@@ -110,7 +122,17 @@ def fit(model, x, y=None, *, chunk_size: int | None = None, shuffle_blocks=False
                 fit_kwargs=kwargs,
             )
 
-    xv = unshard(x) if isinstance(x, ShardedRows) else np.asarray(x)
+    resident = False
+    if isinstance(x, ShardedRows):
+        from .linear_model import _sgd_pass
+
+        resident = _sgd_pass.takes(model, x, y, kwargs)
+
+    def host(a):
+        return unshard(a) if isinstance(a, ShardedRows) else np.asarray(a)
+
+    xv = x if resident else host(x)
+    n = xv.shape[0]
     if chunk_size is None:
         from .linear_model._sgd import DEFAULT_STREAM_CHUNK
 
@@ -118,19 +140,22 @@ def fit(model, x, y=None, *, chunk_size: int | None = None, shuffle_blocks=False
     else:
         # accept dask-style (rows, cols) specs too; validates positivity
         chunk_size = check_chunks(
-            xv.shape[0], xv.shape[1] if xv.ndim > 1 else None, chunk_size
+            n, xv.shape[1] if len(xv.shape) > 1 else None, chunk_size
         )
     yv = None
     if y is not None:
-        yv = unshard(y) if isinstance(y, ShardedRows) else np.asarray(y)
-        if yv.shape[0] != xv.shape[0]:
+        yv = y if resident else host(y)
+        if yv.shape[0] != n:
             raise ValueError(
-                f"x and y have different lengths: {xv.shape[0]} vs {yv.shape[0]}"
+                f"x and y have different lengths: {n} vs {yv.shape[0]}"
             )
-    spans = list(_row_chunks(xv.shape[0], chunk_size))
+    spans = list(_row_chunks(n, chunk_size))
     if shuffle_blocks:
         rng = check_random_state(random_state)
         rng.shuffle(spans)
+    if resident:
+        return _sgd_pass.run(model, xv, yv, [lo for lo, _ in spans],
+                             chunk_size, kwargs)
 
     def _blocks():
         for i, (lo, hi) in enumerate(spans):

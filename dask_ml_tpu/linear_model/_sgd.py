@@ -103,6 +103,23 @@ def _margin_losses(loss: str, margins, ysigned):
     return ell, dz * ysigned
 
 
+def ova_targets(ydata, classes):
+    """Device labels -> ±1 one-vs-all targets ``[n, K]`` (``K = 1`` for
+    two classes), the device twin of ``SGDClassifier._encode_targets``.
+    Pure and jit-safe, one elementwise pass: a label outside ``classes``
+    is -1 in every column, so callers check :func:`labels_outside`."""
+    hit = ydata[:, None] == classes[None, :]
+    if classes.shape[0] == 2:
+        hit = hit[:, 1:]
+    return jnp.where(hit, 1.0, -1.0).astype(jnp.float32)
+
+
+def labels_outside(ydata, mask, classes):
+    """How many real rows (``mask > 0``) hold a label not in ``classes``."""
+    known = jnp.any(ydata[:, None] == classes[None, :], axis=1)
+    return jnp.sum(jnp.where(known, 0.0, 1.0) * (mask > 0))
+
+
 def _regression_losses(loss: str, pred, y, epsilon):
     r = pred - y
     if loss == "squared_error":
@@ -760,19 +777,10 @@ class SGDClassifier(ClassifierMixin, _BaseSGD):
         exempt from the label-validity check; one scalar crosses to host.
         """
         classes = jnp.asarray(self.classes_, ydata.dtype)
-        idx = jnp.clip(
-            jnp.searchsorted(classes, ydata), 0, len(self.classes_) - 1
-        )
-        bad = jnp.sum(
-            (jnp.take(classes, idx) != ydata).astype(jnp.float32)
-            * (mask > 0)
-        )
-        if float(bad) > 0:  # scalar fetch, mirrors the host path's check
+        if float(labels_outside(ydata, mask, classes)) > 0:
+            # scalar fetch, mirrors the host path's check
             raise ValueError("y contains labels not in `classes`")
-        if len(self.classes_) == 2:
-            return jnp.where(idx == 1, 1.0, -1.0)[:, None].astype(jnp.float32)
-        k = len(self.classes_)
-        return (2.0 * jax.nn.one_hot(idx, k) - 1.0).astype(jnp.float32)
+        return ova_targets(ydata, classes)
 
     def _ensure_state(self, n_features: int):
         if not hasattr(self, "_state"):

@@ -160,7 +160,10 @@ class Incremental(ParallelPostFit):
     Reference: ``wrappers.py :: Incremental`` (``shuffle_blocks``,
     ``random_state``, ``assume_equal_chunks``); the chain of
     ``dask_ml/_partial.py :: fit`` becomes a host stream into a resident
-    model (SURVEY.md §3.5).
+    model (SURVEY.md §3.5), or, for this package's SGD estimators on a
+    table and labels held on the device, one program a pass over the
+    resident blocks (``linear_model/_sgd_pass.py``): ``fit`` and
+    ``partial_fit`` take that route in ``_partial.fit``.
     """
 
     def __init__(self, estimator=None, scoring=None, shuffle_blocks=True,

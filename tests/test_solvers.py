@@ -1580,3 +1580,72 @@ class TestSearchCompiledForTheChip:
         # sublanes): the compare, the mask and the sum fuse into the
         # product's consumer
         assert compiled.memory_analysis().temp_size_in_bytes < rows * 4
+
+
+class TestSgdPassCompiledForTheChip:
+    """At the benchmark's size (62,500,000 x 28 on one v5e, 60
+    blocks of 1,048,576 rows): one ``partial_fit`` pass over the resident
+    table, ``linear_model/_sgd_pass.py``, takes its blocks out of the
+    table without a copy, and asks for float32 products, which at the
+    TPU's default precision a one-vs-all step would feed a bfloat16 copy
+    of the table.  Compiled, never run.  (In this file because one process
+    at a time may load the TPU's library: ``v5e_chip``.)"""
+
+    ROWS, D, BLOCK = 62_500_000, 28, 1_048_576
+
+    def _compiled(self, mesh, classes, monkeypatch, precision=None):
+        import contextlib
+
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from dask_ml_tpu.core.mesh import MeshHolder
+        from dask_ml_tpu.linear_model import _sgd_pass
+
+        if precision is not None:  # the default: what the pass refuses
+            monkeypatch.setattr(jax, "default_matmul_precision",
+                                lambda _: contextlib.nullcontext())
+            jax.clear_caches()  # no trace of the pass as it asks is reused
+
+        def S(shape, spec, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(shape, dtype,
+                                        sharding=NamedSharding(mesh, spec))
+
+        k = 1 if classes == 2 else classes
+        state = {"coef": S((self.D, k), P()), "intercept": S((k,), P()),
+                 "t": S((), P())}
+        hyper = {name: S((), P()) for name in (
+            "alpha", "eta0", "power_t", "t0", "l1_ratio", "epsilon",
+            "eta_scale")}
+        blocks = -(-self.ROWS // self.BLOCK)
+        args = (state, S((self.ROWS, self.D), P("data", None)),
+                S((self.ROWS,), P("data")), S((self.ROWS,), P("data")),
+                S((blocks,), P(), jnp.int32), hyper, S((classes,), P()))
+        with _compile_cache_off():
+            return _sgd_pass._pass_program.lower(
+                *args, block=self.BLOCK, targets="ova", loss="log_loss",
+                penalty="l2", schedule="optimal", fit_intercept=True,
+                mesh_holder=MeshHolder(mesh), axes=("data",)).compile()
+
+    @pytest.mark.parametrize("classes", [2, 3])
+    def test_the_pass_copies_nothing_and_stays_float32(
+            self, v5e_chip, classes, monkeypatch):
+        compiled = self._compiled(v5e_chip, classes, monkeypatch)
+        # 290,816 B (two classes) and 355,328 B (three) when written:
+        # a block is read where it lies, never copied out of the table
+        assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+        assert "bf16[" not in compiled.as_text()
+
+    def test_the_default_precision_would_copy_the_table(
+            self, v5e_chip, monkeypatch):
+        """Why the pass asks: three classes at the default precision make
+        a bfloat16 copy of the table (4,000,328,704 B of temporaries when
+        written), two classes are reductions either way."""
+        import jax
+
+        try:
+            compiled = self._compiled(v5e_chip, 3, monkeypatch, "default")
+        finally:
+            jax.clear_caches()  # nor is this trace, by a later test
+        assert compiled.memory_analysis().temp_size_in_bytes > 3.5e9
+        assert "bf16[" in compiled.as_text()

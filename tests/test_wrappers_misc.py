@@ -54,13 +54,19 @@ class TestParallelPostFit:
 
 
 class TestIncremental:
-    def test_streams_partial_fit(self, clf_data):
+    @pytest.mark.parametrize("resident", [True, False])
+    def test_streams_partial_fit(self, clf_data, resident):
+        """A resident table trains through one program a pass
+        (``linear_model/_sgd_pass.py``), host arrays through the stream."""
         X, y = clf_data
         inc = Incremental(
             SGDClassifier(loss="log_loss", random_state=0, tol=None),
             shuffle_blocks=False, chunk_size=50,
         )
-        inc.fit(shard_rows(X), shard_rows(y), classes=[0, 1])
+        if resident:
+            inc.fit(shard_rows(X), shard_rows(y), classes=[0, 1])
+        else:
+            inc.fit(X, y, classes=[0, 1])
         assert inc.score(X, y) > 0.8
         assert hasattr(inc, "coef_")
 
