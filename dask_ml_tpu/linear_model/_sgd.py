@@ -174,8 +174,7 @@ def sgd_step(state, xb, yb, mask, hyper, *, loss, penalty, schedule,
       loss/penalty/schedule: static strings selecting the compiled branches.
     Returns (new_state, mean_loss).
     """
-    coef, intercept, t = state["coef"], state["intercept"], state["t"]
-    margins = xb @ coef + intercept  # [B, K]
+    margins = xb @ state["coef"] + state["intercept"]  # [B, K]
     if loss in _CLS_LOSSES:
         ell, dmarg = _margin_losses(loss, margins, yb)
     else:
@@ -186,7 +185,17 @@ def sgd_step(state, xb, yb, mask, hyper, *, loss, penalty, schedule,
     dmarg = dmarg * m / count
     gcoef = xb.T @ dmarg  # [d, K] — the other MXU gemm
     gint = jnp.sum(dmarg, axis=0)
+    new = sgd_update(state, gcoef, gint, hyper, penalty=penalty,
+                     schedule=schedule, fit_intercept=fit_intercept)
+    return new, mean_loss
 
+
+def sgd_update(state, gcoef, gint, hyper, *, penalty, schedule,
+               fit_intercept=True):
+    """The step :func:`sgd_step` takes from its block's mean loss
+    gradient, ``gcoef`` [d, K] and ``gint`` [K]: the penalty's gradient
+    added, the learning rate at ``t`` applied, ``t`` advanced."""
+    coef, intercept, t = state["coef"], state["intercept"], state["t"]
     alpha = hyper["alpha"]
     if penalty == "l2":
         gcoef = gcoef + alpha * coef
@@ -197,12 +206,11 @@ def sgd_step(state, xb, yb, mask, hyper, *, loss, penalty, schedule,
         gcoef = gcoef + alpha * (l1r * jnp.sign(coef) + (1.0 - l1r) * coef)
 
     eta = _learning_rate(schedule, t, hyper)
-    new = {
+    return {
         "coef": coef - eta * gcoef,
         "intercept": intercept - eta * gint if fit_intercept else intercept,
         "t": t + 1.0,
     }
-    return new, mean_loss
 
 
 # One compiled program per (loss, penalty, schedule, fit_intercept, shapes);

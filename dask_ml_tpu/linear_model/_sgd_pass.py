@@ -21,7 +21,17 @@ block: the rows it shares with the block before it are masked out by row
 index, as are pad rows, and train nothing.  The two products of a step,
 ``xb @ coef`` and ``xb.T @ dmarg``, are asked for at ``highest`` matmul
 precision: float32, where the TPU's default would feed a matrix product
-bfloat16 inputs.
+bfloat16 inputs.  Each product is a read of the block.
+
+Where :func:`one_read` holds (a float32 table on TPUs with its rows
+minor, shapes :mod:`._sgd_kernel` covers), a step instead takes its sums
+from :func:`._sgd_kernel.block_sums`, a Pallas kernel inside the same
+program that reads each of the block's rows once and gets the margins,
+their loss derivatives and the gradient from that one read; the update
+(:func:`~._sgd.sgd_update`) and the losses are :func:`sgd_step`'s own.
+The kernel walks the block's own rows, so the short last block takes no
+earlier rows.  The span says which step ran (``step_kernel``), and so
+does the registry (``sgd.pass_kernel``, tagged ``pallas`` / ``xla``).
 
 On a mesh of several row shards each shard steps on its own rows of the
 block, and the shards' new states are averaged, weighted by their rows
@@ -49,8 +59,10 @@ from .. import programs as _programs
 from ..core.compat import shard_map_unchecked
 from ..core.mesh import MeshHolder
 from ..core.sharded import ShardedRows
+from ..utils import safe_denominator
+from . import _sgd_kernel
 from ._sgd import (SGDClassifier, SGDRegressor, labels_outside, ova_targets,
-                   sgd_step)
+                   sgd_step, sgd_update)
 
 __all__ = ["takes", "run"]
 
@@ -94,16 +106,38 @@ def takes(model, x, y, fit_kwargs) -> bool:
         NamedSharding(x.data.sharding.mesh, P(axes)), 1)
 
 
+def one_read(x: ShardedRows, y: ShardedRows, outputs: int) -> bool:
+    """Whether a pass's steps take their sums from one read of each
+    block (:mod:`._sgd_kernel`): a float32 table and mask and float32 or
+    int32 labels on TPUs, the table with its rows minor, so that its
+    transpose is a bitcast, and shapes the kernel covers."""
+    data = x.data
+    if any(d.platform != "tpu" for d in data.sharding.device_set):
+        return False
+    if (data.dtype, x.mask.dtype) != (jnp.float32, jnp.float32) or (
+            y.data.dtype not in (jnp.float32, jnp.int32)):
+        return False
+    layout = data.format.layout
+    if layout is None or tuple(layout.major_to_minor) != (1, 0):
+        return False
+    shards = math.prod(data.sharding.mesh.shape[a] for a in _row_axes(x))
+    return _sgd_kernel.covers(data.shape[1], data.shape[0] // shards,
+                              outputs)
+
+
 def _sgd_pass(state, x, y, mask, starts, hyper, classes, *, block, targets,
-              loss, penalty, schedule, fit_intercept, mesh_holder, axes):
-    """``lax.scan`` of :func:`sgd_step` over the blocks that start at
-    ``starts``, in that order (see the module docstring)."""
+              loss, penalty, schedule, fit_intercept, mesh_holder, axes,
+              kernel):
+    """``lax.scan`` of an SGD step over the blocks that start at
+    ``starts``, in that order (see the module docstring): the step's sums
+    from :func:`._sgd_kernel.block_sums` where ``kernel``, else
+    :func:`sgd_step` on the block's slice."""
     mesh = mesh_holder.mesh
     shards = math.prod(mesh.shape[a] for a in axes)
     local = x.shape[0] // shards
     size = min(block, local)
-    step = dict(loss=loss, penalty=penalty, schedule=schedule,
-                fit_intercept=fit_intercept)
+    update = dict(penalty=penalty, schedule=schedule,
+                  fit_intercept=fit_intercept)
 
     def encode(yb, classes):
         if targets == "ova":
@@ -113,8 +147,25 @@ def _sgd_pass(state, x, y, mask, starts, hyper, classes, *, block, targets,
     def shard_pass(state, x, y, mask, starts, hyper, classes):
         first = lax.axis_index(axes) * local if shards > 1 else 0
         offsets = jnp.arange(size, dtype=jnp.int32)
+        positive = None
+        if targets == "ova":  # the class each column's target is +1 for
+            positive = classes[1:] if classes.shape[0] == 2 else classes
 
-        def body(st, start):
+        def kernel_step(st, start):
+            """The step from the kernel's sums over this shard's rows of
+            the block, each row read once."""
+            lo = jnp.clip(start - first, 0, local)
+            hi = jnp.clip(start + block - first, 0, local)
+            gx, gs, n = _sgd_kernel.block_sums(
+                x, y, mask, lo, hi, st["coef"], st["intercept"], positive,
+                hyper["epsilon"], loss=loss, size=size)
+            count = safe_denominator(n)
+            return sgd_update(st, gx / count, gs / count, hyper,
+                              **update), n
+
+        def xla_step(st, start):
+            """:func:`sgd_step` on the block's slice: the margins and the
+            gradient are two products, each a read of the block."""
             at = jnp.clip(start - first, 0, local - size)
             rows = first + at + offsets
             own = (rows >= start) & (rows < start + block)
@@ -123,9 +174,12 @@ def _sgd_pass(state, x, y, mask, starts, hyper, classes, *, block, targets,
             yb = encode(lax.dynamic_slice_in_dim(y, at, size), classes)
             # the step's mean loss is left unused, so it is never
             # computed: a pass returns the state alone
-            new, _ = sgd_step(st, xb, yb, mb, hyper, **step)
+            new, _ = sgd_step(st, xb, yb, mb, hyper, loss=loss, **update)
+            return new, jnp.sum(mb)
+
+        def body(st, start):
+            new, n = (kernel_step if kernel else xla_step)(st, start)
             if shards > 1:
-                n = jnp.sum(mb)
                 share = n / lax.psum(n, axes)
                 new = {k: st[k] + lax.psum(share * (new[k] - st[k]), axes)
                        for k in ("coef", "intercept")} | {"t": new["t"]}
@@ -145,7 +199,7 @@ def _sgd_pass(state, x, y, mask, starts, hyper, classes, *, block, targets,
 _pass_program = _programs.cached_program(
     _sgd_pass, name="sgd.pass",
     static_argnames=("block", "targets", "loss", "penalty", "schedule",
-                     "fit_intercept", "mesh_holder", "axes"),
+                     "fit_intercept", "mesh_holder", "axes", "kernel"),
     donate_argnames=("state",),
 )
 
@@ -182,8 +236,11 @@ def run(model, x: ShardedRows, y: ShardedRows, starts, block: int,
     that start at ``starts``, in that order, as one dispatch of
     ``sgd.pass``; the state stays on the device.  Span
     ``incremental.pass``: ``blocks``, ``table_host_mb`` (bytes of table
-    or labels brought to the host, MB) and ``host_waits`` (waits of the
-    host on the device inside the pass)."""
+    or labels brought to the host, MB), ``host_waits`` (waits of the host
+    on the device inside the pass) and ``step_kernel`` (``"pallas"`` where
+    the steps' sums came from one read of each block, :func:`one_read`,
+    else ``"xla"``; also the registry count ``sgd.pass_kernel``, tagged
+    with it)."""
     model._validate()
     classifier = isinstance(model, SGDClassifier)
     if classifier and not hasattr(model, "classes_"):
@@ -199,6 +256,7 @@ def run(model, x: ShardedRows, y: ShardedRows, starts, block: int,
             classes = np.asarray(model.classes_, y.data.dtype)
             waits += _check_labels(model, y, jnp.asarray(classes))
         model._ensure_state(x.data.shape[1])
+        kernel = one_read(x, y, model._state["coef"].shape[1])
         # every operand but the table whole on each device of the table's
         # mesh, as the program's outputs come: a fresh state and a carried
         # one are then one signature, and the pass one compile
@@ -210,6 +268,8 @@ def run(model, x: ShardedRows, y: ShardedRows, starts, block: int,
             block=int(block), targets="ova" if classifier else "value",
             loss=model.loss, penalty=model.penalty,
             schedule=model.learning_rate, fit_intercept=model.fit_intercept,
-            mesh_holder=MeshHolder(mesh), axes=_row_axes(x))
-        span.set(table_host_mb=0.0, host_waits=waits)
+            mesh_holder=MeshHolder(mesh), axes=_row_axes(x), kernel=kernel)
+        path = "pallas" if kernel else "xla"
+        obs.registry().counter("sgd.pass_kernel", path).inc()
+        span.set(table_host_mb=0.0, host_waits=waits, step_kernel=path)
     return model

@@ -174,6 +174,28 @@ def test_forty_passes_compile_the_pass_once():
     assert inc.estimator_.t_ == 40 * 8
 
 
+@pytest.mark.parametrize("n_classes", [2, 3])
+def test_the_span_and_the_registry_name_the_step(n_classes):
+    """Each pass's span says which step it took and the registry counts
+    the passes by it: off the TPU the XLA step (``"xla"``), since the
+    kernel's path needs a table on TPUs with its rows minor."""
+    from dask_ml_tpu.obs.metrics import registry
+
+    X, y = _table(rows=900, classes=n_classes)
+    before = registry().family("sgd.pass_kernel")
+    with use_mesh(device_mesh(1)):
+        sX, sy = shard_rows(X), shard_rows(y)
+        assert not _sgd_pass.one_read(sX, sy, 1)
+        inc = Incremental(SGDClassifier(), chunk_size=300, random_state=0)
+        inc.fit(sX, sy, classes=np.unique(y))
+        inc.partial_fit(sX, sy)
+    after = registry().family("sgd.pass_kernel")
+    assert [s.attrs["step_kernel"] for s in _pass_roots()[-2:]] == [
+        "xla", "xla"]
+    assert after.get("xla", 0) - before.get("xla", 0) == 2
+    assert after.get("pallas", 0) == before.get("pallas", 0)
+
+
 @pytest.mark.parametrize("case", ["sample_weight", "class_weight",
                                   "host_labels", "sklearn_model"])
 def test_what_keeps_the_host_stream(case):

@@ -1585,15 +1585,19 @@ class TestSearchCompiledForTheChip:
 class TestSgdPassCompiledForTheChip:
     """At the benchmark's size (62,500,000 x 28 on one v5e, 60
     blocks of 1,048,576 rows): one ``partial_fit`` pass over the resident
-    table, ``linear_model/_sgd_pass.py``, takes its blocks out of the
-    table without a copy, and asks for float32 products, which at the
-    TPU's default precision a one-vs-all step would feed a bfloat16 copy
-    of the table.  Compiled, never run.  (In this file because one process
-    at a time may load the TPU's library: ``v5e_chip``.)"""
+    table, ``linear_model/_sgd_pass.py``, reads its blocks where they lie.
+    On the chip its steps take their sums from the one-read kernel
+    (``_sgd_kernel.py``), which reads the table's transpose: a bitcast,
+    since the table lies with its rows minor.  The XLA step asks for
+    float32 products, which at the TPU's default precision a one-vs-all
+    step would feed a bfloat16 copy of the table.  Compiled, never run.
+    (In this file because one process at a time may load the TPU's
+    library: ``v5e_chip``.)"""
 
     ROWS, D, BLOCK = 62_500_000, 28, 1_048_576
 
-    def _compiled(self, mesh, classes, monkeypatch, precision=None):
+    def _compiled(self, mesh, classes, monkeypatch, precision=None,
+                  kernel=True):
         import contextlib
 
         import jax
@@ -1625,16 +1629,41 @@ class TestSgdPassCompiledForTheChip:
             return _sgd_pass._pass_program.lower(
                 *args, block=self.BLOCK, targets="ova", loss="log_loss",
                 penalty="l2", schedule="optimal", fit_intercept=True,
-                mesh_holder=MeshHolder(mesh), axes=("data",)).compile()
+                mesh_holder=MeshHolder(mesh), axes=("data",),
+                kernel=kernel).compile()
 
     @pytest.mark.parametrize("classes", [2, 3])
     def test_the_pass_copies_nothing_and_stays_float32(
             self, v5e_chip, classes, monkeypatch):
+        """The path the chip takes: the kernel in ``jit__sgd_pass``, the
+        table handed to it as its transpose, a bitcast of the parameter
+        (``{0,1}``: rows minor), never a copy, a transpose or a slice."""
+        import re
+
         compiled = self._compiled(v5e_chip, classes, monkeypatch)
+        hlo = compiled.as_text()
+        # 258,560 B (two classes) and 226,304 B (three) when written
+        assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+        assert "bf16[" not in hlo
+        assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+        assert "f32[62500000,28]{0,1:T(8,128)} parameter(" in hlo
+        table = [line for line in hlo.splitlines()
+                 if re.search(r"= f32\[(62500000,28|28,62500000)\]", line)]
+        ops = {re.search(r"\} ([\w-]+)\(", line).group(1) for line in table}
+        assert ops <= {"parameter", "get-tuple-element", "bitcast"}, ops
+        assert "f32[1048576,28]" not in hlo
+
+    @pytest.mark.parametrize("classes", [2, 3])
+    def test_the_xla_step_copies_nothing_and_stays_float32(
+            self, v5e_chip, classes, monkeypatch):
+        """The step off the kernel's path: two products a block."""
+        compiled = self._compiled(v5e_chip, classes, monkeypatch,
+                                  kernel=False)
         # 290,816 B (two classes) and 355,328 B (three) when written:
         # a block is read where it lies, never copied out of the table
         assert compiled.memory_analysis().temp_size_in_bytes < 1e6
         assert "bf16[" not in compiled.as_text()
+        assert "custom-call" not in compiled.as_text()
 
     def test_the_default_precision_would_copy_the_table(
             self, v5e_chip, monkeypatch):
@@ -1644,7 +1673,8 @@ class TestSgdPassCompiledForTheChip:
         import jax
 
         try:
-            compiled = self._compiled(v5e_chip, 3, monkeypatch, "default")
+            compiled = self._compiled(v5e_chip, 3, monkeypatch, "default",
+                                      kernel=False)
         finally:
             jax.clear_caches()  # nor is this trace, by a later test
         assert compiled.memory_analysis().temp_size_in_bytes > 3.5e9
