@@ -146,25 +146,36 @@ def _publish_lanes(span, counts):
     finished lanes sat out while the slowest ran (one vmapped
     ``while_loop`` turns until its last lane is done); where the runner
     counts them (``SOLVE_COUNTS``), ``passes_max``, the most reads of the
-    train rows any lane's solve made, and ``trials_max``, the most of them
-    any lane's line searches made: the lanes' objective is a black box,
-    whose every trial is a pass, and a solve's other passes are one at
-    its start (ADMM: a round) and one an iteration.  Both are least
+    train rows any lane's solve made, and ``trials_max``, the most trials
+    any lane's line searches took.  ``objective`` is what the lanes
+    searched on, as their counts tell it: on the cached linear predictor
+    (``"linear"``) a trial is a reduction over the lanes' row vectors,
+    which the lanes count apart (``trials``), at least one a search; on a
+    black box (``"black_box"``) ``trials`` reads 0, every trial is a
+    pass, and a solve's other passes are one at its start (ADMM: a round)
+    and one an iteration.  Lanes that took no search read
+    ``"black_box"``: nothing was searched on either.  Both are least
     counts of the program's own: the lanes search in lock-step, so a turn
     costs what its slowest lane takes.  The lanes go into the always-on
-    registry."""
+    registry, and the sweep by its objective."""
     from ..solvers.algorithms import SOLVE_COUNTS
 
     counts = np.asarray(counts).astype(np.int64)
     col = dict(zip(SOLVE_COUNTS, counts.reshape(len(counts), -1).T))
     iters = col["rounds"]
-    span.set(iters_max=int(iters.max()), iters_min=int(iters.min()),
+    objective = ("linear" if "trials" in col and col["trials"].any()
+                 else "black_box")
+    span.set(objective=objective, iters_max=int(iters.max()),
+             iters_min=int(iters.min()),
              lane_idle_iters=int((iters.max() - iters).sum()))
     if "passes" in col:
         starts = iters if "rho_moves" in col else 1
-        span.set(passes_max=int(col["passes"].max()), trials_max=int(
-            (col["passes"] - col["inner_iters"] - starts).max()))
+        trials = (col["trials"] if objective == "linear"
+                  else col["passes"] - col["inner_iters"] - starts)
+        span.set(passes_max=int(col["passes"].max()),
+                 trials_max=int(trials.max()))
     _obs.registry().counter("search.lanes").inc(int(iters.size))
+    _obs.registry().counter("search.sweep_objective", objective).inc()
 
 
 def _fold_classes_ok(ytr, yte) -> bool:

@@ -1014,13 +1014,13 @@ def lambda_sweep(solver: str, X, y, lams, *, family: type[Family] = Logistic,
     (as ``lbfgs`` and ``admm`` take it) returns each lane's whole count
     in the iterations' place, (K, n): the counted runners' vector
     :data:`SOLVE_COUNTS` lays out, a single column of iterations for the
-    others.  The lanes' objective is a black box, so a lane's ``trials``
-    are 0 and its searches' trials are among its ``passes``.
+    others.  What the lanes search on is :func:`sweep_objective`'s: on a
+    black box a lane's ``trials`` are 0 and are among its ``passes``.
     """
     reg = get_regularizer(regularizer)
     if line_search != "backtrack":
         line_search = "backtrack"  # same vmap-lane rule as packed_solve
-    objective = "black_box"  # and the same rule for the cached predictor
+    objective = sweep_objective(solver, family)
     x, yd, mask = _prep(X, y)
     dt = _param_dtype(x)
     lam_v = jnp.asarray(np.asarray(lams), dt)
@@ -1063,12 +1063,12 @@ def lambda_sweep(solver: str, X, y, lams, *, family: type[Family] = Logistic,
     if solver == "newton" and getattr(family, "params_per_feature", 1) > 1:
         raise ValueError("newton does not support matrix-parameter families")
     DISPATCH_COUNTS["solves"] += 1
-    run = runners[solver]
+    run = _lbfgs_lane if objective == "linear" else runners[solver]
     B0 = jnp.zeros((K, _pdim(x, family, intercept)), dtype=dt)
     extra_kw = (
         {} if solver == "proximal_grad" else {"line_search": line_search}
     )
-    if solver == "lbfgs":
+    if run is _lbfgs_run:
         extra_kw["objective"] = objective
 
     return _sweep_lanes(
@@ -1100,3 +1100,42 @@ def _exit_counts(st):
     """One finished L-BFGS solve as ``int32[4]`` counts by
     ``lbfgs_core.EXITS``: 1 at its ``LBFGSState.reason``."""
     return (jnp.arange(len(EXITS)) == st.reason).astype(jnp.int32)
+
+
+def sweep_objective(solver, family):
+    """What :func:`lambda_sweep`'s lanes search on: ``"linear"``, the
+    cached linear predictor (:func:`_lbfgs_lane`), for ``lbfgs`` lanes of
+    a family that :func:`_lbfgs_objective` gives one for; else
+    ``"black_box"``, where every trial of a search is a pass over X
+    (``admm`` lanes, the other runners, ``multinomial``, a family that
+    defines ``loss`` alone).  The rule is :func:`_lbfgs_objective`'s, asked
+    with no data: its parts read none until they are called."""
+    linear = solver == "lbfgs" and isinstance(
+        _lbfgs_objective("linear", family, None, None, None, None),
+        LinearObjective)
+    return "linear" if linear else "black_box"
+
+
+def _lbfgs_lane(x, yv, mask, beta0, lamduh, max_iter, tol, *, family, reg,
+                line_search="backtrack"):
+    """:func:`_lbfgs_run`'s solve and counts for one of
+    :func:`_sweep_lanes`' lanes, on the cached linear predictor.  The
+    lanes' images and gradient product are ``families.lane_products``:
+    float32 under ``vmap`` (the pair a multiply-and-sum, one vector and
+    its transpose, the gradient, products at ``HIGHEST``), where the
+    single solve's ``X @ w`` would be a matrix product at the default
+    precision.  An iteration reads X twice for all lanes at once (the
+    images of ``x`` and ``p``, the gradient at the accepted step) and a
+    trial is a reduction over the lanes' row vectors."""
+    from .families import lane_products
+
+    obj = _lbfgs_objective("linear", family, x, yv, mask,
+                           lambda b: reg.penalty(b, lamduh),
+                           _has_intercept(beta0, x, family))
+    obj = obj._replace(predict=lambda *betas: lane_products(
+        x, *(family.split(b, x)[0] for b in betas)))
+    beta, st = lbfgs_minimize(
+        obj, beta0, max_iter=max_iter, tol=tol, line_search=line_search)
+    return beta, jnp.concatenate([
+        jnp.stack([st.k, st.k, st.n_evals, st.n_trials]),
+        _exit_counts(st)]).astype(jnp.int32)

@@ -188,3 +188,19 @@ class Poisson(Family):
     @staticmethod
     def predict(eta):
         return jnp.exp(eta)
+
+
+def lane_products(X, *weights):
+    """``X @ w`` for each of a few weight VECTORS in float32, in the form
+    for lanes that a caller puts under ``vmap``.  There ``X @ w`` of a
+    batch of vectors is a matrix product, which the TPU's default
+    precision feeds a bfloat16 copy of ``X``, made again inside every
+    loop that holds it.  One vector is a product at ``HIGHEST``
+    precision, so that its transpose (the gradient's ``X' r`` for every
+    lane) is one too: as a multiply-and-sum over the rows it took four
+    reads' worth of time on a v5e, as a product one.  Several vectors
+    are one multiply-and-sum reduction with several results, as
+    :meth:`Family.products` forms them."""
+    if len(weights) > 1:
+        return Family.products(X, *weights)
+    return (jnp.dot(X, weights[0], precision=lax.Precision.HIGHEST),)

@@ -470,9 +470,9 @@ def lbfgs_minimize(
     one product before the search, one transposed product for the
     gradient at the accepted step) and every trial of the search works
     on the cached images.  Direction, history update, the Wolfe tests
-    and the four exits are the same for both.  A caller about to
-    ``vmap`` this function passes a plain callable: under vmap the
-    cached images are lanes x their length.
+    and the four exits are the same for both.  Under ``vmap`` the
+    cached images are lanes x their length, and the products are the
+    caller's to form (``algorithms._lbfgs_lane``).
 
     Convergence: ‖g‖_∞ ≤ tol (scipy's ``pgtol``), OR relative objective
     decrease ≤ 10·eps(dtype) (scipy's ``factr``-style stagnation exit,

@@ -249,7 +249,8 @@ def test_search_leaves_its_spans_counts_and_no_second_compile():
     def book():
         counters = diagnostics.run_report()["metrics"]["counters"]
         return (np.array([counters.get("search." + k, 0)
-                          for k in ("fits", "folds", "lanes")]),
+                          for k in ("fits", "folds", "lanes",
+                                    "sweep_objective{linear}")]),
                 diagnostics.program_report()["totals"]["misses"])
 
     counts, misses = book()
@@ -268,7 +269,7 @@ def test_search_leaves_its_spans_counts_and_no_second_compile():
     assert not [m for m in seen if "Finished XLA compilation" in m]
     after, misses_after = book()
     assert misses_after == misses
-    np.testing.assert_array_equal(after - counts, [1, 3, 24])
+    np.testing.assert_array_equal(after - counts, [1, 3, 24, 3])
 
     tree, spans = _search_tree()
     assert tree["attrs"] == {
@@ -292,11 +293,14 @@ def test_search_leaves_its_spans_counts_and_no_second_compile():
         iters, passes = lane["rounds"], lane["passes"]
         assert (a["iters_max"], a["iters_min"]) == (iters.max(), iters.min())
         assert a["lane_idle_iters"] == int((iters.max() - iters).sum())
-        # a black box's trials are passes (none counted apart), all but
-        # one at the start and one an iteration
-        assert not lane["trials"].any() and (passes > 2 * iters).all()
+        # on the cached linear predictor a lane reads the train rows once
+        # at its start and twice an iteration; its trials are reductions
+        # over its row vectors, counted apart, at least one an iteration
+        assert a["objective"] == "linear"
+        assert (passes == 1 + 2 * iters).all()
+        assert (lane["trials"] >= iters).all()
         assert a["passes_max"] == passes.max()
-        assert a["trials_max"] == (passes - iters - 1).max() > 0
+        assert a["trials_max"] == lane["trials"].max() > 0
     refit = spans("search.refit")[0]
     assert refit["attrs"]["candidate"] == search.best_index_
     assert [c["name"] for c in refit["children"]] == ["glm.fit"]
@@ -319,6 +323,39 @@ def test_lanes_return_their_counts_on_request(solver):
     np.testing.assert_array_equal(counts[:, 0], np.asarray(iters))
     assert ("passes" in dict(zip(SOLVE_COUNTS, counts.T))) == (
         solver != "newton")
+
+
+@pytest.mark.parametrize("objective,counts,trials_max", [
+    # on the cached linear predictor the lanes count their trials apart:
+    # passes are 1 + 2 x iterations
+    ("linear", [[4, 4, 9, 11, 0, 1, 0, 0], [3, 3, 7, 6, 1, 0, 0, 0]], 11),
+    # on a black box a trial is a pass: passes less the start's and one
+    # an iteration
+    ("black_box", [[4, 4, 12, 0, 0, 1, 0, 0], [3, 3, 11, 0, 1, 0, 0, 0]], 7),
+])
+def test_the_sweep_span_takes_its_trials_by_the_objective(
+        objective, counts, trials_max):
+    """``_publish_lanes``: ``trials_max`` from the lanes' own ``trials``
+    where they searched on the cached predictor, from their passes on a
+    black box; ``objective`` on the span, and the registry's count of
+    sweeps by it."""
+    class Span:
+        attrs = {}
+
+        def set(self, **kw):
+            self.attrs.update(kw)
+
+    def sweeps():
+        counters = diagnostics.run_report()["metrics"]["counters"]
+        return counters.get(f"search.sweep_objective{{{objective}}}", 0)
+
+    before, span = sweeps(), Span()
+    _search._publish_lanes(span, np.array(counts, np.int32))
+    assert span.attrs["objective"] == objective
+    assert span.attrs["passes_max"] == max(c[2] for c in counts)
+    assert span.attrs["trials_max"] == trials_max
+    assert (span.attrs["iters_max"], span.attrs["iters_min"]) == (4, 3)
+    assert sweeps() == before + 1
 
 
 def test_a_finished_search_holds_nothing_of_the_tables_size():
@@ -446,7 +483,10 @@ def test_cell_rehearsed_small(tmp_path):
     assert metrics["solve.rounds"] == 3 and metrics["window.compiles"] == 0
     assert metrics["search.fold_host_mb"] == 0
     assert metrics["search.lane_idle_iters"] > 0
-    assert metrics["sweep.passes"] > metrics["sweep.trials"] > 0
+    # trials are reductions over the lanes' row vectors, counted apart
+    # from the reads of X: at least one an iteration, and two reads an
+    # iteration (passes less the start's)
+    assert metrics["sweep.trials"] >= (metrics["sweep.passes"] - 3) / 2 > 0
     for name in ("search.fold_ms", "search.sweep_ms", "search.score_ms",
                  "search.refit_ms"):
         assert metrics[name] > 0, name

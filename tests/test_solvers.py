@@ -312,6 +312,96 @@ class TestLambdaSweep:
         with pytest.raises(ValueError, match="1-D"):
             lambda_sweep("lbfgs", X, y, [[0.1, 1.0]], family=Logistic)
 
+    @staticmethod
+    def _newton_optimum(X, y, lam, intercept):
+        """The penalized logistic optimum in float64 on the host, the
+        intercept last and penalized as the solvers penalize it."""
+        A = np.hstack([X, np.ones((len(X), 1))]) if intercept else X
+        A = A.astype(np.float64)
+        w = np.zeros(A.shape[1])
+        for _ in range(30):
+            p = 1 / (1 + np.exp(-A @ w))
+            H = (A * (p * (1 - p))[:, None]).T @ A + lam * np.eye(len(w))
+            w -= np.linalg.solve(H, A.T @ (p - y) + lam * w)
+        return w
+
+    @pytest.mark.parametrize("intercept", [False, True])
+    def test_lbfgs_lanes_search_on_the_cached_predictor(
+            self, rng, mesh, intercept):
+        """``lbfgs`` lanes search on the cached linear predictor.  They
+        end where the black-box lanes and eight single solves end (the
+        same iterations, or one apart: each solve stops once the
+        float32 loss stalls, 1e-4 to 4e-4 from the float64 optimum here,
+        and where exactly within that the rounding of its sums decides),
+        read X once at the start and twice an iteration, and count their
+        trials apart from those reads."""
+        from dask_ml_tpu.solvers.algorithms import (
+            SOLVE_COUNTS, _lbfgs_run, _prep, _sweep_lanes)
+
+        X = rng.normal(size=(300, 5)).astype(np.float32)
+        y = (X[:, 0] - X[:, 1] + 0.5 * rng.logistic(size=300) > 0).astype(
+            np.float32)
+        lams = [0.01, 0.1, 1.0, 10.0, 100.0, 1000.0, 1e4, 1e5]
+        kw = dict(family=Logistic, tol=1e-5, intercept=intercept)
+        betas, counts = lambda_sweep("lbfgs", X, y, lams, return_counts=True,
+                                     **kw)
+        # the lanes as they were: the runner vmapped on the black box
+        x, yv, mask = _prep(X, y)
+        black_box, bb_counts = _sweep_lanes(
+            x, yv, mask, jnp.asarray(lams, jnp.float32),
+            jnp.zeros((len(lams), 5 + intercept), jnp.float32),
+            jnp.int32(100), jnp.float32(1e-5), run=_lbfgs_run,
+            family=Logistic, reg=L2, extra_kw=(
+                ("line_search", "backtrack"), ("objective", "black_box")),
+            counts=True)
+        lane = dict(zip(SOLVE_COUNTS, np.asarray(counts).T))
+        bb = dict(zip(SOLVE_COUNTS, np.asarray(bb_counts).T))
+        # the lanes whose solves stop on a stalled float32 loss may stop an
+        # iteration apart: where, the rounding of their sums decides
+        assert (np.abs(lane["rounds"] - bb["rounds"]) <= 1).all(), (
+            lane["rounds"], bb["rounds"])
+        assert (lane["passes"] == 1 + 2 * lane["rounds"]).all()
+        assert (lane["trials"] >= lane["rounds"]).all()
+        assert not bb["trials"].any() and (
+            bb["passes"] > 1 + 2 * bb["rounds"]).all()
+        for i, lam in enumerate(lams):
+            single = solvers.lbfgs(X, y, lamduh=lam, line_search="backtrack",
+                                   **kw)
+            for other in (black_box[i], single):
+                np.testing.assert_allclose(
+                    np.asarray(betas[i]), np.asarray(other), atol=5e-4,
+                    err_msg=f"lane {i} (lam={lam})")
+            np.testing.assert_allclose(
+                np.asarray(betas[i]),
+                self._newton_optimum(X, y, lam, intercept), atol=2e-3,
+                err_msg=f"lane {i} (lam={lam})")
+
+    @pytest.mark.parametrize("solver,family,objective,trials", [
+        ("lbfgs", Logistic, "linear", True),
+        ("lbfgs", Normal, "linear", True),
+        # a matrix of parameters, and the runners with no cached
+        # predictor: every trial a pass over X
+        ("lbfgs", multinomial(3), "black_box", False),
+        ("admm", Logistic, "black_box", False),
+        ("gradient_descent", Logistic, "black_box", None),
+    ])
+    def test_which_lanes_search_on_the_cached_predictor(
+            self, rng, mesh, solver, family, objective, trials):
+        from dask_ml_tpu.solvers.algorithms import (
+            SOLVE_COUNTS, sweep_objective)
+
+        assert sweep_objective(solver, family) == objective
+        X, y = self._data(rng)
+        if family is multinomial(3):
+            y = (X[:, 0] > 0).astype(np.float32) + (X[:, 1] > 0)
+        _, counts = lambda_sweep(solver, X, y, [0.1, 1.0], family=family,
+                                 max_iter=5, return_counts=True)
+        lane = dict(zip(SOLVE_COUNTS, np.asarray(counts).T))
+        # trials counted apart from the passes on the cached predictor
+        # alone; a runner that counts no passes has no such column
+        assert (bool(lane["trials"].all()) if "trials" in lane
+                else None) == trials
+
 
 def _force_objective(monkeypatch, kind):
     """Every dispatch of the counted runners asks for ``kind``, the
@@ -439,8 +529,11 @@ class TestSolveCounts:
     @pytest.mark.parametrize("solver", ["admm", "lbfgs"])
     def test_lambda_sweep_keeps_scalar_iterations(
             self, logistic_data, monkeypatch, solver):
-        # the sweep's lanes run the black-box objective, as packed lanes do
-        _force_objective(monkeypatch, "black_box")
+        # the sweep's admm lanes run the black-box objective, as packed
+        # lanes do; its lbfgs lanes the cached linear predictor, as the
+        # single solve does
+        if solver == "admm":
+            _force_objective(monkeypatch, "black_box")
         X, y, _ = logistic_data
         lams = [0.1, 1.0, 10.0]
         betas, n_its = lambda_sweep(solver, X, y, lams, family=Logistic)
@@ -772,13 +865,16 @@ class TestLinearObjective:
     @pytest.mark.parametrize("solver", ["admm", "lbfgs"])
     def test_vmapped_callers_keep_the_black_box_program(
             self, logistic_data, monkeypatch, mesh, solver):
-        """``packed_solve`` and ``lambda_sweep`` put the runners under
-        ``vmap`` and ask for the black-box objective: their programs
-        hold the black box's products with X and no reduction with two
-        results (the pair product).  14 products before ISSUE 35, when
-        the expansion's condition took the slope at t and the value at
-        2t and its body took that value again; 10 now that one loop body
-        takes each once (both counts as ``make_jaxpr`` shows them)."""
+        """``packed_solve`` and ``lambda_sweep``'s ``admm`` lanes put the
+        runners under ``vmap`` and ask for the black-box objective: their
+        programs hold the black box's products with X and no reduction
+        with two results (the pair product).  10 products: one loop body
+        takes the slope at t and the value at 2t once each (as
+        ``make_jaxpr`` shows them).  ``lambda_sweep``'s ``lbfgs`` lanes
+        search on the cached predictor: the pair is a reduction, and
+        their three products with X (the first value and gradient's two,
+        the gradient's one an iteration) are float32 ones, at
+        ``HIGHEST``."""
         import jax
 
         from dask_ml_tpu.solvers import packed_solve
@@ -792,17 +888,17 @@ class TestLinearObjective:
                         if hasattr(j, "eqns"):
                             yield from eqns(j)
 
-        def census(fn, shape):
+        def census(fn, shape, precisions=None):
             # X whole, or one shard's rows of it (admm's shard_map)
             shapes = (shape, (shape[0] // mesh.devices.size, shape[1]))
             found = list(eqns(jax.make_jaxpr(fn)().jaxpr))
-            dots = sum(
-                e.primitive.name == "dot_general" and any(
-                    tuple(v.aval.shape) in shapes for v in e.invars)
-                for e in found)
+            dots = [e for e in found if e.primitive.name == "dot_general"
+                    and any(tuple(v.aval.shape) in shapes for v in e.invars)]
+            if precisions is not None:
+                precisions.extend(e.params["precision"] for e in dots)
             pairs = sum(e.primitive.name == "reduce" and len(e.outvars) > 1
                         for e in found)
-            return dots, pairs
+            return len(dots), pairs
 
         X, y, _ = logistic_data
         sX = shard_rows(X)
@@ -813,8 +909,13 @@ class TestLinearObjective:
         monkeypatch.setenv("DASK_ML_TPU_PACK", "packed")
         assert census(lambda: packed_solve(
             solver, sX, jnp.asarray(Y), **kw), shape) == (10, 0)
+        precisions = []
         assert census(lambda: lambda_sweep(
-            solver, sX, Y[0], [0.1, 1.0], family=Logistic), shape) == (10, 0)
+            solver, sX, Y[0], [0.1, 1.0], family=Logistic), shape,
+            precisions) == ((3, 1) if solver == "lbfgs" else (10, 0))
+        if solver == "lbfgs":
+            highest = jax.lax.Precision.HIGHEST
+            assert precisions == [(highest, highest)] * 3, precisions
         # one solve a dispatch: the first value_and_grad (two products),
         # then the pair and one transposed product an iteration
         monkeypatch.setenv("DASK_ML_TPU_PACK", "sequential")
@@ -1544,27 +1645,119 @@ class TestSearchCompiledForTheChip:
         hlo = compiled.as_text()
         assert not re.search(r" gather\(| sort\(", hlo)  # nothing by index
 
-    def test_eight_lanes_hold_a_fold_and_their_temporaries(self, v5e_chip):
-        from dask_ml_tpu.solvers.algorithms import _lbfgs_run, _sweep_lanes
+    def _lanes(self, mesh, rows):
+        """``_sweep_lanes`` compiled for eight ``lbfgs`` lanes of
+        ``LogisticRegression`` over a fold's train slab of ``rows``, as
+        the search calls it (``lambda_sweep``)."""
+        from dask_ml_tpu.solvers.algorithms import (
+            _lbfgs_lane, _sweep_lanes, sweep_objective)
 
-        S = self._shapes(v5e_chip)
-        rows = self.ROWS - self.EDGES[1]  # the first fold's train rows
+        assert sweep_objective("lbfgs", Logistic) == "linear"
+        S = self._shapes(mesh)
         with _compile_cache_off():
-            compiled = _sweep_lanes.lower(
+            return _sweep_lanes.lower(
                 S((rows, self.D)), S((rows,)), S((rows,)), S((self.LANES,)),
                 S((self.LANES, self.D + 1)), S((), jnp.int32), S(()),
-                run=_lbfgs_run, family=Logistic, reg=L2,
-                extra_kw=(("line_search", "backtrack"),
-                          ("objective", "black_box")),
-                counts=True).compile()  # as the search calls it
+                run=_lbfgs_lane, family=Logistic, reg=L2,
+                extra_kw=(("line_search", "backtrack"),),
+                counts=True).compile()
+
+    def test_eight_lanes_hold_a_fold_and_their_temporaries(self, v5e_chip):
+        rows = self.ROWS - self.EDGES[1]  # the first fold's train rows
+        compiled = self._lanes(v5e_chip, rows)
         memory = compiled.memory_analysis()
         vector = rows * 4
         assert memory.argument_size_in_bytes < rows * 32 * 4 + 2.01 * vector
-        # each lane's eta, residual and trial point at a row's length:
-        # 17 row vectors when written (1,420,318,720 B), 3.4 GB under the
-        # chip's 15.75 with the table and the fold resident (8.5 GB)
+        # each lane's images of x and p at a row's length, the residual
+        # made inside the gradient's product: 17 row vectors when written
+        # (1,421,454,336 B; 1,420,318,720 on the black box), 3.4 GB under
+        # the chip's 15.75 with the table and the fold resident (8.5 GB)
         assert memory.temp_size_in_bytes < 17.5 * vector
         assert "jit__sweep_lanes" in compiled.as_text()[:200]
+
+    @pytest.mark.parametrize("fold", [0, 1])
+    def test_the_lanes_read_the_table_by_float32_reductions(
+            self, v5e_chip, fold):
+        """Eight lanes under ``vmap`` make no product with the table at
+        the default precision, which would feed it a bfloat16 copy of
+        the table: they read it by the pair of images, a float32
+        reduction, and by the gradient, a product at ``HIGHEST``.  Both
+        train slabs' lengths a search compiles (the first fold's, and
+        the others')."""
+        import re
+
+        rows = self.ROWS - (self.EDGES[fold + 1] - self.EDGES[fold])
+        hlo = self._lanes(v5e_chip, rows).as_text()
+        table = r"f32\[%d,%d\]" % (rows, self.D)
+        assert not re.search(r"bf16\[[^\]]*\] convert\(", hlo)
+        assert "bf16[%d,%d]" % (rows, self.D) not in hlo
+        products = re.findall(r" (?:dot|convolution)\(.*", hlo)
+        assert products and all(
+            "operand_precision={highest,highest}" in p for p in products)
+        # the fusions the table feeds: the pair X @ [x | p] for all lanes
+        # (two f32[8, rows] results), the gradient X' r (f32[8, 28]) and
+        # the first image (f32[8, rows]), each in float32
+        # (the table reaches the products by bitcasts: never a copy)
+        fed = re.findall(r"^%%([\w.-]+) \([^)]*%s[^)]*\) -> (.+) \{$"
+                         % table, hlo, re.M)
+        assert all(name.startswith("bitcast_fusion")
+                   for name, out in fed if out == "f32[%d,%d]" % (
+                       rows, self.D)), fed
+        fed = [out for _, out in fed if out != "f32[%d,%d]" % (rows, self.D)]
+        lanes = r"f32\[%d,%d\]" % (self.LANES, rows)
+        assert any(re.fullmatch(r"\(%s, %s\)" % (lanes, lanes), out)
+                   for out in fed), fed
+        assert "f32[%d,%d]" % (self.LANES, self.D) in fed, fed
+        assert set(fed) <= {"(f32[%d,%d], f32[%d,%d])" % (
+            self.LANES, rows, self.LANES, rows),
+            "f32[%d,%d]" % (self.LANES, rows),
+            "(f32[%d], f32[%d], f32[%d,%d])" % (
+                self.LANES, self.LANES, self.LANES, rows),
+            "f32[%d,%d]" % (self.LANES, self.D)}, fed
+
+    def test_admm_and_refit_lower_alike_after_the_lanes(self, v5e_devices):
+        """The lanes' own way of forming their products leaves the single
+        solves alone: ``_admm_run`` in both line searches, on one shard
+        and on four, and ``_lbfgs_run`` as a search's refit runs it lower
+        to the same text (locations stripped) whether or not the lanes'
+        program was traced before them in the process."""
+        import jax
+        from jax.sharding import Mesh
+
+        from dask_ml_tpu.core.mesh import MeshHolder
+        from dask_ml_tpu.solvers.algorithms import _admm_run, _lbfgs_run
+
+        def texts():
+            jax.clear_caches()  # every lowering below traces anew
+            out = []
+            for shards, ls in ((1, "backtrack"), (1, "probe_grid"),
+                               (4, "backtrack")):
+                mesh = Mesh(np.array(v5e_devices[:shards]).reshape(
+                    shards, 1), ("data", "model"))
+                S, rows = self._shapes(mesh), self.ROWS * shards
+                out.append(_admm_run.lower(
+                    S((rows, self.D)), S((rows,)), S((rows,)),
+                    *[S(())] * 5, S((), jnp.int32), S((self.D + 1,)),
+                    family=Logistic, reg=L2, mesh_holder=MeshHolder(mesh),
+                    inner_iter=30, line_search=ls,
+                    objective="linear").as_text())
+            S = self._shapes(Mesh(np.array(v5e_devices[:1]).reshape(1, 1),
+                                  ("data", "model")))
+            out.append(_lbfgs_run.lower(
+                S((self.ROWS, self.D)), S((self.ROWS,)), S((self.ROWS,)),
+                S((self.D + 1,)), S(()), S((), jnp.int32), S(()),
+                family=Logistic, reg=L2, line_search="probe_grid",
+                objective="linear").as_text())
+            assert "loc(" not in out[0]
+            return out
+
+        before = texts()
+        self._lanes(Mesh(np.array(v5e_devices[:1]).reshape(1, 1),
+                         ("data", "model")), self.ROWS - self.EDGES[1])
+        try:
+            assert texts() == before
+        finally:
+            jax.clear_caches()
 
     def test_the_scoring_gemm_makes_no_rows_by_lanes_array(self, v5e_chip):
         from dask_ml_tpu.model_selection._search import _sweep_kernels
