@@ -247,8 +247,6 @@ def main() -> None:
 
     import dask_ml_tpu  # noqa: F401  (arms the compile cache at import)
     from dask_ml_tpu import diagnostics
-    from dask_ml_tpu.ops import scatter_strategy
-    from dask_ml_tpu.solvers import grid_pack_strategy, pack_strategy
     from dask_ml_tpu.solvers.algorithms import line_search_strategy
 
     n_fit, n_stream, block_rows = SIZES[rehearsal]
@@ -293,9 +291,7 @@ def main() -> None:
         "device": device,
         "versions": versions,
         "compile_cache_dir": diagnostics.program_report()["persistent_cache"],
-        "arms": {"scatter": scatter_strategy(), "pack": pack_strategy(),
-                 "grid_pack": grid_pack_strategy(),
-                 "line_search": line_search_strategy()},
+        "arms": {"line_search": line_search_strategy()},
         "phases": phases,
     }
     print(json.dumps(report))

@@ -126,8 +126,8 @@ def _lloyd_step_fn(x, mask, centers, *, mode="highest", scatter="segsum"):
         min_d2 = jnp.min(d2, axis=1)
         inertia = jnp.sum(min_d2 * mask)
     # per-cluster reduce through the shared scatter policy (ops.scatter):
-    # one-hot gemm on the MXU or segment_sum, whichever the platform
-    # measurement favors.  Precision on the gemm path: HIGH in fast mode
+    # one-hot gemm on the MXU, segment_sum past 1,024 clusters (the
+    # one-hot's memory).  Precision on the gemm path: HIGH in fast mode
     # (3-pass bf16 split — Mosaic's kernel writes the same split by
     # hand), HIGHEST otherwise (centers feed the next round's argmin).
     # The weight mask pre-multiplies x so both strategies accumulate the

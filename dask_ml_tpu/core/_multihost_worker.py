@@ -73,7 +73,7 @@ def main(pid: int, nproc: int, port: str, local_devices: int = 4) -> None:
     from dask_ml_tpu.ops.scatter import scatter_strategy
 
     _scatter = scatter_strategy(2)  # resolved OUTSIDE the jit (static):
-    # defaulting it would bake segsum in and drop the TPU onehot policy
+    # the loop's own default is segsum, not the policy's onehot
     # a replicated operand must hold the SAME value on every process: the
     # centres come from the shared stream, not from this process's rows
     # (the anchored Lloyd reduce adds its sums to the centres it was given,

@@ -748,10 +748,10 @@ class _BaseSearchCV(TPUEstimator):
         The reference builds K independent task graphs here; this is the
         TPU-native counterpart of its graph-level dedup.
 
-        Gated on ``pack_strategy() == "packed"`` (vmap packing measured
-        SLOWER on CPU, r3 ``packed_speedup 0.684``); ineligible grids
-        fall through to the per-task path.  Returns True when it filled
-        the score arrays, and then leaves ``coefs_paths_`` (folds,
+        Every eligible grid packs, on every platform; the others
+        (another estimator or parameter, an explicit ``scoring``, fit
+        params) fall through to the per-task path.  Returns True when it
+        filled the score arrays, and then leaves ``coefs_paths_`` (folds,
         candidates, p), each fold's lanes as they came to the host, the
         intercept last (what ``LogisticRegressionCV`` keeps under that
         name); a search that took the per-task path has no such
@@ -759,15 +759,12 @@ class _BaseSearchCV(TPUEstimator):
         """
         from ..linear_model import LinearRegression as _OLS
         from ..linear_model import LogisticRegression as _LR
-        from ..solvers import grid_pack_strategy
 
         self.__dict__.pop("coefs_paths_", None)  # an earlier fit's
         est = self.estimator
         is_clf = type(est) is _LR
         is_reg = type(est) is _OLS  # identity link: R² scores by gemm
         if not (is_clf or is_reg):
-            return False
-        if grid_pack_strategy() != "packed":
             return False
         if fit_params or self.scoring is not None:
             return False

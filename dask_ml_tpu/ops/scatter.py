@@ -1,4 +1,4 @@
-"""Scatter-add strategy: ``segment_sum`` vs one-hot gemm, one policy.
+"""Scatter-add strategy: one-hot gemm vs ``segment_sum``, one policy.
 
 Two lowerings exist for "accumulate rows into labeled buckets" — the
 shape under the histogram quantile sketch
@@ -6,21 +6,14 @@ shape under the histogram quantile sketch
 reduce (``cluster/k_means.py :: _lloyd_step``), and GaussianNB's
 per-class moments:
 
-- ``jax.ops.segment_sum`` — an XLA scatter-add.  On CPU this wins big
-  (r3 measurement: 160× over the one-hot gemm).  On TPU scatters
-  historically lower poorly (serialized updates).
-- one-hot matmul — builds the (n, k) indicator and rides the MXU.  The
-  k-means header's historical choice on TPU.
+- one-hot matmul — builds the (n, k) indicator and rides the MXU.
+- ``jax.ops.segment_sum`` — an XLA scatter-add, which lowers poorly on
+  a TPU (serialized updates).
 
-No chip reading of the two lowerings against each other exists (ROADMAP
-D6: ``SCATTER`` waits for a cell on either side).  The policy here is
-the single place both consumers consult:
-
-``DASK_ML_TPU_SCATTER`` = ``segsum`` | ``onehot`` | ``auto`` (default).
-``auto`` picks ``onehot`` on TPU and ``segsum`` elsewhere, EXCEPT when
-``num_segments`` is large (> 1024): a one-hot with that many columns is
-memory-quadratic and loses everywhere (the 4096-bin sketch would build
-an (n·d, 4096·d) indicator).  The strategy is read at TRACE time.
+:func:`scatter_strategy` is the single place every consumer consults:
+``onehot``, EXCEPT when ``num_segments`` is large (> 1024), where
+``segsum``: a one-hot with that many columns is memory-quadratic (the
+4096-bin sketch would build an (n·d, 4096·d) indicator).
 
 A HISTOGRAM (1-D values) over many buckets has a third lowering,
 ``onehot2``: split every id into ``(id // w, id % w)`` and take ONE
@@ -29,7 +22,7 @@ outer-product gemm of the two narrow indicators, ``(n, S/w)ᵀ @ (n, w)``
 ``S/w + w`` columns instead of ``S``.  Measured on a v5e at 25M rows
 into 2049 buckets (PERF.md, PR 28): ``segment_sum`` 171 ms, ``onehot2``
 6.7 ms, bit-equal on 0/1 weights.  ``scatter_strategy(n, histogram=True)``
-returns it where the policy says one-hot and ``n`` is over the guard.
+returns it where ``n`` is over the guard.
 
 Reference analogue: dask's graph has no such choice — blockwise numpy
 ``np.add.at``/``bincount`` is the only lowering (SURVEY.md §2.1 #13).
@@ -43,23 +36,13 @@ import jax.numpy as jnp
 _ONEHOT_MAX_SEGMENTS = 1024
 
 
-def scatter_strategy(num_segments: int | None = None, *,
-                     histogram: bool = False) -> str:
-    """The platform policy, overridable via ``DASK_ML_TPU_SCATTER``.
-    ``histogram``: the values are 1-D, so many buckets may take the
-    two-level one-hot (``onehot2``) where one-hot is the policy."""
-    from ..utils import env_choice
-
-    v = env_choice("DASK_ML_TPU_SCATTER", ("auto", "segsum", "onehot"))
-    # the large-segment guard binds even under the env override: forcing
-    # onehot to A/B the k-means reduce must not make the 4096-bin sketch
-    # build an (n·d, d·4096) indicator — that is an OOM, not a strategy
-    large = num_segments is not None and num_segments > _ONEHOT_MAX_SEGMENTS
-    if large and not histogram:
-        return "segsum"
-    if v == "auto":
-        v = "onehot" if jax.default_backend() == "tpu" else "segsum"
-    return "onehot2" if large and v == "onehot" else v
+def scatter_strategy(num_segments: int, *, histogram: bool = False) -> str:
+    """The lowering for ``num_segments`` buckets (module docstring).
+    ``histogram``: the values are 1-D, so many buckets take the
+    two-level one-hot (``onehot2``) instead of ``segsum``."""
+    if num_segments <= _ONEHOT_MAX_SEGMENTS:
+        return "onehot"
+    return "onehot2" if histogram else "segsum"
 
 
 def _onehot2_sum(values, ids, num_segments: int, precision):
@@ -82,13 +65,11 @@ def bucket_sum(values, ids, num_segments: int, *, precision=None,
     applies to the one-hot gemm path only (segment_sum accumulates in
     full f32 natively, which is strictly at least as precise).
 
-    ``strategy``: callers inside jitted code MUST resolve
+    ``strategy``: callers inside jitted code resolve
     ``scatter_strategy`` OUTSIDE the jit and pass it through as a static
-    argument — resolving here at trace time would bake the env value
-    into the jit cache, so flipping ``DASK_ML_TPU_SCATTER`` in-process
-    (the documented A/B use case) would silently keep the stale
-    strategy.  ``None`` (eager callers) resolves at call time.  The
-    large-segment OOM guard binds either way.
+    argument; tests name a lowering through it.  ``None`` resolves
+    through ``scatter_strategy(num_segments)``.  The large-segment OOM
+    guard binds either way.
     """
     if getattr(values, "ndim", None) not in (1, 2):
         raise ValueError(

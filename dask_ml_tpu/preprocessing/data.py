@@ -100,9 +100,9 @@ def _hist_quantiles(x, mask, probs, *, bins=4096, refinements=3,
         inside = weights_all * (x >= lo_f[None, :]) * (x <= hi_f[None, :])
         below = jnp.sum(weights_all * (x < lo_f[None, :]), axis=0)  # (d,)
         # routed through the shared scatter policy (ops.scatter): with
-        # d*bins segments the one-hot form is memory-quadratic, so auto
-        # resolves to segment_sum on every platform — but the decision
-        # lives in ONE place with the k-means reduce
+        # d*bins segments the one-hot form is memory-quadratic, so the
+        # policy resolves to segment_sum — but the decision lives in ONE
+        # place with the k-means reduce
         from ..ops.scatter import bucket_sum
 
         counts = bucket_sum(
@@ -177,7 +177,7 @@ def _masked_quantiles(x, mask, probs, method: str = "auto"):
         return jnp.nanquantile(xm, jnp.asarray(probs), axis=0)
     from ..ops.scatter import scatter_strategy
 
-    # resolved OUTSIDE the jit: the env knob must key the jit cache
+    # resolved OUTSIDE the jit: the strategy is a static argument
     return _hist_quantiles(x, mask, jnp.asarray(probs),
                            scatter=scatter_strategy(x.shape[1] * 4096))
 

@@ -17,9 +17,7 @@ from .algorithms import (  # noqa: F401
     gradient_descent,
     lbfgs,
     newton,
-    grid_pack_strategy,
     lambda_sweep,
-    pack_strategy,
     packed_solve,
     proximal_grad,
     reset_dispatch_counts,
@@ -40,9 +38,7 @@ __all__ = [
     "lbfgs",
     "newton",
     "proximal_grad",
-    "grid_pack_strategy",
     "lambda_sweep",
-    "pack_strategy",
     "packed_solve",
     "DISPATCH_COUNTS",
     "reset_dispatch_counts",

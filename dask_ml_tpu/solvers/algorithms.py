@@ -762,44 +762,6 @@ def admm(X, y, *, family: type[Family] = Logistic, regularizer=L2,
     return _with_counts(beta, counts, return_n_iter, return_counts)
 
 
-# ------------------------------------------------------- packed (vmap) --
-
-
-def pack_strategy(n_lanes: int | None = None) -> str:
-    """How one-vs-rest multi-class solves execute,
-    ``DASK_ML_TPU_PACK`` = ``packed`` | ``sequential`` | ``auto``:
-
-    - ``packed``: all K solves as ONE vmapped XLA program.
-    - ``sequential``: K whole-solve dispatches, one per class — each
-      class stops at ITS OWN convergence instead of the pack's slowest
-      lane.
-    - ``auto`` (default): the measured per-platform winner — **packed
-      on TPU at every measured K, sequential on CPU**.  Final clean
-      chip numbers (fixed-work instrument, device-resident operands,
-      all-outputs terminal dependency): **1.60× (K=4), 2.49× (K=8),
-      4.02× (K=16), 7.55× (K=64)** — the packed gemm reads X once for
-      all K lanes (the dominant HBM traffic, amortized K ways) and the
-      MXU batches K ≤ 128 lanes at near-constant cost.  Three earlier
-      contradictory adjudications were instrument errors, each worth
-      knowing (docs/design.md "invalid-instrument postmortem"):
-      coin-flip targets let the line-search-failure exit give the arms
-      different WORK; iteration-count fetches inside the timed region
-      gave the arms different SYNC; and a ``_prep``/``shard_rows``
-      device→host→device round trip on device-resident operands — a
-      real product bug found BY the instrument chase, since fixed —
-      taxed the arms differently per input type.  On CPU the fixed-work
-      pack loses (vmap serializes lanes; 0.84× at K=4) — sequential
-      stays the CPU winner.  ``n_lanes`` is accepted for future
-      K-dependent policies; the current winner does not depend on it.
-    """
-    from ..utils import env_choice
-
-    v = env_choice("DASK_ML_TPU_PACK", ("auto", "packed", "sequential"))
-    if v != "auto":
-        return v
-    return "packed" if jax.default_backend() == "tpu" else "sequential"
-
-
 def line_search_strategy(requested: str = "auto") -> str:
     """Resolve a line-search choice, ``DASK_ML_TPU_LINE_SEARCH`` =
     ``auto`` | ``backtrack`` | ``probe_grid``.
@@ -810,8 +772,8 @@ def line_search_strategy(requested: str = "auto") -> str:
     differ by the cost of their trials on vectors of a row's length, not
     by reads of the design matrix: PERF.md section 5 has the readings.
     An explicit ``requested`` value wins over the env knob; the env knob
-    wins over ``auto``.  Resolution must happen OUTSIDE jit (same
-    trace-time-staleness rule as ``ops.scatter.scatter_strategy``).
+    wins over ``auto``.  Resolution must happen OUTSIDE jit: read at
+    trace time, the env knob's first value would stay in the jit cache.
     """
     from ..utils import env_choice
 
@@ -824,24 +786,67 @@ def line_search_strategy(requested: str = "auto") -> str:
     return "probe_grid" if jax.default_backend() == "tpu" else "backtrack"
 
 
-def grid_pack_strategy() -> str:
-    """Whether GRID-SEARCH C-sweeps pack (``solvers.lambda_sweep``) —
-    ``DASK_ML_TPU_GRID_PACK`` = ``packed`` | ``sequential`` | ``auto``.
-    A separate knob from ``DASK_ML_TPU_PACK``: the two optimizations
-    have opposite signs on CPU (OvR packing loses 1.5×, the grid sweep
-    WINS 2× at small n because it also removes per-candidate
-    orchestration) and must not share one switch.  Auto follows the
-    at-scale measurement: packed on TPU, sequential on CPU (at large n
-    the CPU solve dominates and vmap serialization loses,
-    ``grid_sweep_lbfgs`` CPU: 0.626×); small-n CPU users can force
-    ``packed`` for the measured orchestration win."""
-    from ..utils import env_choice
+# ------------------------------------------------------- packed (vmap) --
 
-    v = env_choice("DASK_ML_TPU_GRID_PACK",
-                   ("auto", "packed", "sequential"))
-    if v != "auto":
-        return v
-    return "packed" if jax.default_backend() == "tpu" else "sequential"
+
+#: the whole-solve runners a lane of :func:`packed_solve` or
+#: :func:`lambda_sweep` runs, by solver name (``admm`` lanes run
+#: :func:`_admm_lane`)
+_LANE_RUNNERS = {
+    "lbfgs": _lbfgs_run,
+    "gradient_descent": _gd_run,
+    "proximal_grad": _pg_run,
+    "newton": _newton_run,
+}
+
+
+def _note_lanes_backtrack(requested):
+    """Lanes search the line by backtracking, whatever was asked: a
+    ``lax.cond`` grid under ``vmap`` executes BOTH branches in every
+    lane, so ``probe_grid`` would pay the full grid per lane per
+    iteration.  Logs it where a caller asked for something else."""
+    if requested not in ("backtrack", "auto"):
+        logger.info(
+            "vmapped lanes force line_search='backtrack' (requested %r): "
+            "lanes run grids in both cond branches", requested,
+        )
+
+
+def _lane_runner(solver, family, reg, penalized):
+    """The runner of a non-``admm`` lane on the black-box objective and
+    its keyword arguments, after the checks every solver's own entry
+    point makes.  ``penalized``: some lane's ``lamduh`` is not 0."""
+    if solver not in _LANE_RUNNERS:
+        raise ValueError(f"Unknown solver {solver!r}")
+    if solver in ("lbfgs", "gradient_descent", "newton") and penalized \
+            and not reg.smooth:
+        raise ValueError(
+            f"{solver} requires a smooth penalty; got {reg.__name__}"
+        )
+    if solver == "newton" and getattr(family, "params_per_feature", 1) > 1:
+        raise ValueError("newton does not support matrix-parameter families")
+    # proximal_grad has its own prox backtracking and takes no knob
+    kw = {} if solver == "proximal_grad" else {"line_search": "backtrack"}
+    if solver == "lbfgs":
+        kw["objective"] = "black_box"
+    return _LANE_RUNNERS[solver], kw
+
+
+def _admm_lane(yv, b0, lam, *, x, mask, rho, abstol, reltol, inner_tol,
+               max_iter, mesh, counts=False, **static):
+    """One ADMM solve as a lane: ``packed_solve`` maps it over ``(yv,
+    b0)``, ``lambda_sweep`` over ``lam``.  Under ``vmap`` the cached
+    linear predictor would be K x rows floats twice over, so the lanes
+    keep the black-box objective.  Returns the iterations, or with
+    ``counts`` the whole :data:`SOLVE_COUNTS` vector."""
+    dt = _param_dtype(x)
+    beta, c = _admm_run(
+        x, yv, mask, lam, jnp.asarray(rho, dt), jnp.asarray(abstol, dt),
+        jnp.asarray(reltol, dt), jnp.asarray(inner_tol, dt),
+        jnp.int32(max_iter), b0, mesh_holder=MeshHolder(mesh or get_mesh()),
+        line_search="backtrack", objective="black_box", **static,
+    )
+    return beta, c if counts else c[0]
 
 
 def packed_solve(solver: str, X, Y, *, family: type[Family] = Logistic,
@@ -849,16 +854,14 @@ def packed_solve(solver: str, X, Y, *, family: type[Family] = Logistic,
                  tol: float = 1e-5, rho: float = 1.0, abstol: float = 1e-4,
                  reltol: float = 1e-2, inner_iter: int = 50,
                  inner_tol: float = 1e-6, mesh=None,
-                 line_search: str | None = None, Beta0=None,
+                 line_search: str = "backtrack", Beta0=None,
                  intercept: bool = False):
     """All K independent solves as ONE vmapped XLA program over the
     leading axis of ``Y`` — the one-vs-rest fit issues a single dispatch
     instead of K sequential ones (the solvers' whole-solve ``while_loop``
     design is vmap-safe by construction: converged lanes hold their carry
-    while stragglers keep iterating).  Under ``pack_strategy() ==
-    "sequential"`` (the measured CPU winner, or forced via
-    ``DASK_ML_TPU_PACK``) the same K solves run as K dispatches instead;
-    results are identical up to lane-vs-loop accumulation order.
+    while stragglers keep iterating).  The lanes backtrack on the
+    black-box objective, whatever ``line_search`` asks.
 
     Reference: ``dask_ml/linear_model/glm.py :: LogisticRegression``
     dispatches per class; there is no packed equivalent to cite — this is
@@ -874,37 +877,7 @@ def packed_solve(solver: str, X, Y, *, family: type[Family] = Logistic,
       carries its own executed-iteration count.
     """
     reg = get_regularizer(regularizer)
-    strategy = pack_strategy(len(Y))
-    if strategy == "packed":
-        # a lax.cond grid under vmap executes BOTH branches in every
-        # lane, so probe_grid would pay the full grid per lane per
-        # iteration — lockstep backtracking is strictly better here.
-        # (sequential solves have no lanes; they keep the request)
-        if line_search not in (None, "backtrack", "auto"):
-            logger.info(
-                "packed_solve forces line_search='backtrack' "
-                "(requested %r): vmapped lanes run grids in both cond "
-                "branches", line_search,
-            )
-        line_search = "backtrack"
-    elif line_search is None:
-        # OUR default (sentinel, so a user's explicit value — including
-        # 'auto' — is distinguishable): lbfgs follows the measured
-        # per-platform policy; admm/gd/newton keep their own
-        # measured-safe backtrack default rather than being silently
-        # opted into the unadjudicated configuration
-        line_search = (line_search_strategy("auto")
-                       if solver == "lbfgs" else "backtrack")
-    else:
-        # an explicit request — 'auto' included — is the user's opt-in
-        # and resolves through the policy for every solver, matching
-        # the direct entry points' contract
-        line_search = line_search_strategy(line_search)
-    # the same rule for the line search's cached linear predictor: under
-    # vmap it is K x rows floats twice over, so packed lanes keep the
-    # black-box objective; one solve a dispatch caches, as admm() and
-    # lbfgs() do
-    objective = "black_box" if strategy == "packed" else "linear"
+    _note_lanes_backtrack(line_search)
     x, _, mask = _prep(X, Y[0])
     dt = _param_dtype(x)
     Yd = jnp.asarray(Y).astype(dt)
@@ -926,61 +899,15 @@ def packed_solve(solver: str, X, Y, *, family: type[Family] = Logistic,
             )
         B0 = jnp.stack(
             [_init_beta(b, x, family, intercept) for b in Beta0])
-
-    def _sequential(one_fn, *extra_rows):
-        # K whole-solve dispatches (the auto fallback where vmap packing
-        # measured slower); each class converges independently
-        DISPATCH_COUNTS["solves"] += K
-        outs = [
-            one_fn(Yd[c], *(e[c] for e in extra_rows)) for c in range(K)
-        ]
-        betas = jnp.stack([b for b, _ in outs])
-        n_its = jnp.stack([n for _, n in outs])
-        return betas, n_its
-
-    if strategy == "packed":
-        DISPATCH_COUNTS["solves"] += 1
     if solver == "admm":
-        mesh = mesh or get_mesh()
-        mh = MeshHolder(mesh)
-
-        def one(yv, b0):
-            beta, counts = _admm_run(
-                x, yv, mask, lam, jnp.asarray(rho, dt),
-                jnp.asarray(abstol, dt), jnp.asarray(reltol, dt),
-                jnp.asarray(inner_tol, dt), jnp.int32(max_iter), b0,
-                family=family, reg=reg, mesh_holder=mh,
-                inner_iter=inner_iter, line_search=line_search,
-                objective=objective,
-            )
-            return beta, counts[0]
-
-        if strategy == "sequential":
-            return _sequential(one, B0)
-        return jax.vmap(one)(Yd, B0)
-    runners = {
-        "lbfgs": _lbfgs_run,
-        "gradient_descent": _gd_run,
-        "proximal_grad": _pg_run,
-        "newton": _newton_run,
-    }
-    if solver not in runners:
-        raise ValueError(f"Unknown solver {solver!r}")
-    if solver in ("lbfgs", "gradient_descent", "newton") and lamduh \
-            and not reg.smooth:
-        raise ValueError(
-            f"{solver} requires a smooth penalty; got {reg.__name__}"
-        )
-    if solver == "newton" and getattr(family, "params_per_feature", 1) > 1:
-        raise ValueError("newton does not support matrix-parameter families")
-    run = runners[solver]
-
-    # proximal_grad has its own prox backtracking and takes no knob
-    extra_kw = (
-        {} if solver == "proximal_grad" else {"line_search": line_search}
-    )
-    if solver == "lbfgs":
-        extra_kw["objective"] = objective
+        DISPATCH_COUNTS["solves"] += 1
+        one = partial(
+            _admm_lane, x=x, mask=mask, rho=rho, abstol=abstol,
+            reltol=reltol, inner_tol=inner_tol, max_iter=max_iter,
+            mesh=mesh, family=family, reg=reg, inner_iter=inner_iter)
+        return jax.vmap(one, in_axes=(0, 0, None))(Yd, B0, lam)
+    run, extra_kw = _lane_runner(solver, family, reg, bool(lamduh))
+    DISPATCH_COUNTS["solves"] += 1
 
     def one(yv, b0):
         beta, n_it = run(
@@ -989,8 +916,6 @@ def packed_solve(solver: str, X, Y, *, family: type[Family] = Logistic,
         )
         return beta, _iterations(n_it)
 
-    if strategy == "sequential":
-        return _sequential(one, B0)
     return jax.vmap(one)(Yd, B0)
 
 
@@ -1004,11 +929,8 @@ def lambda_sweep(solver: str, X, y, lams, *, family: type[Family] = Logistic,
     strengths as ONE vmapped program — the grid-search twin of
     ``packed_solve`` (there the lanes differ in y, here in ``lamduh``,
     which every runner takes as a TRACED scalar, so a hyperparameter
-    sweep is one dispatch instead of K).  No sequential fallback here:
-    the grid-search caller gates on ``grid_pack_strategy()`` (NOT
-    ``pack_strategy()`` — the two knobs are deliberately separate, with
-    opposite CPU signs) and keeps its per-candidate path where packing
-    measured slower.
+    sweep is one dispatch instead of K).  The lanes backtrack, as
+    ``packed_solve``'s do.
 
     Returns (betas (K, pdim), n_iters (K,)).  ``return_counts=True``
     (as ``lbfgs`` and ``admm`` take it) returns each lane's whole count
@@ -1018,59 +940,30 @@ def lambda_sweep(solver: str, X, y, lams, *, family: type[Family] = Logistic,
     black box a lane's ``trials`` are 0 and are among its ``passes``.
     """
     reg = get_regularizer(regularizer)
-    if line_search != "backtrack":
-        line_search = "backtrack"  # same vmap-lane rule as packed_solve
-    objective = sweep_objective(solver, family)
+    _note_lanes_backtrack(line_search)
     x, yd, mask = _prep(X, y)
     dt = _param_dtype(x)
     lam_v = jnp.asarray(np.asarray(lams), dt)
     if lam_v.ndim != 1:
         raise ValueError(f"lams must be 1-D, got shape {lam_v.shape}")
-    K = lam_v.shape[0]
+    pdim = _pdim(x, family, intercept)
     if solver == "admm":
         DISPATCH_COUNTS["solves"] += 1  # after arg validation, like
         # every per-solver entry point — a rejected config must not
         # skew the dispatch instrumentation
-        mesh = mesh or get_mesh()
-        mh = MeshHolder(mesh)
-
-        def one_a(lam):
-            beta, counts = _admm_run(
-                x, yd, mask, lam, jnp.asarray(rho, dt),
-                jnp.asarray(abstol, dt), jnp.asarray(reltol, dt),
-                jnp.asarray(inner_tol, dt), jnp.int32(max_iter),
-                jnp.zeros(_pdim(x, family, intercept), dtype=dt),
-                family=family, reg=reg, mesh_holder=mh,
-                inner_iter=inner_iter, line_search=line_search,
-                objective=objective,
-            )
-            return beta, counts if return_counts else counts[0]
-
-        return jax.vmap(one_a)(lam_v)
-    runners = {
-        "lbfgs": _lbfgs_run,
-        "gradient_descent": _gd_run,
-        "proximal_grad": _pg_run,
-        "newton": _newton_run,
-    }
-    if solver not in runners:
-        raise ValueError(f"Unknown solver {solver!r}")
-    if solver in ("lbfgs", "gradient_descent", "newton") \
-            and not reg.smooth and bool(np.any(np.asarray(lams))):
-        raise ValueError(
-            f"{solver} requires a smooth penalty; got {reg.__name__}"
-        )
-    if solver == "newton" and getattr(family, "params_per_feature", 1) > 1:
-        raise ValueError("newton does not support matrix-parameter families")
+        one = partial(
+            _admm_lane, x=x, mask=mask, rho=rho, abstol=abstol,
+            reltol=reltol, inner_tol=inner_tol, max_iter=max_iter,
+            mesh=mesh, counts=return_counts, family=family, reg=reg,
+            inner_iter=inner_iter)
+        return jax.vmap(one, in_axes=(None, None, 0))(
+            yd, jnp.zeros(pdim, dtype=dt), lam_v)
+    run, extra_kw = _lane_runner(solver, family, reg,
+                                 bool(np.any(np.asarray(lams))))
+    if sweep_objective(solver, family) == "linear":
+        run, extra_kw = _lbfgs_lane, {"line_search": "backtrack"}
     DISPATCH_COUNTS["solves"] += 1
-    run = _lbfgs_lane if objective == "linear" else runners[solver]
-    B0 = jnp.zeros((K, _pdim(x, family, intercept)), dtype=dt)
-    extra_kw = (
-        {} if solver == "proximal_grad" else {"line_search": line_search}
-    )
-    if run is _lbfgs_run:
-        extra_kw["objective"] = objective
-
+    B0 = jnp.zeros((lam_v.shape[0], pdim), dtype=dt)
     return _sweep_lanes(
         x, yd, mask, lam_v, B0, jnp.int32(max_iter), jnp.asarray(tol, dt),
         run=run, family=family, reg=reg, extra_kw=tuple(extra_kw.items()),

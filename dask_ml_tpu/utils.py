@@ -160,9 +160,9 @@ def slice_columns(X, columns):
 
 def env_choice(name: str, allowed: tuple, default: str = "auto") -> str:
     """Read a strategy knob from the environment with validation — the
-    shared shape behind ``DASK_ML_TPU_SCATTER`` / ``DASK_ML_TPU_PACK``
-    (each policy keeps its own platform-auto logic, but the read/validate
-    step lives once)."""
+    shared shape behind the knobs that name a strategy (each policy
+    keeps its own ``auto`` logic, but the read/validate step lives
+    once)."""
     import os
 
     v = os.environ.get(name, default).strip().lower()

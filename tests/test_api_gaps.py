@@ -157,7 +157,7 @@ class TestMulticlassLogistic:
 
         for y, kw in [
             (yb, {}),
-            (y3, {}),  # OvR (sequential on CPU by auto policy)
+            (y3, {}),  # OvR (the packed lanes)
             (y3, {"multi_class": "multinomial"}),
         ]:
             cold = LogisticRegression(
@@ -171,12 +171,11 @@ class TestMulticlassLogistic:
             np.testing.assert_allclose(
                 np.asarray(cold.coef_), coef_first, atol=1e-3)
 
-    def test_warm_start_packed_lanes(self, rng, monkeypatch):
-        """The vmapped packed-OvR path consumes the per-lane Beta0 stack
-        (auto falls back to sequential on CPU, so force packed)."""
+    def test_warm_start_packed_lanes(self, rng):
+        """The vmapped packed-OvR path consumes the per-lane Beta0
+        stack."""
         from dask_ml_tpu.linear_model import LogisticRegression
 
-        monkeypatch.setenv("DASK_ML_TPU_PACK", "packed")
         X = rng.normal(size=(200, 6)).astype(np.float32)
         w = rng.normal(size=6)
         y3 = np.digitize(X @ w, [-0.5, 0.5]).astype(np.float32)

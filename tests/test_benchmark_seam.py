@@ -27,7 +27,6 @@ import logging
 import numbers
 import os
 import re
-from unittest import mock
 
 import numpy as np
 import pytest
@@ -91,14 +90,8 @@ def _fit(cfg, rows=ROWS):
         '"$seed"', "0"))
     X, y = _table(cfg, rows)
     est = make(**args)
-    # a search's candidates pack into lanes on a TPU, where the cells run
-    # (the CPU's ``auto`` fits them one by one, and opens no
-    # ``search.sweep``): the search's configuration alone is told so
-    packs = ({"DASK_ML_TPU_GRID_PACK": "packed"} if "sweep_modules" in cfg
-             else {})
-    with mock.patch.dict(os.environ, packs):
-        est.fit(shard_rows(X)) if y is None else est.fit(
-            shard_rows(X), shard_rows(y))
+    est.fit(shard_rows(X)) if y is None else est.fit(
+        shard_rows(X), shard_rows(y))
     return est
 
 

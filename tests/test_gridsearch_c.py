@@ -40,10 +40,8 @@ GRID = CONFIG["estimator_args"]["param_grid"]
 
 
 @pytest.fixture(autouse=True)
-def _as_on_the_chip(monkeypatch):
-    """What ``auto`` means on a TPU, where the cell runs: the candidates
-    pack into lanes; and the notice of unstratified folds is expected."""
-    monkeypatch.setenv("DASK_ML_TPU_GRID_PACK", "packed")
+def _as_on_the_chip():
+    """The notice of unstratified folds is expected."""
     with warnings.catch_warnings():
         warnings.filterwarnings("ignore", "sharded input uses unshuffled")
         yield
@@ -203,19 +201,18 @@ def test_the_unstratified_notice_comes_once_a_fit():
 
 # ---- the packed search -------------------------------------------------------
 
-def test_packed_search_is_the_per_candidate_search(monkeypatch):
-    """The lanes against one fit a candidate and fold
-    (``DASK_ML_TPU_GRID_PACK=sequential``): the same folds, so scores a
-    few rows apart at most (the lanes search the line on the black box,
-    a single fit on its cached predictor), the same choice, the same
-    refit."""
+def test_packed_search_is_the_per_candidate_search():
+    """The lanes against one fit a candidate and fold, which an explicit
+    ``scoring="accuracy"`` (what the default scores) routes to: the same
+    folds, so scores a few rows apart at most (the lanes and a single
+    fit form their products with X differently), the same choice, the
+    same refit."""
     rows = 6001
     X, y = _logistic(rows, seed=3)
     sX, sy = shard_rows(X), shard_rows(y)
     packed = _grid().fit(sX, sy)
     assert _search_tree()[0]["attrs"]["packed"] == 1
-    monkeypatch.setenv("DASK_ML_TPU_GRID_PACK", "sequential")
-    single = _grid().fit(sX, sy)
+    single = _grid(scoring="accuracy").fit(sX, sy)
     tree, spans = _search_tree()
     assert tree["attrs"]["packed"] == 0 and not spans("search.sweep")
     assert len(spans("search.fold")) == 3  # made once, shared by the eight
@@ -234,7 +231,8 @@ def test_packed_search_is_the_per_candidate_search(monkeypatch):
     np.testing.assert_array_equal(
         np.asarray(packed.best_estimator_.coef_),
         np.asarray(single.best_estimator_.coef_))
-    assert not hasattr(packed.fit(sX, sy), "coefs_paths_")
+    assert not hasattr(packed.set_params(scoring="accuracy").fit(sX, sy),
+                       "coefs_paths_")
 
 
 def test_search_leaves_its_spans_counts_and_no_second_compile():

@@ -73,8 +73,11 @@ BY_RULE = dict(tol=1e-4)
 #: and the same for them: three iterations and no rule.  A solve that
 #: ends by its rule ends on a last step of the rule's size, taken whole,
 #: halved or not at all as float32 falls; under ``vmap`` every lane has
-#: its own
-THREE_STEPS = dict(tol=0.0, max_iter=3)
+#: its own.  A tolerance under 0 is no rule at all: 0 still ends gradient
+#: descent, proximal gradient and Newton on an objective float32 stops
+#: moving, which Newton reaches on the normal family's quadratic after
+#: one step (the two forms' lanes then stop at 2 or 3 by a rounding)
+THREE_STEPS = dict(tol=-1.0, max_iter=3)
 
 #: solver, penalty, and what makes the solve end by its own rule on this
 #: table well inside ``max_iter`` (so that "the same n_iter" says the two

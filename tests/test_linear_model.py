@@ -212,13 +212,10 @@ class TestPackedOvR:
         "solver", ["lbfgs", "admm", "gradient_descent", "proximal_grad"]
     )
     def test_single_dispatch_and_accuracy(self, multiclass_data, mesh,
-                                          solver, monkeypatch):
+                                          solver):
         from dask_ml_tpu import solvers
 
         X, y = multiclass_data
-        # this test pins the PACKED path specifically (auto resolves to
-        # sequential on CPU per the measured r3 number)
-        monkeypatch.setenv("DASK_ML_TPU_PACK", "packed")
         solvers.reset_dispatch_counts()
         lr = dlm.LogisticRegression(
             solver=solver, C=1.0, max_iter=150
@@ -230,12 +227,10 @@ class TestPackedOvR:
         sk = sl.LogisticRegression(C=1.0, max_iter=300).fit(X, y)
         assert acc >= sk.score(X, y) - 0.03
 
-    def test_sharded_multiclass_single_dispatch(self, multiclass_data, mesh,
-                                                monkeypatch):
+    def test_sharded_multiclass_single_dispatch(self, multiclass_data, mesh):
         from dask_ml_tpu import solvers
 
         X, y = multiclass_data
-        monkeypatch.setenv("DASK_ML_TPU_PACK", "packed")
         sX, sy = shard_rows(X), shard_rows(y.astype(np.float32))
         solvers.reset_dispatch_counts()
         lr = dlm.LogisticRegression(solver="lbfgs", C=1.0, max_iter=150).fit(
@@ -244,10 +239,8 @@ class TestPackedOvR:
         assert solvers.DISPATCH_COUNTS["solves"] == 1
         assert float((lr.predict(sX)[: len(y)] == y).mean()) > 0.8
 
-    def test_packed_matches_sequential_loop(self, multiclass_data, mesh,
-                                            monkeypatch):
+    def test_packed_matches_sequential_loop(self, multiclass_data, mesh):
         # the packed program must agree with K independent solves
-        monkeypatch.setenv("DASK_ML_TPU_PACK", "packed")
         from dask_ml_tpu.solvers import Logistic, lbfgs, packed_solve
         from dask_ml_tpu.core import shard_rows as _sr
 
@@ -272,6 +265,38 @@ class TestPackedOvR:
             np.testing.assert_allclose(
                 np.asarray(betas[i]), np.asarray(b), rtol=5e-3, atol=1e-3
             )
+
+    @pytest.mark.parametrize("solver", ["admm", "lbfgs",
+                                        "gradient_descent",
+                                        "proximal_grad", "newton"])
+    def test_lanes_match_one_binary_solve_per_class(
+            self, multiclass_data, mesh, solver):
+        """Every ``packed_solve`` solver: each one-vs-rest lane against
+        the solver's own single solve of ``y == c``.  Tolerance as above:
+        the lanes' vmapped gemms (and, for ``admm`` / ``lbfgs``, the
+        black-box objective against the single solve's cached
+        predictor) accumulate in another order."""
+        from dask_ml_tpu import solvers
+        from dask_ml_tpu.core import shard_rows as _sr
+
+        X, y = multiclass_data
+        sX = _sr(X)
+        classes = np.unique(y)
+        Y = np.zeros((len(classes), sX.data.shape[0]), np.float32)
+        for i, c in enumerate(classes):
+            Y[i, : len(y)] = (y == c)
+        betas, n_its = solvers.packed_solve(
+            solver, sX, Y, lamduh=1.0, max_iter=100)
+        assert betas.shape == (len(classes), X.shape[1])
+        assert n_its.shape == (len(classes),)
+        # the single solve at the lanes' stopping tolerance (the entry
+        # points' own defaults differ by solver; admm stops by Boyd's)
+        kw = {} if solver == "admm" else {"tol": 1e-5}
+        for i in range(len(classes)):
+            b = getattr(solvers, solver)(sX, Y[i], lamduh=1.0, max_iter=100,
+                                         **kw)
+            np.testing.assert_allclose(
+                np.asarray(betas[i]), np.asarray(b), rtol=5e-3, atol=1e-3)
 
 
 class TestMultinomial:
@@ -490,33 +515,6 @@ class TestClassWeightValidation:
             SGDClassifier(max_iter=5, class_weight={"dog": 2.0}).fit(X, y)
 
 
-class TestPackStrategy:
-    """DASK_ML_TPU_PACK auto-fallback (r3 verdict #3): the OvR execution
-    strategy follows the measured per-platform winner and both forms
-    agree numerically."""
-
-    def test_auto_is_sequential_on_cpu(self):
-        from dask_ml_tpu.solvers import pack_strategy
-
-        assert pack_strategy() == "sequential"  # measured: fixed-work
-        # pack loses on CPU (0.84x, packed_ovr_fixedwork; vmap
-        # serializes the lanes)
-
-    def test_auto_is_packed_on_tpu(self, monkeypatch):
-        # pins the TPU branch (clean fixed-work chip wins at every
-        # measured K: 1.6x@4 .. 7.6x@64 — pack_strategy docstring)
-        # without TPU hardware: the policy reads jax.default_backend()
-        # at call time
-        import dask_ml_tpu.solvers.algorithms as algos
-
-        monkeypatch.delenv("DASK_ML_TPU_PACK", raising=False)
-        monkeypatch.setattr(algos.jax, "default_backend", lambda: "tpu")
-        for k in (None, 4, 16, 64):
-            assert algos.pack_strategy(k) == "packed"
-        monkeypatch.setenv("DASK_ML_TPU_PACK", "sequential")
-        assert algos.pack_strategy(16) == "sequential"  # env force wins
-
-
 class TestDeviceIngest:
     """Raw jax.Array inputs stay on device end to end (the r5 ingest
     round-trip fix): wrapping is a device-side reshard and label
@@ -570,35 +568,25 @@ class TestDeviceIngest:
         b = lbfgs(Xd, yd, family=Logistic, lamduh=0.1, max_iter=20)
         assert np.isfinite(np.asarray(b)).all()
 
-    def test_bad_env_rejected(self, monkeypatch):
-        from dask_ml_tpu.solvers import pack_strategy
-
-        monkeypatch.setenv("DASK_ML_TPU_PACK", "vectorised")
-        import pytest as _pytest
-
-        with _pytest.raises(ValueError, match="DASK_ML_TPU_PACK"):
-            pack_strategy()
-
-    def test_sequential_matches_packed(self, multiclass_data, mesh,
-                                       monkeypatch):
+    def test_sequential_matches_packed(self, multiclass_data, mesh):
+        # the one-vs-rest lanes (one dispatch) against K binary fits on
+        # y == c, one solve each.  Tolerance = the stagnation-exit noise
+        # floor: both stop when the fp32 objective can no longer certify
+        # progress (lbfgs_core round-5 exit), and lanes (black-box
+        # objective, vmapped gemms) and single fits (cached predictor)
+        # accumulate in different orders inside that certified band
         from dask_ml_tpu import solvers
 
         X, y = multiclass_data
-        outs = {}
-        for strat in ("packed", "sequential"):
-            monkeypatch.setenv("DASK_ML_TPU_PACK", strat)
-            solvers.reset_dispatch_counts()
-            lr = dlm.LogisticRegression(
-                solver="lbfgs", C=1.0, max_iter=150).fit(X, y)
-            outs[strat] = (np.asarray(lr.betas_),
-                           solvers.DISPATCH_COUNTS["solves"])
-        # tolerance = the stagnation-exit noise floor: both arms stop
-        # when the fp32 objective can no longer certify progress
-        # (lbfgs_core round-5 exit), and lane-vs-loop accumulation order
-        # differs inside that certified band — observed 2.1e-3 on a
-        # near-zero coefficient at 7 devices, identical predictions
-        np.testing.assert_allclose(outs["packed"][0],
-                                   outs["sequential"][0],
-                                   rtol=5e-3, atol=5e-3)
-        assert outs["packed"][1] == 1
-        assert outs["sequential"][1] == len(np.unique(y))
+        kw = dict(solver="lbfgs", C=1.0, max_iter=150)
+        solvers.reset_dispatch_counts()
+        lr = dlm.LogisticRegression(**kw).fit(X, y)
+        assert solvers.DISPATCH_COUNTS["solves"] == 1
+        classes = np.unique(y)
+        binary = [dlm.LogisticRegression(**kw).fit(
+            X, (y == c).astype(np.float32)) for c in classes]
+        assert solvers.DISPATCH_COUNTS["solves"] == 1 + len(classes)
+        np.testing.assert_allclose(
+            np.asarray(lr.betas_),
+            np.stack([np.asarray(b.betas_[0]) for b in binary]),
+            rtol=5e-3, atol=5e-3)

